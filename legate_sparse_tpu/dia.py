@@ -15,10 +15,12 @@ into ``dia_array.dot``).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from .base import CompressedBase
@@ -44,6 +46,44 @@ def _band_slot_gather(data, offs, extent: int):
     return gathered, valid, src
 
 
+def _band_csr_keep(data, offs, rows: int, w: int):
+    """(values, CSR column, keep) over the (diag, row) slot grid of a
+    column-aligned band: keep marks in-range nonzero slots (scipy drops
+    explicit zeros)."""
+    vals, _, col = _band_slot_gather(data, offs, rows)
+    return vals, col, (col >= 0) & (col < w) & (vals != 0)
+
+
+@partial(jax.jit, static_argnames=("rows", "w", "cdt"))
+def _band_csr_nnz(data, offs, rows: int, w: int, cdt):
+    return jnp.sum(_band_csr_keep(data, offs.astype(cdt), rows, w)[2])
+
+
+@partial(jax.jit, static_argnames=("rows", "w", "nnz", "cdt"))
+def _band_csr_parts(data, offs, rows: int, w: int, nnz: int, cdt):
+    """(data, indices, indptr) of the band's CSR form, in one program
+    (the eager ops compiled ~60, two seconds each on the chip).  With
+    offsets ascending, row r's entries are its kept slots in diagonal
+    order, so slot (d, r) lands at ``indptr[r]`` plus the kept slots
+    above it in its column of the (diag, row) grid — one scatter, and
+    no (rows, diags) transpose, which the TPU would pad to 128 lanes."""
+    vals, col, keep = _band_csr_keep(data, offs.astype(cdt), rows, w)
+    # indptr counts nnz, not coordinates: platform-width ints (int64
+    # under x64, else int32 with the documented 2^31-1 per-process nnz
+    # limit — the caller's check_nnz fails loudly first).
+    k = keep.astype(nnz_dtype())
+    cindptr = jnp.concatenate(
+        [jnp.zeros((1,), dtype=nnz_dtype()), jnp.cumsum(jnp.sum(k, axis=0))]
+    )
+    # Dropped slots scatter out of range (mode="drop").
+    pos = jnp.where(keep, cindptr[None, :-1] + jnp.cumsum(k, axis=0) - k,
+                    nnz)
+    cdata = jnp.zeros((nnz,), vals.dtype).at[pos].set(vals, mode="drop")
+    cindices = jnp.zeros((nnz,), cdt).at[pos].set(col.astype(cdt),
+                                                   mode="drop")
+    return cdata, cindices, cindptr
+
+
 class dia_array(CompressedBase):
     """Sparse matrix with DIAgonal storage, backed by jax.Arrays."""
 
@@ -55,10 +95,13 @@ class dia_array(CompressedBase):
             shape = arg.shape if shape is None else tuple(shape)
         elif isinstance(arg, tuple) and len(arg) == 2:
             data_in, offsets_in = arg
-            data = jnp.atleast_2d(jnp.asarray(data_in))
-            offsets = jnp.atleast_1d(
-                jnp.asarray(offsets_in, dtype=index_dtype())
-            )
+            # Shapes fixed on the host: an eager jnp.atleast_* is one
+            # more compiled program per build.
+            data = jnp.asarray(data_in)
+            if data.ndim < 2:
+                data = jnp.asarray(np.atleast_2d(np.asarray(data)))
+            offsets = jnp.asarray(np.atleast_1d(np.asarray(offsets_in)),
+                                  dtype=index_dtype())
             if shape is None:
                 raise ValueError("dia_array from (data, offsets) needs shape")
         else:
@@ -156,48 +199,31 @@ class dia_array(CompressedBase):
         )
 
     def tocsr(self, copy: bool = False):
-        """DIA -> CSR, sort-free.
+        """DIA -> CSR, sort-free (``_band_csr_parts``).
 
         The reference routes through a transpose and a masked-cumsum CSC
         build (``dia.py:152-190``, scipy's DIA->CSC algorithm).  No
         global sort is ever needed: distinct offsets mean distinct
         columns within a row, and with offsets pre-sorted ascending
-        (host-side, num_d elements) a row-major flatten of the
-        (row, diag) slot grid IS CSR order — one mask + one compacting
-        gather replaces the previous two-key ``lax.sort`` over every
-        band slot (184M elements at the 2^24 bench size, the largest
-        single device op in the banded build path).
+        (host-side, num_d elements) the slot grid is already in CSR
+        order.  Two compiled programs: the nnz count, then the parts.
         """
         from .csr import csr_array
 
         rows, cols = self.shape
         num_d, width = self._data.shape
         w = min(width, cols)
-        cdt = coord_dtype_for(max(rows, cols) + 1)
+        cdt = np.dtype(coord_dtype_for(max(rows, cols) + 1))
         order = np.argsort(np.asarray(self._offsets), kind="stable")
         if np.array_equal(order, np.arange(num_d)):
-            offs, data = self._offsets.astype(cdt), self._data
+            offs, data = self._offsets, self._data
         else:   # gather copies the whole band; skip when already sorted
-            offs = self._offsets.astype(cdt)[jnp.asarray(order)]
+            offs = self._offsets[jnp.asarray(order)]
             data = self._data[jnp.asarray(order)]
-        # scipy DIA storage is column-aligned: data[d, col] holds
-        # A[col - off_d, col].
-        vals, _, col = _band_slot_gather(data, offs, rows)
-        keep = (col >= 0) & (col < w) & (vals != 0)  # scipy drops zeros
-        nnz = int(jnp.sum(keep))
+        nnz = int(_band_csr_nnz(data, offs, rows, w, cdt))
         check_nnz(nnz)
-        idx = jnp.nonzero(keep.T.reshape(-1), size=nnz, fill_value=0)[0]
-        cdata = vals.T.reshape(-1)[idx]
-        cindices = col.T.reshape(-1)[idx].astype(cdt)
-        # indptr counts nnz, not coordinates: platform-width ints
-        # (int64 under x64, else int32 with the documented 2^31-1
-        # per-process nnz limit — check_nnz above fails loudly first).
-        counts = jnp.sum(keep, axis=0, dtype=nnz_dtype())
-        cindptr = jnp.concatenate(
-            [jnp.zeros((1,), dtype=nnz_dtype()), jnp.cumsum(counts)]
-        )
         return csr_array._from_parts(
-            cdata, cindices, cindptr, self.shape
+            *_band_csr_parts(data, offs, rows, w, nnz, cdt), self.shape
         )
 
     # ---------------- products (DIA fast path) ----------------

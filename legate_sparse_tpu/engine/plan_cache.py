@@ -34,6 +34,7 @@ Counters (always on, ``obs.counters`` contract):
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from collections import OrderedDict
@@ -244,7 +245,9 @@ def maybe_enable_persistent_cache() -> bool:
     kernels included), with the min-compile-time threshold dropped to
     0 so small engine plans qualify.  Deliberate — non-engine retraces
     become warm-startable too — but the operator owns the directory's
-    growth (docs/ENGINE.md, scope caveat)."""
+    growth (docs/ENGINE.md, scope caveat).  A set
+    ``JAX_COMPILATION_CACHE_DIR`` names the process's cache and wins
+    over ``engine_persist_dir``."""
     global _persist_enabled
     from ..settings import settings
 
@@ -257,7 +260,8 @@ def maybe_enable_persistent_cache() -> bool:
         try:
             import jax
 
-            jax.config.update("jax_compilation_cache_dir", path)
+            if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+                jax.config.update("jax_compilation_cache_dir", path)
             # Persist everything the engine compiles, not only slow
             # builds (the default threshold skips small kernels).
             try:

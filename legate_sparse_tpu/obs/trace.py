@@ -3,9 +3,9 @@
 """Op-level tracing: near-zero-overhead spans and structured events.
 
 The performance story of this package was "asserted, not demonstrated"
-for five review rounds (VERDICT.md): ``bench.py`` emitted one JSON
-blob, and when the CPU fallback regressed nobody could tell whether
-compile, host<->device transfer, or kernel execution moved.  This
+for five review rounds: ``bench.py`` emitted one JSON blob, and when a
+number regressed nobody could tell whether compile, host<->device
+transfer, or kernel execution moved.  This
 module is the fix: every hot path wraps its python-level dispatch in
 
     with obs.span("spmv", nnz=nnz, bytes=nbytes) as sp:

@@ -57,6 +57,9 @@ B = 128  # block edge: one lane tile; MXU-native matvec per block
 # Present-block scalar ids live in SMEM; cap their footprint (2 int32
 # arrays) and the densified data (64 KiB/block in HBM).
 MAX_BLOCKS = 1 << 16
+# int32 zero for index maps: a bare Python 0 binds as i64 in an
+# x64-enabled process, which Mosaic cannot return from an index map.
+_I0 = np.int32(0)
 
 
 def bsr_pack(data, indices, indptr, shape, max_expand: float):
@@ -130,22 +133,28 @@ def _make_kernel(pl):
         def _():
             y_ref[...] = jnp.zeros_like(y_ref)
 
-        xc = x_ref[...]          # (1, B)
+        xc = x_ref[0]            # (1, B)
         blkT = blk_ref[0]        # (B, B), blkT[c, r]
+        # HIGHEST: an f32 matmul on the MXU otherwise runs one bf16
+        # pass, ~1e-3 relative error (measured on the v5e, PR 21).
         y_ref[...] += jnp.dot(
-            xc, blkT, preferred_element_type=y_ref.dtype
-        )
+            xc, blkT, preferred_element_type=y_ref.dtype,
+            precision=jax.lax.Precision.HIGHEST,
+        )[None]
 
     return kernel
 
 
 @partial(jax.jit, static_argnames=("nbr", "nbc", "interpret"))
-def bsr_spmv_pallas(blkT, brow, bcol, x2d, nbr: int, nbc: int,
+def bsr_spmv_pallas(blkT, brow, bcol, x3d, nbr: int, nbc: int,
                     interpret: bool = False):
-    """y2d (nbr, B) = A @ x over present blocks, one grid step each.
+    """y3d (nbr, 1, B) = A @ x over present blocks, one grid step each.
 
-    ``x2d`` is x zero-padded and reshaped (nbc, B).  Output rows beyond
-    the matrix's true row count are garbage-free (zero blocks pad empty
+    ``x3d`` is x zero-padded and reshaped (nbc, 1, B): a (1, 1, B)
+    block then equals the array's last two dimensions, which Mosaic
+    requires of a block that is not (8, 128)-divisible (the SpMM
+    kernel lays X out the same way).  Output rows beyond the matrix's
+    true row count are garbage-free (zero blocks pad empty
     block-rows); the caller truncates after ravel.
     """
     from jax.experimental import pallas as pl
@@ -156,17 +165,19 @@ def bsr_spmv_pallas(blkT, brow, bcol, x2d, nbr: int, nbc: int,
         num_scalar_prefetch=2,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, B, B), lambda i, brow, bcol: (i, 0, 0)),
-            pl.BlockSpec((1, B), lambda i, brow, bcol: (bcol[i], 0)),
+            pl.BlockSpec((1, B, B), lambda i, brow, bcol: (i, _I0, _I0)),
+            pl.BlockSpec((1, 1, B),
+                         lambda i, brow, bcol: (bcol[i], _I0, _I0)),
         ],
-        out_specs=pl.BlockSpec((1, B), lambda i, brow, bcol: (brow[i], 0)),
+        out_specs=pl.BlockSpec((1, 1, B),
+                               lambda i, brow, bcol: (brow[i], _I0, _I0)),
     )
     return pl.pallas_call(
         _make_kernel(pl),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nbr, B), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nbr, 1, B), jnp.float32),
         interpret=interpret,
-    )(brow, bcol, blkT, x2d)
+    )(brow, bcol, blkT, x3d)
 
 
 def _make_spmm_kernel(pl):
@@ -183,7 +194,8 @@ def _make_spmm_kernel(pl):
         xt = xt_ref[0]           # (k_pad, B): X chunk transposed
         blkT = blk_ref[0]        # (B, B), blkT[c, r]
         y_ref[...] += jnp.dot(
-            xt, blkT, preferred_element_type=y_ref.dtype
+            xt, blkT, preferred_element_type=y_ref.dtype,
+            precision=jax.lax.Precision.HIGHEST,
         )[None]
 
     return kernel
@@ -213,12 +225,12 @@ def bsr_spmm_pallas(blkT, brow, bcol, xt3, nbr: int, nbc: int,
         num_scalar_prefetch=2,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, B, B), lambda i, brow, bcol: (i, 0, 0)),
+            pl.BlockSpec((1, B, B), lambda i, brow, bcol: (i, _I0, _I0)),
             pl.BlockSpec((1, k_pad, B),
-                         lambda i, brow, bcol: (bcol[i], 0, 0)),
+                         lambda i, brow, bcol: (bcol[i], _I0, _I0)),
         ],
         out_specs=pl.BlockSpec((1, k_pad, B),
-                               lambda i, brow, bcol: (brow[i], 0, 0)),
+                               lambda i, brow, bcol: (brow[i], _I0, _I0)),
     )
     return pl.pallas_call(
         _make_spmm_kernel(pl),
@@ -268,12 +280,11 @@ class BsrStructure:
             xf = jnp.concatenate(
                 [xf, jnp.zeros((pad,), dtype=self.dtype)]
             )
-        x2d = xf.reshape(self.nbc, B)
-        y2d = bsr_spmv_pallas(
-            self.blkT, self.brow, self.bcol, x2d, self.nbr, self.nbc,
-            interpret=interpret,
+        y3d = bsr_spmv_pallas(
+            self.blkT, self.brow, self.bcol, xf.reshape(self.nbc, 1, B),
+            self.nbr, self.nbc, interpret=interpret,
         )
-        return y2d.ravel()[: self.rows].astype(self.dtype)
+        return y3d.ravel()[: self.rows].astype(self.dtype)
 
     def matmat(self, X, interpret: bool):
         """Y = A @ X for dense (cols, k) X, k <= SPMM_MAX_K."""

@@ -44,10 +44,13 @@ Design (role parity with the reference's hand-tuned SpMV leaf,
   matching CSR semantics exactly (same contract as ``ops/spmv.py``).
 
 Supported: f32/bf16 values (f64 is rejected — Mosaic has no 64-bit
-vectors; the XLA path in ``ops/dia_ops.py`` is the f64 fallback),
+vectors; the XLA path in ``ops/dia_ops.py`` serves f64),
 ``max|offset| <= tile`` (tile auto-grows to 2^17), any rectangular
-shape.  The wrapper returns None when unsupported and the caller falls
-back to the XLA kernels.
+shape.  bf16 tiles are widened to f32 inside VMEM before the rolls
+(Mosaic rotates 32-bit data only) and accumulate in f32.  Whether the
+kernel serves a band is decided before dispatch (``supported``, the
+VMEM model, the platform); a kernel the dispatch chose either runs or
+raises — no compile error is caught and replaced by the XLA path.
 """
 
 from __future__ import annotations
@@ -66,17 +69,50 @@ from .. import obs as _obs
 L = 128                 # TPU lane width
 TILE_MIN = 1 << 14      # default rows per grid step (multiple of 1024)
 TILE_MAX = 1 << 17      # beyond this the VMEM working set is too large
-# VMEM budget for one grid step (bytes); conservative vs the ~128 MB/core.
-_VMEM_BUDGET = 96 << 20
+# Scoped-VMEM limit requested from Mosaic for every band kernel (the
+# compiler's default is 16 MiB; v5e has 128 MiB per core), and the
+# budget the VMEM models below hold a grid step's double-buffered
+# blocks, lane padding included, to — the rest is headroom for the
+# compiler's own temporaries (the rolled copies of the x window).
+_VMEM_LIMIT = 64 << 20
+_VMEM_BUDGET = 48 << 20
+# int32 zero for index maps: a bare Python 0 binds as i64 in an
+# x64-enabled process, which Mosaic cannot return from an index map.
+_I0 = np.int32(0)
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _spmv_vmem(tile: int, nd: int, itemsize: int, masked: bool) -> int:
+    """Modeled VMEM of one SpMV grid step: the three x views, the
+    output and the band (plus its int8 mask) as (tile/L, L) blocks —
+    lane-exact, no padding — double-buffered, plus the f32 window and
+    its rolled copies."""
+    blocks = 4 * tile * itemsize + nd * tile * (itemsize + masked)
+    return 2 * blocks + 12 * tile * 4
+
+
+def _spmm_vmem(tile: int, k: int, nd: int, itemsize: int,
+               masked: bool) -> int:
+    """Modeled VMEM of one SpMM grid step: the SpMV blocks with the x
+    views and the output widened k-fold (transposed, lane-exact),
+    double-buffered, plus the f32 window, its rolled copies and the
+    accumulator."""
+    blocks = 4 * k * tile * itemsize + nd * tile * (itemsize + masked)
+    return 2 * blocks + 12 * k * tile * 4
 
 
 def _tile_override() -> Optional[int]:
     """Operator-forced rows-per-grid-step (``LEGATE_SPARSE_TPU_PALLAS_TILE``,
     power of two in [2^10, TILE_MAX]).  Exists for on-chip tuning and
-    fault isolation: the tile sets the grid length (2^24 rows = 1024
-    steps at the default 2^14), and a grid-length-dependent fault looks
-    exactly like the r3 loop-composition crash.  Read at dispatch
-    time; invalid values are ignored with a warning."""
+    for tests: the tile sets the grid length (2^24 rows = 1024 steps at
+    the default 2^14), so a small forced tile runs a multi-step grid at
+    a CPU-sized band.  Read at dispatch time; invalid values are
+    ignored with a warning."""
     v = os.environ.get("LEGATE_SPARSE_TPU_PALLAS_TILE")
     if not v:
         return None
@@ -123,7 +159,7 @@ def supported(offsets: Tuple[int, ...], dtype, masked: bool) -> Optional[int]:
     itemsize = np.dtype(dtype).itemsize
 
     def vmem_of(t: int) -> int:
-        return t * itemsize * (3 + 1) + nd * t * (itemsize + masked)
+        return _spmv_vmem(t, nd, itemsize, masked)
 
     tile = choose_tile(max(abs(o) for o in offsets))
     if tile is None:
@@ -189,59 +225,6 @@ def row_align(dia_data, offsets: Tuple[int, ...], shape: Tuple[int, int],
     return rdata, rmask
 
 
-def _use_mosaic_roll() -> bool:
-    """Roll lowering inside the kernels: ``pltpu.roll`` (default) or
-    plain ``jnp.roll`` with ``LEGATE_SPARSE_TPU_PALLAS_ROLL=xla``.
-    Both operate on VMEM-resident tiles, so the jnp variant's relayout
-    is VPU shuffle work, not HBM traffic — a fallback lowering in case
-    the Mosaic roll primitive is implicated in the on-chip worker
-    fault (fault_isolate's ``pallas-jroll`` mode probes it).
-
-    Read at kernel TRACE time and not part of the jit key: set it
-    before the first banded op of the process (the isolation harness
-    uses one subprocess per probe, so each reads it fresh)."""
-    return os.environ.get("LEGATE_SPARSE_TPU_PALLAS_ROLL", "tpu") != "xla"
-
-
-def _distinct_inputs() -> bool:
-    """Band-kernel neighbor-tile inputs (SpMV, SpMM, and the banded
-    SpGEMM): pass the SAME padded buffer three times with clamped
-    index maps (default, zero-copy), or three DISTINCT tile-shifted
-    copies with plain index maps
-    (``LEGATE_SPARSE_TPU_PALLAS_INPUTS=distinct``).
-
-    The distinct mode exists as a fault-isolation rung: the r3 on-chip
-    worker fault appears only when the kernel is embedded in a jitted
-    fori_loop (eager launches at full size pass), and the loop is
-    exactly where XLA's buffer reuse interacts with the three aliased
-    operands + min/max index maps.  Distinct copies cost one extra
-    pass over x per call (~15% of the band traffic at the bench
-    shape) and remove both structural suspects at once.
-
-    Read at kernel TRACE time, not part of the jit key — set before
-    the first banded op of the process (the isolation harness and the
-    bench canary ladder run one subprocess per variant)."""
-    return os.environ.get(
-        "LEGATE_SPARSE_TPU_PALLAS_INPUTS", "alias") == "distinct"
-
-
-def _shifted_triple(buf, blocks: int, axis: int):
-    """(minus, center, plus): DISTINCT tile-shifted copies of ``buf``
-    along ``axis`` (shift unit = ``blocks`` rows), zero edge tiles,
-    separated by an optimization barrier so XLA cannot re-alias them —
-    the shared construction for the de-aliased input mode."""
-    shape = list(buf.shape)
-    shape[axis] = blocks
-    z = jnp.zeros(shape, buf.dtype)
-    def take(lo, hi):
-        idx = [slice(None)] * buf.ndim
-        idx[axis] = slice(lo, hi)
-        return buf[tuple(idx)]
-    minus = jnp.concatenate([z, take(None, -blocks)], axis=axis)
-    plus = jnp.concatenate([take(blocks, None), z], axis=axis)
-    return jax.lax.optimization_barrier((minus, buf, plus))
-
-
 def _flat_shift(w, s: int, lane, interpret: bool, axis: int = 0):
     """xs with ``xs_flat[p] = w_flat[p + s]`` along the flattened last
     two dims of ``w`` (.., R, L); leading dims (axis base > 0) are
@@ -251,16 +234,14 @@ def _flat_shift(w, s: int, lane, interpret: bool, axis: int = 0):
     R = w.shape[axis]
     q, r = divmod(s, L)
 
-    if interpret or not _use_mosaic_roll():
+    if interpret:
         roll = lambda a, amt, ax: jnp.roll(a, amt, ax)
     else:
         from jax.experimental.pallas import tpu as pltpu
 
         # The shift operand must be i32: a plain Python int binds as a
         # weak i64 constant in an x64-enabled process, which
-        # tpu.dynamic_rotate rejects at Mosaic verification (caught by
-        # the off-chip TPU-export regression tests; on-chip processes
-        # run x64-off so the lowering there is unchanged).
+        # tpu.dynamic_rotate rejects at Mosaic verification.
         roll = lambda a, amt, ax: pltpu.roll(a, np.int32(amt), ax)
 
     def rowroll(q_):
@@ -287,21 +268,24 @@ def _make_kernel(offsets: Tuple[int, ...], rows: int, cols: int,
         import jax.experimental.pallas as pl
 
         base = pl.program_id(0) * tile
-        w = jnp.concatenate([xm_ref[:], xc_ref[:], xp_ref[:]], axis=0)
+        dtype = d_ref.dtype
+        acc_dtype = jnp.float32 if dtype != jnp.float64 else dtype
+        # Widened before the rolls: Mosaic rotates 32-bit data only.
+        w = jnp.concatenate([xm_ref[:], xc_ref[:], xp_ref[:]],
+                            axis=0).astype(acc_dtype)
         lane3 = jax.lax.broadcasted_iota(jnp.int32, (3 * Rt, L), 1)
         row_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 0)
         lane_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 1)
         gi = base + row_t * L + lane_t            # global output row
-        dtype = d_ref.dtype
-        acc_dtype = jnp.float32 if dtype != jnp.float64 else dtype
         acc = jnp.zeros((Rt, L), acc_dtype)
         for di, off in enumerate(offsets):
             xs = _flat_shift(w, off, lane3, interpret)[Rt: 2 * Rt]
             valid = (gi + off >= 0) & (gi + off < cols) & (gi < rows)
             if masked:
-                valid = valid & (m_ref[di] > 0)
-            xsafe = jnp.where(valid, xs, jnp.zeros((), xs.dtype))
-            acc = acc + (d_ref[di] * xsafe).astype(acc_dtype)
+                # v5e has no int8 vector compare: widen first.
+                valid = valid & (m_ref[di].astype(jnp.int32) > 0)
+            xsafe = jnp.where(valid, xs, jnp.zeros((), acc_dtype))
+            acc = acc + d_ref[di].astype(acc_dtype) * xsafe
         y_ref[:] = acc.astype(dtype)
 
     return kernel
@@ -331,29 +315,16 @@ def pallas_dia_spmv(rdata, rmask, x, offsets: Tuple[int, ...],
     masked = rmask is not None
     kernel = _make_kernel(offsets, rows, cols, tile, masked, interpret)
 
-    if _distinct_inputs():
-        # Three separate tile-shifted buffers, plain index maps.  The
-        # zero edge tiles are safe: every read whose global source row
-        # is out of range is masked by `valid` inside the kernel.
-        xm_b, xc_b, xp_b = _shifted_triple(xv, Rt, axis=0)
-        in_specs = [
-            pl.BlockSpec((Rt, L), lambda i: (i, 0)),
-            pl.BlockSpec((Rt, L), lambda i: (i, 0)),
-            pl.BlockSpec((Rt, L), lambda i: (i, 0)),
-            pl.BlockSpec((nd, Rt, L), lambda i: (0, i, 0)),
-        ]
-        args = [xm_b, xc_b, xp_b, rdata]
-    else:
-        in_specs = [
-            pl.BlockSpec((Rt, L), lambda i: (jnp.maximum(i - 1, 0), 0)),
-            pl.BlockSpec((Rt, L), lambda i: (jnp.minimum(i, ntx - 1), 0)),
-            pl.BlockSpec((Rt, L),
-                         lambda i: (jnp.minimum(i + 1, ntx - 1), 0)),
-            pl.BlockSpec((nd, Rt, L), lambda i: (0, i, 0)),
-        ]
-        args = [xv, xv, xv, rdata]
+    in_specs = [
+        pl.BlockSpec((Rt, L), lambda i: (jnp.maximum(i - 1, 0), _I0)),
+        pl.BlockSpec((Rt, L), lambda i: (jnp.minimum(i, ntx - 1), _I0)),
+        pl.BlockSpec((Rt, L), lambda i: (jnp.minimum(i + 1, ntx - 1), _I0)),
+        pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)),
+    ]
+    args = [xv, xv, xv, rdata]
     if masked:
-        in_specs.append(pl.BlockSpec((nd, Rt, L), lambda i: (0, i, 0)))
+        in_specs.append(
+            pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)))
         args.append(rmask)
 
     y2 = pl.pallas_call(
@@ -361,7 +332,8 @@ def pallas_dia_spmv(rdata, rmask, x, offsets: Tuple[int, ...],
         out_shape=jax.ShapeDtypeStruct((rows_pad // L, L), rdata.dtype),
         grid=(nt,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((Rt, L), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((Rt, L), lambda i: (i, _I0)),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(*args)
     return y2.reshape(-1)[:rows]
@@ -369,8 +341,13 @@ def pallas_dia_spmv(rdata, rmask, x, offsets: Tuple[int, ...],
 
 def _make_spmm_kernel(offsets: Tuple[int, ...], rows: int, cols: int,
                       tile: int, masked: bool, interpret: bool):
-    """SpMM (dense multi-RHS) variant: X tiles are (tile, k), shifts
-    move whole rows — a pure sublane roll, no lane decomposition."""
+    """SpMM (dense multi-RHS) variant: the SpMV kernel batched over the
+    k columns of X.  X rides transposed as lane-dense (k, tile/L, L)
+    blocks, so every shift is the SpMV's flat shift (batched over k)
+    and the (tile/L, L) band block broadcasts over k.  A row-major
+    (tile, k) X block would need the band as (tile, 1) columns, which
+    the TPU pads to a full lane each — 128x the band's bytes."""
+    Rt = tile // L
 
     def kernel(*refs):
         if masked:
@@ -380,28 +357,24 @@ def _make_spmm_kernel(offsets: Tuple[int, ...], rows: int, cols: int,
             m_ref = None
         import jax.experimental.pallas as pl
 
-        if interpret or not _use_mosaic_roll():
-            roll = lambda a, amt: jnp.roll(a, amt, 0)
-        else:
-            from jax.experimental.pallas import tpu as pltpu
-
-            # i32 shift for the same reason as _flat_shift's roll.
-            roll = lambda a, amt: pltpu.roll(a, np.int32(amt), 0)
-
         base = pl.program_id(0) * tile
-        w = jnp.concatenate([xm_ref[:], xc_ref[:], xp_ref[:]], axis=0)
-        R3 = 3 * tile
-        gi = base + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
         dtype = d_ref.dtype
         acc_dtype = jnp.float32 if dtype != jnp.float64 else dtype
-        acc = jnp.zeros((tile, w.shape[1]), acc_dtype)
+        # Widened before the rolls: Mosaic rotates 32-bit data only.
+        w = jnp.concatenate([xm_ref[:], xc_ref[:], xp_ref[:]],
+                            axis=1).astype(acc_dtype)
+        lane3 = jax.lax.broadcasted_iota(jnp.int32, w.shape, 2)
+        row_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 0)
+        lane_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 1)
+        gi = base + row_t * L + lane_t            # global output row
+        acc = jnp.zeros((w.shape[0], Rt, L), acc_dtype)
         for di, off in enumerate(offsets):
-            xs = roll(w, (R3 - (tile + off)) % R3)[:tile]
+            xs = _flat_shift3(w, off, lane3, interpret)[:, Rt: 2 * Rt, :]
             valid = (gi + off >= 0) & (gi + off < cols) & (gi < rows)
             if masked:
-                valid = valid & (m_ref[di] > 0)
-            xsafe = jnp.where(valid, xs, jnp.zeros((), xs.dtype))
-            acc = acc + (d_ref[di] * xsafe).astype(acc_dtype)
+                valid = valid & (m_ref[di].astype(jnp.int32) > 0)
+            xsafe = jnp.where(valid[None], xs, jnp.zeros((), acc_dtype))
+            acc = acc + d_ref[di].astype(acc_dtype)[None] * xsafe
         y_ref[:] = acc.astype(dtype)
 
     return kernel
@@ -419,85 +392,77 @@ def pallas_dia_spmm(rdata, rmask, X, offsets: Tuple[int, ...],
                     interpret: bool = False):
     """Y = A @ X for dense X (cols, k) over the row-aligned band pack.
 
-    Row shifts of a 2-D X are sublane-dimension rolls — cheaper than
-    the SpMV case, which must also decompose across lanes.
+    ``tile`` divides the pack's SpMV tile (``_spmm_tile``).  X and Y
+    cross HBM transposed, (k, rows/L, L), the layout the kernel reads.
     """
     import jax.experimental.pallas as pl
 
     rows, cols = shape
     nd = len(offsets)
     k = X.shape[1]
-    rows_pad = rdata.shape[1] * rdata.shape[2]
+    Rt = tile // L
+    rows_pad = rdata.shape[1] * L
     nt = rows_pad // tile
     x_pad = -(-max(cols, rows_pad) // tile) * tile
     ntx = x_pad // tile
-    Xv = jnp.pad(X, ((0, x_pad - cols), (0, 0)))
-    # Row-vector view of the band data: (nd, rows_pad, 1) broadcasts
-    # over X's k columns (bitcast-compatible reshape of the SpMV pack).
-    rd = rdata.reshape(nd, rows_pad, 1)
-    rm = rmask.reshape(nd, rows_pad, 1) if rmask is not None else None
+    XT = jnp.pad(X, ((0, x_pad - cols), (0, 0))).T.reshape(k, -1, L)
 
-    masked = rm is not None
+    masked = rmask is not None
     kernel = _make_spmm_kernel(offsets, rows, cols, tile, masked,
                                interpret)
-    if _distinct_inputs():
-        # De-aliased variant (see the SpMV case in pallas_dia_spmv):
-        # three separate tile-shifted X buffers, plain index maps.
-        Xm, Xc, Xp = _shifted_triple(Xv, tile, axis=0)
-        in_specs = [
-            pl.BlockSpec((tile, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile, k), lambda i: (i, 0)),
-            pl.BlockSpec((nd, tile, 1), lambda i: (0, i, 0)),
-        ]
-        args = [Xm, Xc, Xp, rd]
-    else:
-        in_specs = [
-            pl.BlockSpec((tile, k), lambda i: (jnp.maximum(i - 1, 0), 0)),
-            pl.BlockSpec((tile, k),
-                         lambda i: (jnp.minimum(i, ntx - 1), 0)),
-            pl.BlockSpec((tile, k),
-                         lambda i: (jnp.minimum(i + 1, ntx - 1), 0)),
-            pl.BlockSpec((nd, tile, 1), lambda i: (0, i, 0)),
-        ]
-        args = [Xv, Xv, Xv, rd]
+    in_specs = [
+        pl.BlockSpec((k, Rt, L),
+                     lambda i: (_I0, jnp.maximum(i - 1, 0), _I0)),
+        pl.BlockSpec((k, Rt, L),
+                     lambda i: (_I0, jnp.minimum(i, ntx - 1), _I0)),
+        pl.BlockSpec((k, Rt, L),
+                     lambda i: (_I0, jnp.minimum(i + 1, ntx - 1), _I0)),
+    ]
+    args = [XT, XT, XT, rdata]
+    in_specs.append(pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)))
     if masked:
-        in_specs.append(pl.BlockSpec((nd, tile, 1), lambda i: (0, i, 0)))
-        args.append(rm)
+        in_specs.append(
+            pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)))
+        args.append(rmask)
 
-    Y = pl.pallas_call(
+    YT = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((rows_pad, k), rdata.dtype),
+        out_shape=jax.ShapeDtypeStruct((k, rows_pad // L, L),
+                                       rdata.dtype),
         grid=(nt,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tile, k), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((k, Rt, L), lambda i: (_I0, i, _I0)),
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(*args)
-    return Y[:rows]
-
-
-_SPMM_FAILED: set = set()
-_SPMM_OK: set = set()
+    return YT.reshape(k, rows_pad)[:, :rows].T
 
 
 def _spmm_tile(packed, k: int) -> Optional[int]:
     """Row-tile for the SpMM kernel: VMEM scales with k, so it is
-    chosen per (band, k) — a power-of-two divisor of the SpMV tile that
-    still covers the band reach and fits the budget."""
+    chosen per (band, k) — the largest power-of-two divisor of the
+    SpMV tile that still covers the band reach (and whole (8, L) row
+    blocks) and fits the modeled VMEM."""
     max_off = max(abs(o) for o in packed.offsets)
-    tile = 1024
-    while tile < max_off:
-        tile *= 2
-    if tile > packed.tile:
-        return None
     itemsize = np.dtype(packed.rdata.dtype).itemsize
     nd = len(packed.offsets)
-    vmem = 4 * tile * k * itemsize + nd * tile * (itemsize + 1)
-    return tile if vmem <= _VMEM_BUDGET else None
+    masked = packed.rmask is not None
+    tile = packed.tile
+    while tile >= max(8 * L, max_off):
+        if _spmm_vmem(tile, k, nd, itemsize, masked) <= _VMEM_BUDGET:
+            return tile
+        tile //= 2
+    return None
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def dia_spmm_maybe_pallas(packed, X):
-    """SpMM through the Pallas kernel, or None for the XLA fallback."""
+    """SpMM through the Pallas kernel, or None when the kernel does not
+    serve this operand (the caller runs the XLA path).  The decision is
+    made here, before dispatch; a kernel chosen runs or raises."""
     mode = _mode()
     if mode == "0" or packed is None:
         return None
@@ -505,50 +470,17 @@ def dia_spmm_maybe_pallas(packed, X):
     if k == 0 or k > SPMM_MAX_K:
         return None
     interpret = mode == "interpret"
-    if not interpret:
-        try:
-            if jax.devices()[0].platform != "tpu":
-                return None
-        except Exception:
-            return None
+    if not interpret and not _on_tpu():
+        return None
     tile = _spmm_tile(packed, k)
     if tile is None:
         return None
-    key = (packed.offsets, tile, k, str(packed.rdata.dtype),
-           packed.rmask is not None, packed.shape, interpret)
-    if key in _SPMM_FAILED:
-        return None
-    # Never FIRST-attempt inside an outer trace (compile errors there
-    # escape this except with no fallback); eager calls prove the key.
-    if key not in _SPMM_OK:
-        try:
-            from jax._src.core import trace_state_clean
-
-            if not trace_state_clean():
-                return None
-        except ImportError:
-            return None
-    try:
-        with _obs.span("pallas.spmm", tile=tile, k=int(k),
-                       num_diags=len(packed.offsets)):
-            y = pallas_dia_spmm(
-                packed.rdata, packed.rmask, X, packed.offsets,
-                packed.shape, tile, interpret=interpret,
-            )
-        _SPMM_OK.add(key)
-        return y
-    except Exception as e:
-        import sys
-
-        sys.stderr.write(
-            f"legate_sparse_tpu: pallas DIA SpMM unavailable "
-            f"({e!r:.200}); using XLA path\n"
+    with _obs.span("pallas.spmm", tile=tile, k=int(k),
+                   num_diags=len(packed.offsets)):
+        return pallas_dia_spmm(
+            packed.rdata, packed.rmask, X, packed.offsets,
+            packed.shape, tile, interpret=interpret,
         )
-        _obs.inc("op.pallas_fallback.spmm")
-        _obs.event("pallas.fallback", kernel="spmm",
-                   error=repr(e)[:200])
-        _SPMM_FAILED.add(key)
-        return None
 
 
 def _make_spgemm_kernel(offs_a: Tuple[int, ...], offs_b: Tuple[int, ...],
@@ -568,20 +500,22 @@ def _make_spgemm_kernel(offs_a: Tuple[int, ...], offs_b: Tuple[int, ...],
         import jax.experimental.pallas as pl
 
         base = pl.program_id(0) * tile
-        wA = jnp.concatenate([am_ref[:], ac_ref[:], ap_ref[:]], axis=1)
+        dtype = b_ref.dtype
+        acc_dtype = jnp.float32 if dtype != jnp.float64 else dtype
+        # Widened before the rolls: Mosaic rotates 32-bit data only.
+        wA = jnp.concatenate([am_ref[:], ac_ref[:], ap_ref[:]],
+                             axis=1).astype(acc_dtype)
         lane3 = jax.lax.broadcasted_iota(
             jnp.int32, (wA.shape[0], 3 * Rt, L), 2
         )
         row_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 0)
         lane_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 1)
         gj = base + row_t * L + lane_t           # global output column
-        dtype = b_ref.dtype
-        acc_dtype = jnp.float32 if dtype != jnp.float64 else dtype
         accs = [jnp.zeros((Rt, L), acc_dtype) for _ in offs_c]
         for b_i, ob in enumerate(offs_b):
             # One shift serves every A diagonal for this ob.
             xsA = _flat_shift3(wA, -ob, lane3, interpret)[:, Rt: 2 * Rt, :]
-            bt = b_ref[b_i]
+            bt = b_ref[b_i].astype(acc_dtype)
             for a_i, oa in enumerate(offs_a):
                 oc = oa + ob
                 j_lo = max(0, ob, oc)
@@ -590,9 +524,9 @@ def _make_spgemm_kernel(offs_a: Tuple[int, ...], offs_b: Tuple[int, ...],
                     continue
                 valid = (gj >= j_lo) & (gj < j_hi)
                 contrib = jnp.where(valid, xsA[a_i] * bt,
-                                    jnp.zeros((), dtype))
+                                    jnp.zeros((), acc_dtype))
                 ci = idx_c[oc]
-                accs[ci] = accs[ci] + contrib.astype(acc_dtype)
+                accs[ci] = accs[ci] + contrib
         c_ref[:] = jnp.stack(accs).astype(dtype)
 
     return kernel
@@ -631,62 +565,53 @@ def pallas_dia_spgemm(a_data, b_data, offs_a: Tuple[int, ...],
 
     kernel = _make_spgemm_kernel(offs_a, offs_b, offs_c, shape_a,
                                  shape_b, tile, interpret)
-    if _distinct_inputs():
-        # De-aliased variant (see pallas_dia_spmv): tile-shifted A-band
-        # copies along the blocked width axis, plain index maps.
-        am, ac, ap = _shifted_triple(av, Rt, axis=1)
-        a_specs = [
-            pl.BlockSpec((nda, Rt, L), lambda i: (0, i, 0)),
-            pl.BlockSpec((nda, Rt, L), lambda i: (0, i, 0)),
-            pl.BlockSpec((nda, Rt, L), lambda i: (0, i, 0)),
-        ]
-        a_args = [am, ac, ap]
-    else:
-        a_specs = [
-            pl.BlockSpec((nda, Rt, L),
-                         lambda i: (0, jnp.maximum(i - 1, 0), 0)),
-            pl.BlockSpec((nda, Rt, L),
-                         lambda i: (0, jnp.minimum(i, nta - 1), 0)),
-            pl.BlockSpec((nda, Rt, L),
-                         lambda i: (0, jnp.minimum(i + 1, nta - 1), 0)),
-        ]
-        a_args = [av, av, av]
+    a_specs = [
+        pl.BlockSpec((nda, Rt, L),
+                     lambda i: (_I0, jnp.maximum(i - 1, 0), _I0)),
+        pl.BlockSpec((nda, Rt, L),
+                     lambda i: (_I0, jnp.minimum(i, nta - 1), _I0)),
+        pl.BlockSpec((nda, Rt, L),
+                     lambda i: (_I0, jnp.minimum(i + 1, nta - 1), _I0)),
+    ]
     C = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((ndc, pc // L, L), b_data.dtype),
         grid=(pc // tile,),
         in_specs=[*a_specs,
-                  pl.BlockSpec((ndb, Rt, L), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((ndc, Rt, L), lambda i: (0, i, 0)),
+                  pl.BlockSpec((ndb, Rt, L), lambda i: (_I0, i, _I0))],
+        out_specs=pl.BlockSpec((ndc, Rt, L), lambda i: (_I0, i, _I0)),
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(*a_args, bv)
+    )(av, av, av, bv)
     return C.reshape(ndc, -1)[:, :n]
-
-
-_SPGEMM_FAILED: set = set()
-_SPGEMM_OK: set = set()
 
 
 def _spgemm_tile(offs_b, nda, ndb, ndc, dtype) -> Optional[int]:
     """Tile for the banded SpGEMM kernel: must cover the B-offset
-    reach (A is shifted by -ob) and fit the working set in VMEM."""
+    reach (A is shifted by -ob) and fit the modeled VMEM: the three A
+    views, B and C as lane-exact blocks, double-buffered, plus the f32
+    A window, its rolled copies and the C accumulators."""
     max_ob = max(abs(o) for o in offs_b) if offs_b else 0
     tile = choose_tile(max_ob)
     if tile is None:
         return None
     itemsize = np.dtype(dtype).itemsize
-    vmem = (3 * nda + ndb + 2 * ndc) * tile * itemsize
-    while vmem > _VMEM_BUDGET and tile > TILE_MIN:
+
+    def vmem_of(t: int) -> int:
+        return (2 * (3 * nda + ndb + ndc) * t * itemsize
+                + (9 * nda + ndc) * t * 4)
+
+    while vmem_of(tile) > _VMEM_BUDGET and tile > TILE_MIN:
         tile //= 2
-        vmem //= 2
-    if tile < max_ob or vmem > _VMEM_BUDGET:
+    if tile < max_ob or vmem_of(tile) > _VMEM_BUDGET:
         return None
     return tile
 
 
 def dia_spgemm_maybe_pallas(a_data, b_data, offs_a, offs_b, offs_c,
                             shape_a, shape_b):
-    """Banded SpGEMM through the Pallas kernel, or None (XLA path)."""
+    """Banded SpGEMM through the Pallas kernel, or None when the kernel
+    does not serve these bands (the caller runs the XLA path)."""
     mode = _mode()
     if mode == "0":
         return None
@@ -694,62 +619,27 @@ def dia_spgemm_maybe_pallas(a_data, b_data, offs_a, offs_b, offs_c,
                                       np.dtype(jnp.bfloat16)):
         return None
     if a_data.dtype != b_data.dtype:
-        # The XLA fallback promotes to result_type(a, b); the kernel
-        # emits b's dtype — mixed inputs must not change result dtype
-        # by backend.
+        # The XLA path promotes to result_type(a, b); the kernel emits
+        # b's dtype — mixed inputs must not change result dtype by
+        # backend.
         return None
     interpret = mode == "interpret"
-    if not interpret:
-        try:
-            if jax.devices()[0].platform != "tpu":
-                return None
-        except Exception:
-            return None
+    if not interpret and not _on_tpu():
+        return None
     tile = _spgemm_tile(offs_b, len(offs_a), len(offs_b), len(offs_c),
                         a_data.dtype)
     if tile is None:
         return None
-    key = (offs_a, offs_b, tile, str(a_data.dtype), shape_a, shape_b,
-           interpret)
-    if key in _SPGEMM_FAILED:
-        return None
-    if key not in _SPGEMM_OK:
-        try:
-            from jax._src.core import trace_state_clean
-
-            if not trace_state_clean():
-                return None
-        except ImportError:
-            return None
-    try:
-        with _obs.span("pallas.spgemm", tile=tile,
-                       num_diags_c=len(offs_c)):
-            C = pallas_dia_spgemm(a_data, b_data, offs_a, offs_b,
-                                  offs_c, shape_a, shape_b, tile,
-                                  interpret=interpret)
-        _SPGEMM_OK.add(key)
-        return C
-    except Exception as e:
-        import sys
-
-        sys.stderr.write(
-            f"legate_sparse_tpu: pallas DIA SpGEMM unavailable "
-            f"({e!r:.200}); using XLA path\n"
-        )
-        _obs.inc("op.pallas_fallback.spgemm")
-        _obs.event("pallas.fallback", kernel="spgemm",
-                   error=repr(e)[:200])
-        _SPGEMM_FAILED.add(key)
-        return None
+    with _obs.span("pallas.spgemm", tile=tile, num_diags_c=len(offs_c)):
+        return pallas_dia_spgemm(a_data, b_data, offs_a, offs_b, offs_c,
+                                 shape_a, shape_b, tile,
+                                 interpret=interpret)
 
 
-# Runtime dispatch gate: default ON for TPU backends (the measured 7.5x
-# over the XLA path), opt out with LEGATE_SPARSE_TPU_PALLAS_DIA=0.
-# "interpret" forces the interpret-mode kernel on CPU (differential
-# testing of the exact kernel logic without a chip).
-_FAILED: set = set()
-
-
+# Runtime dispatch gate: default ON for TPU backends, opt out with
+# LEGATE_SPARSE_TPU_PALLAS_DIA=0.  "interpret" forces the
+# interpret-mode kernel on CPU (differential testing of the exact
+# kernel logic without a chip).
 def _mode() -> str:
     return os.environ.get("LEGATE_SPARSE_TPU_PALLAS_DIA", "1")
 
@@ -762,10 +652,7 @@ def pallas_dist_mode() -> str:
     v = os.environ.get("LEGATE_SPARSE_TPU_PALLAS_DIST")
     if v is not None:
         return v
-    try:
-        return "1" if jax.devices()[0].platform == "tpu" else "0"
-    except Exception:
-        return "0"
+    return "1" if _on_tpu() else "0"
 
 
 def pallas_dia_active() -> bool:
@@ -774,49 +661,26 @@ def pallas_dia_active() -> bool:
     mode = _mode()
     if mode == "0":
         return False
-    if mode == "interpret":
-        return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return mode == "interpret" or _on_tpu()
 
 
 def dia_spmv_maybe_pallas(packed, x):
     """Run the Pallas kernel from a ``PackedBand``, or return None so
-    the caller uses the XLA fallback path."""
+    the caller uses the XLA path (no pack, kernel off, or not on TPU).
+    A pack exists only for bands ``supported`` accepted, so the kernel
+    chosen here runs or raises."""
     mode = _mode()
     if mode == "0" or packed is None:
         return None
     interpret = mode == "interpret"
-    if not interpret:
-        try:
-            if jax.devices()[0].platform != "tpu":
-                return None
-        except Exception:
-            return None
-    key = (packed.offsets, packed.tile, str(packed.rdata.dtype), interpret)
-    if key in _FAILED:
+    if not interpret and not _on_tpu():
         return None
-    try:
-        with _obs.span("pallas.spmv", tile=packed.tile,
-                       num_diags=len(packed.offsets)):
-            return pallas_dia_spmv(
-                packed.rdata, packed.rmask, x, packed.offsets,
-                packed.shape, packed.tile, interpret=interpret,
-            )
-    except Exception as e:  # lowering/compile failure -> XLA fallback
-        import sys
-
-        sys.stderr.write(
-            f"legate_sparse_tpu: pallas DIA kernel unavailable "
-            f"({e!r:.200}); using XLA path\n"
+    with _obs.span("pallas.spmv", tile=packed.tile,
+                   num_diags=len(packed.offsets)):
+        return pallas_dia_spmv(
+            packed.rdata, packed.rmask, x, packed.offsets,
+            packed.shape, packed.tile, interpret=interpret,
         )
-        _obs.inc("op.pallas_fallback.spmv")
-        _obs.event("pallas.fallback", kernel="spmv",
-                   error=repr(e)[:200])
-        _FAILED.add(key)
-        return None
 
 
 class PackedBand:
@@ -835,46 +699,13 @@ class PackedBand:
 def pack_band(dia_data, offsets: Tuple[int, ...], shape: Tuple[int, int],
               mask=None) -> Optional[PackedBand]:
     """Build the kernel's layout from the scipy-layout DIA cache
-    (``csr_array._get_dia()`` output).  None when unsupported, or when
-    this band signature already failed to lower (skipping the pack: it
-    doubles band storage and would never be used)."""
+    (``csr_array._get_dia()`` output), or None when ``supported``
+    declines the band."""
     tile = supported(offsets, dia_data.dtype, mask is not None)
     if tile is None:
-        return None
-    interpret = _mode() == "interpret"
-    key = (offsets, tile, str(dia_data.dtype), interpret)
-    if key in _FAILED:
         return None
     rdata, rmask = row_align(
         dia_data, offsets, shape, tile,
         mask=mask, with_mask=mask is not None,
     )
-    packed = PackedBand(rdata, rmask, offsets, shape, tile)
-    # Validate the kernel lowers/compiles NOW, eagerly: a Mosaic failure
-    # surfacing later inside an outer jit (the solvers trace the whole
-    # solve as one while_loop) would escape dia_spmv_maybe_pallas's
-    # except and crash the solve with no fallback.  pack_band only runs
-    # outside traces (csr.py gates on _can_build_cache), so one eager
-    # probe matvec here is safe and costs a single kernel launch.  Only
-    # the real-chip compile needs this; direct interpret-mode users
-    # (tests) see failures at their own call site, and on non-TPU
-    # platforms the dispatch never uses the pack.
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:
-        on_tpu = False
-    if on_tpu and not interpret:
-        try:
-            x_probe = jnp.zeros((shape[1],), rdata.dtype)
-            pallas_dia_spmv(rdata, rmask, x_probe, offsets, shape, tile,
-                            interpret=False)
-        except Exception as e:
-            import sys
-
-            sys.stderr.write(
-                f"legate_sparse_tpu: pallas DIA kernel failed validation "
-                f"({e!r:.200}); using XLA path\n"
-            )
-            _FAILED.add(key)
-            return None
-    return packed
+    return PackedBand(rdata, rmask, offsets, shape, tile)

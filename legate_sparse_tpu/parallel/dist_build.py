@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..types import index_dtype
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .dist_csr import DistCSR

@@ -48,7 +48,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import obs as _obs
 from ..obs import comm as _comm
-from ._compat import shard_map
+from jax import shard_map
 from .mesh import (
     LAYOUT_1D_COL, LAYOUT_1D_ROW, LAYOUT_2D_BLOCK,
     make_grid_mesh, make_row_mesh, resolve_layout,

@@ -3,6 +3,7 @@
 """diags / dia_array tests (mirrors reference ``test_diags.py`` and the
 dia_array transpose/tocsr paths)."""
 
+import jax
 import numpy as np
 import pytest
 import scipy.sparse as scsp
@@ -81,3 +82,48 @@ def test_diags_integer_input_casts_to_float():
     assert np.issubdtype(ours.dtype, np.floating)
     y = np.asarray(ours @ np.ones(5, dtype=ours.dtype))
     np.testing.assert_allclose(y, [-1.0, 0.0, 0.0, 0.0, -1.0])
+
+
+_COMPILES = []
+
+
+def _count_compile(event, secs, **_):
+    if event == "/jax/core/compile/backend_compile_duration":
+        _COMPILES.append(secs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+@pytest.mark.parametrize(
+    "offsets,shape,hole",
+    [
+        (tuple(range(-5, 6)), (4099, 4099), False),   # the bench band
+        ((-3, 0, 2), (1031, 1031), True),             # explicit zeros
+        ((-2, 0, 7), (1033, 1500), False),            # wide
+        ((-7, 0, 2), (1500, 1037), False),            # tall
+    ],
+)
+def test_diags_to_csr_is_two_programs(offsets, shape, hole):
+    # The user's band build (bench.py _banded_config): a handful of
+    # compiled programs, not one per eager op — on the chip each
+    # compile costs ~2 s and the eager DIA->CSR path compiled ~60.
+    rows, cols = shape
+    rng = np.random.default_rng(len(offsets) + rows)
+    diagonals = []
+    for o in offsets:
+        n = min(rows + min(o, 0), cols - max(o, 0))
+        d = rng.random(n).astype(np.float32) + np.float32(0.5)
+        if hole:
+            d[::5] = 0.0
+        diagonals.append(d)
+    before = len(_COMPILES)
+    ours = sparse.diags(diagonals, list(offsets), shape=shape,
+                        format="csr", dtype=np.float32)
+    assert len(_COMPILES) - before <= 2
+    theirs = scsp.diags(diagonals, list(offsets), shape=shape,
+                        format="csr", dtype=np.float32)
+    theirs.eliminate_zeros()
+    np.testing.assert_array_equal(np.asarray(ours.indptr), theirs.indptr)
+    np.testing.assert_array_equal(np.asarray(ours.indices), theirs.indices)
+    np.testing.assert_array_equal(np.asarray(ours.data), theirs.data)

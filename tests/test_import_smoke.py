@@ -56,13 +56,6 @@ def test_all_package_modules_import():
     assert "all-modules-ok" in r.stdout
 
 
-def test_shard_map_compat_resolves():
-    # The compat shim must hand back a callable on every supported jax.
-    from legate_sparse_tpu.parallel._compat import shard_map
-
-    assert callable(shard_map)
-
-
 @pytest.mark.slow
 def test_bench_importable():
     # bench.py is the driver contract surface; a syntax/import error
@@ -70,3 +63,40 @@ def test_bench_importable():
     r = _run("import bench; print('bench-ok')")
     assert r.returncode == 0, r.stderr[-2000:]
     assert "bench-ok" in r.stdout
+
+
+def _run_unpinned(code: str) -> subprocess.CompletedProcess:
+    """``code`` in a subprocess that names no platform, as a user's
+    shell does: no ``JAX_PLATFORMS``, no virtual device count."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    return subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=240, cwd=_REPO, env=env,
+    )
+
+
+def test_planverify_pins_its_mesh_without_platform_env():
+    # tools/planverify.py imports the package, then pins the 8-device
+    # CPU mesh its contract programs lower against.
+    r = _run_unpinned(
+        "import runpy\n"
+        "runpy.run_path('tools/planverify.py', run_name='planverify')\n"
+        "import jax\n"
+        "print(jax.default_backend(), len(jax.devices()))\n")
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split()[-2:] == ["cpu", "8"], r.stdout
+
+
+def test_pin_cpu_after_backend_init_gets_its_device_count():
+    r = _run_unpinned(
+        "import jax\n"
+        "jax.devices()\n"
+        "from legate_sparse_tpu._platform import pin_cpu, restore_platform\n"
+        "pin_cpu(4)\n"
+        "print(jax.default_backend(), len(jax.devices()),"
+        " jax.config.jax_enable_x64)\n"
+        "restore_platform()\n"
+        "print(jax.config.jax_num_cpu_devices)\n")
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == ["cpu", "4", "True", "-1"], r.stdout

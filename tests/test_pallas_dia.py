@@ -321,32 +321,6 @@ def test_dia_array_dispatch_interpret(rng, monkeypatch):
     np.testing.assert_allclose(Y, A_sp @ X, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("shape", [(64, 64), (5000, 5000), (300, 500),
-                                   (500, 300)])
-def test_distinct_inputs_mode_matches_aliased(shape, rng, monkeypatch):
-    # LEGATE_SPARSE_TPU_PALLAS_INPUTS=distinct replaces the three
-    # aliased x operands + clamped index maps with tile-shifted copies
-    # and plain maps (the fault-isolation rung).  Semantics must be
-    # identical, including the zero edge tiles at the first/last grid
-    # steps and rectangular clamping.
-    n, m = shape
-    offsets = (-5, -1, 0, 1, 5)
-    A, A_sp = _banded(n, offsets, rng, m=m)
-    x = rng.standard_normal(m).astype(np.float32)
-    ref = _spmv_via_pallas(A, x)
-    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_INPUTS", "distinct")
-    # Env is read at trace time: a fresh shape/flag combination would
-    # hit the jit cache keyed only on shapes.  Clear to force retrace.
-    pallas_dia.pallas_dia_spmv.clear_cache()
-    try:
-        got = _spmv_via_pallas(A, x)
-    finally:
-        monkeypatch.undo()
-        pallas_dia.pallas_dia_spmv.clear_cache()
-    np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(ref, A_sp @ x, rtol=1e-4, atol=1e-4)
-
-
 @pytest.mark.parametrize("tile", [2048, 8192])
 def test_tile_override_matches_default(tile, rng, monkeypatch):
     # LEGATE_SPARSE_TPU_PALLAS_TILE changes the grid length (fault
@@ -382,44 +356,3 @@ def test_tile_override_over_vmem_budget_degrades_to_auto(monkeypatch):
     offsets = tuple(range(-100, 101))       # 201 diagonals
     tile = pallas_dia.supported(offsets, np.float32, masked=True)
     assert tile == pallas_dia.TILE_MIN
-
-
-def test_distinct_inputs_spmm_and_spgemm_match(rng, monkeypatch):
-    # The de-aliased input mode now covers the SpMM and banded-SpGEMM
-    # kernels too (no XLA fallback under the shift3 variant).
-    n = 3000
-    offsets = (-5, -1, 0, 1, 5)
-    A, A_sp = _banded(n, offsets, rng)
-    X = rng.standard_normal((n, 4)).astype(np.float32)
-
-    dd, offs, mask = A._get_dia()
-    packed = pallas_dia.pack_band(dd, offs, A.shape, mask=mask)
-    tile = pallas_dia._spmm_tile(packed, 4)
-    ref_mm = np.asarray(pallas_dia.pallas_dia_spmm(
-        packed.rdata, packed.rmask, jnp.asarray(X), packed.offsets,
-        packed.shape, tile, interpret=True))
-
-    offs_c = tuple(sorted({a + b for a in offs for b in offs}))
-    sg_tile = pallas_dia._spgemm_tile(offs, len(offs), len(offs),
-                                      len(offs_c), dd.dtype)
-    ref_gg = np.asarray(pallas_dia.pallas_dia_spgemm(
-        dd, dd, offs, offs, offs_c, A.shape, A.shape, sg_tile,
-        interpret=True))
-
-    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_INPUTS", "distinct")
-    pallas_dia.pallas_dia_spmm.clear_cache()
-    pallas_dia.pallas_dia_spgemm.clear_cache()
-    try:
-        got_mm = np.asarray(pallas_dia.pallas_dia_spmm(
-            packed.rdata, packed.rmask, jnp.asarray(X), packed.offsets,
-            packed.shape, tile, interpret=True))
-        got_gg = np.asarray(pallas_dia.pallas_dia_spgemm(
-            dd, dd, offs, offs, offs_c, A.shape, A.shape, sg_tile,
-            interpret=True))
-    finally:
-        monkeypatch.undo()
-        pallas_dia.pallas_dia_spmm.clear_cache()
-        pallas_dia.pallas_dia_spgemm.clear_cache()
-    np.testing.assert_allclose(got_mm, ref_mm, rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(got_gg, ref_gg, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(ref_mm, A_sp @ X, rtol=1e-4, atol=1e-4)

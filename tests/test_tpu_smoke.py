@@ -100,34 +100,9 @@ def test_pallas_dia_spmm_on_chip(accel):
     np.testing.assert_allclose(Y, A.toscipy() @ X, rtol=1e-5, atol=1e-5)
 
 
-def test_pallas_dia_shift3_variant_on_chip(accel, monkeypatch):
-    """The de-aliased input variant (canary-ladder rung 2) lowers and
-    matches scipy on the real chip."""
-    from legate_sparse_tpu.ops import pallas_dia
-
-    A = _poisson(32)
-    dia = A._get_dia()
-    dia_data, offsets, mask = dia
-    packed = pallas_dia.pack_band(dia_data, offsets, A.shape, mask=mask)
-    assert packed is not None
-    x = np.linspace(-1.0, 1.0, A.shape[0]).astype(np.float32)
-    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_INPUTS", "distinct")
-    pallas_dia.pallas_dia_spmv.clear_cache()
-    try:
-        y = np.asarray(pallas_dia.pallas_dia_spmv(
-            packed.rdata, packed.rmask, x, packed.offsets, packed.shape,
-            packed.tile, interpret=False,
-        ))
-    finally:
-        monkeypatch.undo()
-        pallas_dia.pallas_dia_spmv.clear_cache()
-    np.testing.assert_allclose(y, A.toscipy() @ x, rtol=1e-5, atol=1e-5)
-
-
 def test_fused_xla_band_fallback_on_chip(accel):
-    """The ladder's final rung (dia_spmv_fused) runs on-chip and
-    matches scipy — the path the bench lands on if every Pallas
-    variant faults."""
+    """The fused XLA band SpMV (dia_spmv_fused, the path with the
+    Pallas kernel off) runs on-chip and matches scipy."""
     from legate_sparse_tpu.ops import dia_ops
 
     A = _poisson(32)

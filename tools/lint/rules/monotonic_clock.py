@@ -10,11 +10,9 @@ spuriously (or never) when the clock jumps.  This rule keeps the ban
 from regressing: any ``time.time()`` call inside
 ``legate_sparse_tpu/`` is a finding.
 
-The one legitimate use is comparing against *file* timestamps —
-``_platform.py``'s probe-cache TTL compares to an ``st['ts']`` it
-itself recorded as wall-clock epoch seconds, shared with the external
-``tunnel_watch.sh``.  That site carries an inline justified
-suppression, which is exactly the documentation the exception needs.
+A legitimate use (comparing against wall-clock *file* timestamps)
+carries an inline justified suppression, which is exactly the
+documentation the exception needs.
 """
 
 from __future__ import annotations

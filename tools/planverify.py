@@ -16,6 +16,10 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
+# Name the CPU before the package import: it resolves its x64 policy
+# from the platform, which would otherwise start JAX's default backend.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 from legate_sparse_tpu._platform import pin_cpu  # noqa: E402
 
 from tools.verify import catalog  # noqa: E402

@@ -308,7 +308,7 @@ def _psum_built(elems_per_shard: int = 1) -> Built:
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from legate_sparse_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from legate_sparse_tpu.parallel.mesh import ROW_AXIS
 
     mesh = _probe_mesh()
