@@ -1,0 +1,8 @@
+"""Percent of the traced window in which the device ran nothing while
+the host was inside the library's ``cg`` span."""
+
+from benchmark import spans
+
+
+def read(run):
+    return spans.idle_in_spans(run, "cg")

@@ -25,6 +25,7 @@ TPU-first re-design (not a port):
 
 from __future__ import annotations
 
+import functools
 import warnings
 from typing import Optional, Tuple
 
@@ -67,6 +68,15 @@ def _is_sparse_like(obj) -> bool:
     """Sparse-format object of this package or another library (has a
     CSR conversion, is not dense-array-like)."""
     return hasattr(obj, "tocsr") and not hasattr(obj, "__array__")
+
+
+@functools.lru_cache(maxsize=None)
+def _result_itemsize(a_dtype, x_dtype, x64: bool) -> int:
+    """Bytes of one element of ``A @ x``'s result (JAX promotion, which
+    depends on the 64-bit mode).  Cached: the promotion lattice costs
+    microseconds, and the traffic model runs inside every live
+    ``spmv`` span."""
+    return jnp.dtype(jnp.result_type(a_dtype, x_dtype)).itemsize
 
 
 def _dia_xla_nopad() -> bool:
@@ -222,6 +232,7 @@ class csr_array(CompressedBase, DenseSparseBase):
         # the row-binned sliced-ELL pack (False = tried, not viable).
         self._fingerprint = None
         self._sliced_ell = None
+        self._traffic = {}
         self.shape: Tuple[int, int] = tuple(int(s) for s in shape)
         assert self._indptr.shape[0] == self.shape[0] + 1, (
             f"indptr length {self._indptr.shape[0]} != rows+1 "
@@ -846,6 +857,7 @@ class csr_array(CompressedBase, DenseSparseBase):
         self._engine_pack = None
         self._fingerprint = None
         self._sliced_ell = None
+        self._traffic = {}
 
     def sort_indices(self):
         """Sort column indices within each row in place (stable; no
@@ -871,6 +883,7 @@ class csr_array(CompressedBase, DenseSparseBase):
         self._engine_pack = None
         self._fingerprint = None  # block_score reads stored-entry order
         self._sliced_ell = None
+        self._traffic = {}
 
     def power(self, n, dtype=None):
         """Element-wise power (scipy semantics: duplicates are summed
@@ -1460,9 +1473,10 @@ class csr_array(CompressedBase, DenseSparseBase):
                     )
                     path = "csr"
                 if sp is not None:
-                    sp.set(path=path, rows=self.shape[0], nnz=self.nnz,
+                    nnz = self.nnz
+                    sp.set(path=path, rows=self.shape[0], nnz=nnz,
                            bytes=A.spmv_traffic_bytes(x, path=path),
-                           flops=2 * self.nnz)
+                           flops=2 * nnz)
             if squeeze:
                 y = y[:, None]
             return fill_out(y, out)
@@ -1590,6 +1604,18 @@ class csr_array(CompressedBase, DenseSparseBase):
         spans both do); an uncached matrix falls through to the CSR
         gather model.
         """
+        if path is None:
+            return self._traffic_model(x, None)
+        # Once per (path, operand) pair: the live ``spmv`` span asks on
+        # every call, and the structure caches the model reads change
+        # only where ``_traffic`` is reset with them.
+        key = (path, x.shape, x.dtype)
+        got = self._traffic.get(key)
+        if got is None:
+            got = self._traffic[key] = self._traffic_model(x, path)
+        return got
+
+    def _traffic_model(self, x, path) -> int:
         n = self.shape[0]
         if path in ("csr-rowids-bf16", "ell-bf16", "sliced-ell-bf16"):
             # The f32-accumulation variants stream the same blocks as
@@ -1598,8 +1624,8 @@ class csr_array(CompressedBase, DenseSparseBase):
             # already priced.
             path = path[: -len("-bf16")]
         x_bytes = int(x.size) * x.dtype.itemsize
-        out_bytes = n * jnp.dtype(
-            jnp.result_type(self.dtype, x.dtype)).itemsize
+        out_bytes = n * _result_itemsize(self.dtype, np.dtype(x.dtype),
+                                         jax.config.jax_enable_x64)
         if x.ndim == 2:
             out_bytes *= int(x.shape[1])
         # Caches use the False sentinel for "tried, not applicable".
@@ -1662,6 +1688,7 @@ class csr_array(CompressedBase, DenseSparseBase):
         self._bsr = None
         self._engine_pack = None
         self._sliced_ell = None  # packs values, not just structure
+        self._traffic = {}
         if structure_changed:
             self._row_ids = None
             self._ell_width = None

@@ -26,7 +26,11 @@ SpMV-only, so batched sources fall back to a per-source loop there.)
 
 Counters: ``graph.<alg>.runs`` / ``graph.<alg>.iters`` plus the
 ``graph.dist_spmv.<semiring>`` family rows from the dispatch layer;
-all under the ``graph.*`` prefix (docs/OBSERVABILITY.md).
+all under the ``graph.*`` prefix (docs/OBSERVABILITY.md).  Spans: a
+``graph.<alg>`` span covers the whole call, ``graph.build`` the
+operator build inside it, ``graph.sweep`` each BFS sweep; the edge
+fetch, each BFS sweep's scalar and the returned levels count under
+``transfer.host_sync.*``.
 """
 
 from __future__ import annotations
@@ -48,6 +52,8 @@ def _edge_arrays(csgraph, directed: bool, unweighted: bool):
     from ..csgraph import _graph_edges
 
     rows, cols, w, n = _graph_edges(csgraph, directed, unweighted)
+    # rows, cols and w come from the device: three blocking fetches.
+    _obs.inc("transfer.host_sync.graph_edges", 3)
     return (np.asarray(rows, dtype=np.int64),
             np.asarray(cols, dtype=np.int64),
             np.asarray(w), n)
@@ -95,6 +101,17 @@ def _shard_operator(op, mesh, layout):
     return _dc.shard_csr(op, mesh=mesh, layout=layout)
 
 
+def _build(csgraph, directed: bool, unweighted: bool, mesh, layout,
+           zero_weights: bool = False):
+    """``(op, dA, n)``: the push operator and its sharded form, built
+    under one ``graph.build`` span (edge fetch, host symmetrize and
+    lexsort, CSR, shard upload)."""
+    with _obs.span("graph.build"):
+        op, n = _push_operator(csgraph, directed, unweighted,
+                               zero_weights=zero_weights)
+        return op, _shard_operator(op, mesh, layout), n
+
+
 def _shard_vec(v, dA):
     from ..parallel import dist_csr as _dc
 
@@ -132,47 +149,30 @@ def bfs(csgraph, source=0, *, directed: bool = True, mesh=None,
 
     sources = np.atleast_1d(np.asarray(source, dtype=np.int64))
     scalar = np.ndim(source) == 0
-    op, n = _push_operator(csgraph, directed, unweighted=True)
-    if np.any((sources < 0) | (sources >= n)):
-        raise ValueError(f"bfs: source out of range for n={n}")
-    dA = _shard_operator(op, mesh, layout)
-    cap = _max_iters(n, max_iters)
-    _obs.inc("graph.bfs.runs")
     with _latency.timer("lat.graph.bfs"), \
-            _obs.span("graph.bfs", n=n, sources=int(sources.size),
-                      layout=dA.layout) as sp:
-        batched = sources.size > 1 and dA.grid is None
-        if batched:
-            F0 = np.zeros((n, sources.size), dtype=bool)
-            F0[sources, np.arange(sources.size)] = True
-            L0 = np.full((n, sources.size), -1, dtype=np.int32)
-            L0[sources, np.arange(sources.size)] = 0
-            f = _shard_mat(F0, dA)
-            levels = _shard_mat(L0, dA)
-            visited = f
-            spmv = lambda v: _dc.dist_spmm(dA, v, semiring="or-and")
-        else:
-            outs = []
-            for s in sources:
-                outs.append(_bfs_one(dA, int(s), n, cap))
-            lv = np.stack(outs) if not scalar else outs[0]
+            _obs.span("graph.bfs", sources=int(sources.size)) as sp:
+        _, dA, n = _build(csgraph, directed, True, mesh, layout)
+        if np.any((sources < 0) | (sources >= n)):
+            raise ValueError(f"bfs: source out of range for n={n}")
+        cap = _max_iters(n, max_iters)
+        _obs.inc("graph.bfs.runs")
+        if sp is not None:
+            sp.set(n=n, layout=dA.layout)
+        if sources.size == 1 or dA.grid is not None:
+            outs = [_bfs_one(dA, int(s), n, cap) for s in sources]
             if sp is not None:
                 sp.set(batched=False)
-            return lv
-        it = 0
-        while it < cap:
-            nxt = spmv(f)
-            new = jnp.logical_and(nxt, jnp.logical_not(visited))
-            if not bool(jnp.any(new)):
-                break
-            it += 1
-            levels = jnp.where(new, jnp.int32(it), levels)
-            visited = jnp.logical_or(visited, new)
-            f = new
-        _obs.inc("graph.bfs.iters", it)
+            return outs[0] if scalar else np.stack(outs)
+        F0 = np.zeros((n, sources.size), dtype=bool)
+        F0[sources, np.arange(sources.size)] = True
+        L0 = np.full((n, sources.size), -1, dtype=np.int32)
+        L0[sources, np.arange(sources.size)] = 0
+        levels, it = _sweeps(
+            lambda v: _dc.dist_spmm(dA, v, semiring="or-and"),
+            _shard_mat(F0, dA), _shard_mat(L0, dA), cap)
         if sp is not None:
             sp.set(iters=it, batched=True)
-    return np.asarray(levels)[:n].T
+        return _fetch_levels(levels, n).T
 
 
 def _bfs_one(dA, s: int, n: int, cap: int) -> np.ndarray:
@@ -182,20 +182,35 @@ def _bfs_one(dA, s: int, n: int, cap: int) -> np.ndarray:
     f0[s] = True
     l0 = np.full(n, -1, dtype=np.int32)
     l0[s] = 0
-    f = _shard_vec(f0, dA)
+    levels, _ = _sweeps(
+        lambda v: _dc.dist_spmv(dA, v, semiring="or-and"),
+        _shard_vec(f0, dA), _shard_vec(l0, dA), cap)
+    return _fetch_levels(levels, n)
+
+
+def _sweeps(spmv, f, levels, cap: int):
+    """``(levels, iters)``: frontier pushes from frontier ``f`` until a
+    sweep finds no new vertex or ``cap`` sweeps ran.  Each loop turn is
+    one ``graph.sweep`` span holding one semiring SpMV and its one
+    counted scalar fetch."""
     visited = f
-    levels = _shard_vec(l0, dA)
     it = 0
     while it < cap:
-        nxt = _dc.dist_spmv(dA, f, semiring="or-and")
-        new = jnp.logical_and(nxt, jnp.logical_not(visited))
-        if not bool(jnp.any(new)):
-            break
-        it += 1
-        levels = jnp.where(new, jnp.int32(it), levels)
-        visited = jnp.logical_or(visited, new)
-        f = new
+        with _obs.span("graph.sweep"):
+            new = jnp.logical_and(spmv(f), jnp.logical_not(visited))
+            _obs.inc("transfer.host_sync.bfs_sweep")
+            if not bool(jnp.any(new)):
+                break
+            it += 1
+            levels = jnp.where(new, jnp.int32(it), levels)
+            visited = jnp.logical_or(visited, new)
+            f = new
     _obs.inc("graph.bfs.iters", it)
+    return levels, it
+
+
+def _fetch_levels(levels, n: int) -> np.ndarray:
+    _obs.inc("transfer.host_sync.bfs_levels")
     return np.asarray(levels)[:n]
 
 
@@ -217,19 +232,19 @@ def sssp(csgraph, source=0, *, directed: bool = True,
 
     sources = np.atleast_1d(np.asarray(source, dtype=np.int64))
     scalar = np.ndim(source) == 0
-    op, n = _push_operator(csgraph, directed, unweighted)
-    if np.any((sources < 0) | (sources >= n)):
-        raise ValueError(f"sssp: source out of range for n={n}")
-    dA = _shard_operator(op, mesh, layout)
-    fdt = np.asarray(op.data).dtype
-    # Bellman-Ford terminates in n-1 relaxations on cycle-free
-    # distances; improvement at the n-th proves a negative cycle,
-    # so the cap is the detector, not a budget.
-    cap = n if max_iters is None else _max_iters(n, max_iters)
-    _obs.inc("graph.sssp.runs")
     with _latency.timer("lat.graph.sssp"), \
-            _obs.span("graph.sssp", n=n, sources=int(sources.size),
-                      layout=dA.layout) as sp:
+            _obs.span("graph.sssp", sources=int(sources.size)) as sp:
+        op, dA, n = _build(csgraph, directed, unweighted, mesh, layout)
+        if np.any((sources < 0) | (sources >= n)):
+            raise ValueError(f"sssp: source out of range for n={n}")
+        fdt = op.dtype
+        # Bellman-Ford terminates in n-1 relaxations on cycle-free
+        # distances; improvement at the n-th proves a negative cycle,
+        # so the cap is the detector, not a budget.
+        cap = n if max_iters is None else _max_iters(n, max_iters)
+        _obs.inc("graph.sssp.runs")
+        if sp is not None:
+            sp.set(n=n, layout=dA.layout)
         batched = sources.size > 1 and dA.grid is None
         if batched:
             D0 = np.full((n, sources.size), np.inf, dtype=fdt)
@@ -263,8 +278,8 @@ def sssp(csgraph, source=0, *, directed: bool = True,
         _obs.inc("graph.sssp.iters", it)
         if sp is not None:
             sp.set(iters=it, batched=batched)
-    out = np.asarray(dist)[:n]
-    return out.T if batched else (out if scalar else out[None, :])
+        out = np.asarray(dist)[:n]
+        return out.T if batched else (out if scalar else out[None, :])
 
 
 def connected_components(csgraph, *, mesh=None, layout=None,
@@ -282,13 +297,13 @@ def connected_components(csgraph, *, mesh=None, layout=None,
     """
     from ..parallel import dist_csr as _dc
 
-    op, n = _push_operator(csgraph, directed=False, unweighted=True,
-                           zero_weights=True)
-    dA = _shard_operator(op, mesh, layout)
-    cap = _max_iters(n, max_iters)
-    _obs.inc("graph.cc.runs")
-    with _latency.timer("lat.graph.cc"), \
-            _obs.span("graph.cc", n=n, layout=dA.layout) as sp:
+    with _latency.timer("lat.graph.cc"), _obs.span("graph.cc") as sp:
+        _, dA, n = _build(csgraph, False, True, mesh, layout,
+                          zero_weights=True)
+        cap = _max_iters(n, max_iters)
+        _obs.inc("graph.cc.runs")
+        if sp is not None:
+            sp.set(n=n, layout=dA.layout)
         labels = _shard_vec(np.arange(n, dtype=np.int32), dA)
         it = 0
         while it < cap:
@@ -301,7 +316,7 @@ def connected_components(csgraph, *, mesh=None, layout=None,
         _obs.inc("graph.cc.iters", it)
         if sp is not None:
             sp.set(iters=it)
-    lab = np.asarray(labels)[:n]
+        lab = np.asarray(labels)[:n]
     _, relabeled = np.unique(lab, return_inverse=True)
     return int(relabeled.max()) + 1 if n else 0, \
         relabeled.astype(np.int32)
@@ -332,34 +347,38 @@ def pagerank(csgraph, *, alpha: float = 0.85, tol: float = 1e-6,
     from ..parallel import dist_csr as _dc
     from ..settings import settings
 
-    rows, cols, w, n = _edge_arrays(csgraph, directed=True,
-                                    unweighted=True)
-    if n == 0:
-        return np.zeros(0)
-    # Dedupe (row, col) BEFORE the degree count: ``_csr_from_edges``
-    # keeps one representative per coordinate, so a multigraph edge
-    # list (e.g. raw R-MAT output) must not inflate outdeg or M's
-    # column sums drop below 1 and rank mass leaks every iteration.
-    uniq = np.unique(rows * n + cols)
-    rows, cols = uniq // n, uniq % n
-    outdeg = np.bincount(rows, minlength=n).astype(np.float64)
-    inv_out = np.zeros(n)
-    nz = outdeg > 0
-    inv_out[nz] = 1.0 / outdeg[nz]
-    M = _csr_from_edges(cols, rows, inv_out[rows], n)
-    dM = _shard_operator(M, mesh, layout)
-    fdt = np.asarray(M.data).dtype
-    cycle = int(conv_test_iters or settings.graph_conv_iters)
-    r = _shard_vec(np.full(n, 1.0 / n, dtype=fdt), dM)
-    dang = _shard_vec((~nz).astype(fdt), dM)
-    # Real-row mask: rows_padded > n tail rows must stay exactly 0 or
-    # the teleport term would leak rank mass into padding.
-    mask = _shard_vec(np.ones(n, dtype=fdt), dM)
-    inv_n = 1.0 / n
-    _obs.inc("graph.pagerank.runs")
-    it = 0
     with _latency.timer("lat.graph.pagerank"), \
-            _obs.span("graph.pagerank", n=n, layout=dM.layout) as sp:
+            _obs.span("graph.pagerank") as sp:
+        with _obs.span("graph.build"):
+            rows, cols, w, n = _edge_arrays(csgraph, directed=True,
+                                            unweighted=True)
+            if n == 0:
+                return np.zeros(0)
+            # Dedupe (row, col) BEFORE the degree count:
+            # ``_csr_from_edges`` keeps one representative per
+            # coordinate, so a multigraph edge list (e.g. raw R-MAT
+            # output) must not inflate outdeg or M's column sums drop
+            # below 1 and rank mass leaks every iteration.
+            uniq = np.unique(rows * n + cols)
+            rows, cols = uniq // n, uniq % n
+            outdeg = np.bincount(rows, minlength=n).astype(np.float64)
+            inv_out = np.zeros(n)
+            nz = outdeg > 0
+            inv_out[nz] = 1.0 / outdeg[nz]
+            M = _csr_from_edges(cols, rows, inv_out[rows], n)
+            dM = _shard_operator(M, mesh, layout)
+        fdt = M.dtype
+        cycle = int(conv_test_iters or settings.graph_conv_iters)
+        r = _shard_vec(np.full(n, 1.0 / n, dtype=fdt), dM)
+        dang = _shard_vec((~nz).astype(fdt), dM)
+        # Real-row mask: rows_padded > n tail rows must stay exactly 0
+        # or the teleport term would leak rank mass into padding.
+        mask = _shard_vec(np.ones(n, dtype=fdt), dM)
+        inv_n = 1.0 / n
+        _obs.inc("graph.pagerank.runs")
+        if sp is not None:
+            sp.set(n=n, layout=dM.layout)
+        it = 0
         while it < max_iters:
             r_prev = r
             for _ in range(cycle):
@@ -376,4 +395,4 @@ def pagerank(csgraph, *, alpha: float = 0.85, tol: float = 1e-6,
         _obs.inc("graph.pagerank.iters", it)
         if sp is not None:
             sp.set(iters=it)
-    return np.asarray(r)[:n]
+        return np.asarray(r)[:n]

@@ -585,6 +585,26 @@ def _cg_loop_resil(A_mv: Callable, M_mv: Callable, b, x0, atol: float,
     return state[0], state[4]
 
 
+def _cg_prologue(A, b, x0, tol, atol, rtol, M):
+    """What every CG path needs before its loop: the absolute
+    tolerance (from ‖b‖, one host sync), the wrapped operators, the
+    promoted right-hand side and the starting iterate."""
+    with _obs.span("cg.norm"):
+        _obs.inc("transfer.host_sync.cg_norm")
+        bnrm2 = float(jnp.linalg.norm(b))
+    atol, _ = _get_atol_rtol(bnrm2, tol, atol, rtol)
+    A_op = make_linear_operator(A)
+    b = _promote_rhs(b, A_op)
+    M_op = (
+        IdentityOperator(A_op.shape, dtype=A_op.dtype)
+        if M is None
+        else make_linear_operator(M)
+    )
+    x = (jnp.zeros(b.shape[0], dtype=b.dtype) if x0 is None
+         else jnp.asarray(x0, dtype=b.dtype).reshape(-1))
+    return atol, A_op, b, M_op, x
+
+
 def cg(
     A,
     b,
@@ -617,23 +637,40 @@ def cg(
         b = b.reshape(-1)
     assert b.ndim == 1
     assert len(A.shape) == 2 and A.shape[0] == A.shape[1]
-
-    bnrm2 = float(jnp.linalg.norm(b))
-    atol, _ = _get_atol_rtol(bnrm2, tol, atol, rtol)
     n = b.shape[0]
     if maxiter is None:
         maxiter = n * 10
 
-    A_op = make_linear_operator(A)
-    b = _promote_rhs(b, A_op)
-    M_op = (
-        IdentityOperator(A_op.shape, dtype=A_op.dtype)
-        if M is None
-        else make_linear_operator(M)
-    )
-    x = (jnp.zeros(n, dtype=b.dtype) if x0 is None
-         else jnp.asarray(x0, dtype=b.dtype).reshape(-1))
+    if refine is None and callback is None:
+        _obs.inc("op.cg")
+        with _lat.timer("lat.cg.solve." + _lat.shape_bucket(n)), \
+                _obs.span("cg", n=n, maxiter=int(maxiter)) as sp:
+            with _obs.span("cg.launch"):
+                atol, A_op, b, M_op, x = _cg_prologue(A, b, x0, tol,
+                                                      atol, rtol, M)
+                loop = (_cg_loop_resil if _resil_solver_active()
+                        else _cg_loop)
+                xs, iters = loop(
+                    A_op.matvec, M_op.matvec, b, x, atol, int(maxiter),
+                    int(conv_test_iters),
+                )
+            if sp is not None:
+                # Tracing mode trades one host sync for honest span
+                # timing (the fetch is the only trusted completion
+                # signal on detached-dispatch backends) and records
+                # the true iteration count + per-iter traffic model.
+                # It is not counted under transfer.host_sync.*: the
+                # counted syncs are the same with tracing on and off.
+                it = int(iters)
+                sp.set(iters=it)
+                src = getattr(A_op, "A", None)
+                if isinstance(src, csr_array):
+                    sp.set(nnz=src.nnz * it,
+                           bytes=src.spmv_traffic_bytes(b) * it,
+                           flops=2 * src.nnz * it)
+        return xs, iters
 
+    atol, A_op, b, M_op, x = _cg_prologue(A, b, x0, tol, atol, rtol, M)
     if refine is not None:
         if M is not None or callback is not None:
             raise ValueError(
@@ -651,29 +688,6 @@ def cg(
             atol, int(maxiter), _refine_cycles(refine))
 
     _obs.inc("op.cg")
-    if callback is None:
-        with _lat.timer("lat.cg.solve." + _lat.shape_bucket(n)), \
-                _obs.span("cg", n=n, maxiter=int(maxiter)) as sp:
-            loop = (_cg_loop_resil if _resil_solver_active()
-                    else _cg_loop)
-            xs, iters = loop(
-                A_op.matvec, M_op.matvec, b, x, atol, int(maxiter),
-                int(conv_test_iters),
-            )
-            if sp is not None:
-                # Tracing mode trades one host sync for honest span
-                # timing (the fetch is the only trusted completion
-                # signal on detached-dispatch backends) and records
-                # the true iteration count + per-iter traffic model.
-                it = int(iters)
-                sp.set(iters=it)
-                src = getattr(A_op, "A", None)
-                if isinstance(src, csr_array):
-                    sp.set(nnz=src.nnz * it,
-                           bytes=src.spmv_traffic_bytes(b) * it,
-                           flops=2 * src.nnz * it)
-        return xs, iters
-
     # Callback path: Python loop, one deferred pipeline per iteration.
     r = b - A_op.matvec(x)
     p = jnp.zeros_like(b)

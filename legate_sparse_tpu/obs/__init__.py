@@ -14,8 +14,9 @@ Twelve pieces (see each module's docstring for the design contract):
                  hits, jit cache misses) with a per-thread buffered
                  lock-free fast path (``counters.handle``) for
                  hot-loop sites.
-- ``report``   — aggregation into a per-op table with achieved GB/s
-                 against the measured stream roofline.
+- ``report``   — aggregation into a per-op table (calls, first-call
+                 vs steady-state time, nnz/bytes, GB/s over host span
+                 time).
 - ``latency``  — always-on streaming latency histograms (``lat.*``):
                  mergeable fixed-log2-bucket distributions with a
                  documented quantile error bound, written through the
@@ -61,7 +62,9 @@ like the other settings) or programmatically::
     print(obs.report.summarize(obs.records()))
 
 Disabled (the default) the span API is a no-op returning a shared
-null context manager; counters stay live either way.
+null context manager; counters stay live either way.  While a
+``jax.profiler`` trace runs, spans record and also annotate the
+profiler's trace (``trace`` module docstring).
 """
 
 from . import (  # noqa: F401

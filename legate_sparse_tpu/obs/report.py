@@ -5,9 +5,9 @@
 Turns the raw span stream (``trace.records()`` / a trace file) into
 the evidence table the review rounds kept asking for: per op —
 call count, first-call time (compile + execute), steady-state time,
-nnz/bytes/flops totals, achieved GB/s from the steady-state time, and
-the roofline fraction against the stream bandwidth ``bench.py``
-already measures.
+nnz/bytes/flops totals, and GB/s from the steady-state time.  That
+rate is over host span time, so it is no share of the device's
+bandwidth: device rooflines come from a ``jax.profiler`` trace.
 
 Per-op GB/s uses STEADY-STATE time only: first calls carry the jit
 compile, and mixing them in is exactly the "compile or kernel?"
@@ -160,15 +160,10 @@ def format_table(headers: List[str], rows: List[List[str]],
     return "\n".join(out)
 
 
-def render_table(agg: Dict[str, Dict[str, Any]],
-                 stream_gbs: Optional[float] = None) -> str:
-    """Pretty-print the aggregate as a fixed-width per-op table.
-    ``stream_gbs`` (the measured roofline from bench.py) adds a
-    ``vs_stream`` column: achieved fraction of the machine ceiling."""
+def render_table(agg: Dict[str, Dict[str, Any]]) -> str:
+    """Pretty-print the aggregate as a fixed-width per-op table."""
     headers = ["op", "calls", "total_ms", "first_ms", "steady_ms",
                "nnz", "bytes", "GB/s"]
-    if stream_gbs:
-        headers.append("vs_stream")
     rows = []
     order = sorted(agg.items(), key=lambda kv: -kv[1]["total_ms"])
     for name, row in order:
@@ -186,17 +181,13 @@ def render_table(agg: Dict[str, Dict[str, Any]],
             _fmt_count(row["bytes"]),
             _fmt(row["gbs"], "{:.2f}"),
         ]
-        if stream_gbs:
-            frac = (row["gbs"] / stream_gbs) if row["gbs"] else None
-            line.append(_fmt(frac, "{:.3f}"))
         rows.append(line)
     return format_table(headers, rows)
 
 
-def summarize(records: Iterable[Dict[str, Any]],
-              stream_gbs: Optional[float] = None) -> str:
+def summarize(records: Iterable[Dict[str, Any]]) -> str:
     """One-shot: aggregate + render."""
-    return render_table(aggregate(records), stream_gbs=stream_gbs)
+    return render_table(aggregate(records))
 
 
 def render_plans_table(counters: Dict[str, Any]) -> str:

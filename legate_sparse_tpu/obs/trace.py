@@ -19,13 +19,27 @@ them into the per-op table).
 
 Overhead contract
 -----------------
-Disabled (the default), ``span()`` touches one module global and
-returns a shared no-op context manager — no allocation, no clock read;
-the hot-path cost is building the kwargs dict at the call site
-(nanoseconds).  Tracing activates only via ``settings``/env
-(``LEGATE_SPARSE_TPU_OBS=1``) or an explicit ``enable()`` call.  This
-is what lets the spans live permanently in ``csr_array.dot`` and the
-solver loops without moving ``bench_wall_s``.
+Disabled (the default), ``span()`` touches one module global, asks
+``jax.profiler.TraceAnnotation.is_enabled()`` (a static flag, tens of
+nanoseconds) and returns a shared no-op context manager — no
+allocation, no clock read; the hot-path cost is building the kwargs
+dict at the call site (nanoseconds).  Tracing activates via
+``settings``/env (``LEGATE_SPARSE_TPU_OBS=1``), an explicit
+``enable()`` call, or a running ``jax.profiler`` trace.  This is what
+lets the spans live permanently in ``csr_array.dot`` and the solver
+loops without moving ``bench_wall_s``.
+
+Spans follow the profiler
+-------------------------
+While a ``jax.profiler`` trace is being collected
+(``jax.profiler.start_trace`` ... ``stop_trace``, or ``trace()``),
+spans, complete spans and events record into the buffer as if
+``enable()`` had been called — ``disable()`` does not stop them — and
+each context-manager span also opens a ``TraceAnnotation`` of its name
+for its lifetime, and each event a zero-length one, so the package's
+spans sit on the profiler's host plane, on the same clock as the
+device's ``XLA Ops``.  Buffered timestamps stay on
+``time.perf_counter_ns``.
 
 Compile-vs-execute split
 ------------------------
@@ -51,6 +65,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import attrib as _attrib
 from . import context as _context
 from . import counters as _counters
@@ -62,7 +78,12 @@ _ACCUMULATED_ATTRS = {"nnz": "obs.nnz_processed", "bytes": "obs.bytes_moved",
                       "flops": "obs.flops"}
 
 _lock = threading.Lock()
-_records: List[Dict[str, Any]] = []
+# Buffered records as flat tuples ``(type, name, ts_ns, dur_ns, depth,
+# seq, tid, attr items)`` (events: dur_ns, depth and seq None).  Tuples
+# of plain values leave the cyclic collector nothing to traverse, so a
+# buffer of a million spans does not slow every later collection;
+# ``records()`` builds the dicts.
+_records: List[tuple] = []
 _seq_by_name: Dict[str, int] = {}
 _tls = threading.local()
 
@@ -83,8 +104,13 @@ def _env_enabled() -> bool:
 _enabled: bool = _env_enabled()
 
 
+#: Is a ``jax.profiler`` trace being collected?  A static flag read.
+_profiling = _TraceAnnotation.is_enabled
+
+
 def enabled() -> bool:
-    """Fast hot-path check: is tracing on?"""
+    """Fast hot-path check: is tracing on (``enable()`` or the env
+    switch; a running profiler trace is asked separately)?"""
     return _enabled
 
 
@@ -95,7 +121,8 @@ def enable() -> None:
 
 
 def disable() -> None:
-    """Turn span/event recording off (buffered records are kept)."""
+    """Turn span/event recording off (buffered records are kept).  A
+    running ``jax.profiler`` trace still records until it stops."""
     global _enabled
     _enabled = False
 
@@ -114,73 +141,87 @@ def _depth_stack() -> list:
     return st
 
 
+def _accumulators() -> tuple:
+    """This thread's ``(attr key, counter handle)`` pairs for
+    :data:`_ACCUMULATED_ATTRS` (lock-free per-thread handles: spans
+    close on every dispatch)."""
+    acc = getattr(_tls, "acc", None)
+    if acc is None:
+        acc = _tls.acc = tuple((key, _counters.handle(name))
+                               for key, name in _ACCUMULATED_ATTRS.items())
+    return acc
+
+
 class Span:
     """One recorded operation.  Use via ``span()``; ``set()`` attaches
     attributes discovered while the op runs (kernel choice, output
     nnz)."""
 
-    __slots__ = ("name", "attrs", "_t0", "_depth")
+    __slots__ = ("name", "attrs", "_t0", "_stack", "_depth", "_ann")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
         self._t0 = 0
+        self._stack = None
         self._depth = 0
+        self._ann = None
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
         return self
 
     def __enter__(self) -> "Span":
-        st = _depth_stack()
+        st = self._stack = _depth_stack()
         self._depth = len(st)
         st.append(self.name)
+        if _profiling():
+            self._ann = _TraceAnnotation(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dur = time.perf_counter_ns() - self._t0
-        st = _depth_stack()
-        if st and st[-1] == self.name:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        name, attrs = self.name, self.attrs
+        st = self._stack
+        if st and st[-1] == name:
             st.pop()
         if exc_type is not None:
-            self.attrs["error"] = exc_type.__name__
+            attrs["error"] = exc_type.__name__
         # Causal auto-tag (obs/context.py): a span closed while a
         # trace context is active belongs to that request's flow arc.
         # Explicit trace ids (batch spans tagging their members) win.
-        if "trace_id" not in self.attrs and "trace_ids" not in self.attrs:
+        if "trace_id" not in attrs and "trace_ids" not in attrs:
             tid_ctx = _context.current_trace_id()
             if tid_ctx is not None:
-                self.attrs["trace_id"] = tid_ctx
+                attrs["trace_id"] = tid_ctx
+        items = tuple(attrs.items())
+        tid = threading.get_ident()
         with _lock:
-            seq = _seq_by_name.get(self.name, 0)
-            _seq_by_name[self.name] = seq + 1
-            if len(_records) >= MAX_RECORDS:
-                _counters.inc("obs.dropped_records")
-            else:
-                rec = {
-                    "type": "span",
-                    "name": self.name,
-                    "ts_ns": self._t0,
-                    "dur_ns": dur,
-                    "depth": self._depth,
-                    "seq": seq,
-                    "first": seq == 0,
-                    "tid": threading.get_ident(),
-                }
-                if self.attrs:
-                    rec["attrs"] = self.attrs
-                _records.append(rec)
+            seq = _seq_by_name.get(name, 0)
+            _seq_by_name[name] = seq + 1
+            kept = len(_records) < MAX_RECORDS
+            if kept:
+                _records.append(("span", name, self._t0, dur, self._depth,
+                                 seq, tid, items))
+        if not kept:
+            _counters.inc("obs.dropped_records")
         # Counter accumulation is independent of the span buffer: it
         # must keep counting even when overflow drops the records
         # (counters advertise process-lifetime totals).
-        for key, counter in _ACCUMULATED_ATTRS.items():
-            val = self.attrs.get(key)
-            if isinstance(val, (int, float)) and not isinstance(val, bool):
-                _counters.inc(counter, val)
+        if attrs:
+            for key, acc in _accumulators():
+                val = attrs.get(key)
+                if isinstance(val, (int, float)) and not isinstance(
+                        val, bool):
+                    acc.inc(val)
         # Per-tenant attribution (obs/attrib.py): dispatch busy spans
         # charge their wall time to the active tenant members.
-        _attrib.on_span_close(self.name, dur, seq == 0)
+        _attrib.on_span_close(name, dur, seq == 0)
 
 
 class _NullSpan:
@@ -204,11 +245,12 @@ _NULL_SPAN = _NullSpan()
 def span(name: str, **attrs: Any):
     """Context manager recording one operation.
 
-    Yields the live ``Span`` when tracing is enabled (so the body can
-    ``sp.set(...)`` late attributes) and ``None`` when disabled —
-    guard late-attribute work with ``if sp is not None``.
+    Yields the live ``Span`` when tracing is enabled or a
+    ``jax.profiler`` trace is being collected (so the body can
+    ``sp.set(...)`` late attributes) and ``None`` otherwise — guard
+    late-attribute work with ``if sp is not None``.
     """
-    if not _enabled:
+    if not _enabled and not _profiling():
         return _NULL_SPAN
     return Span(name, attrs)
 
@@ -219,8 +261,10 @@ def complete_span(name: str, start_ns: int, dur_ns: int,
     one thread and ends in another (the executor's ``engine.request``
     spans), where a context manager can't bracket it.  Takes the same
     per-name sequence slot and buffer-cap treatment as ``Span``;
-    ``depth`` is 0 (cross-thread lifecycles have no nesting stack)."""
-    if not _enabled:
+    ``depth`` is 0 (cross-thread lifecycles have no nesting stack).
+    Its interval is past when it is recorded, so it opens no profiler
+    annotation."""
+    if not _enabled and not _profiling():
         return
     if "trace_id" not in attrs and "trace_ids" not in attrs:
         tid_ctx = _context.current_trace_id()
@@ -232,26 +276,19 @@ def complete_span(name: str, start_ns: int, dur_ns: int,
         if len(_records) >= MAX_RECORDS:
             _counters.inc("obs.dropped_records")
             return
-        rec: Dict[str, Any] = {
-            "type": "span",
-            "name": name,
-            "ts_ns": int(start_ns),
-            "dur_ns": int(dur_ns),
-            "depth": 0,
-            "seq": seq,
-            "first": seq == 0,
-            "tid": threading.get_ident(),
-        }
-        if attrs:
-            rec["attrs"] = attrs
-        _records.append(rec)
+        _records.append(("span", name, int(start_ns), int(dur_ns), 0, seq,
+                         threading.get_ident(), tuple(attrs.items())))
 
 
 def event(name: str, **attrs: Any) -> None:
     """Record an instant (zero-duration) structured event — e.g. an
-    accelerator-probe failure, a collective-realization decline."""
-    if not _enabled:
+    accelerator-probe failure, a collective-realization decline.  Under
+    a running profiler trace it is also a zero-length annotation."""
+    if not _enabled and not _profiling():
         return
+    if _profiling():
+        with _TraceAnnotation(name):
+            pass
     if "trace_id" not in attrs:
         tid_ctx = _context.current_trace_id()
         if tid_ctx is not None:
@@ -260,23 +297,27 @@ def event(name: str, **attrs: Any) -> None:
         if len(_records) >= MAX_RECORDS:
             _counters.inc("obs.dropped_records")
             return
-        rec: Dict[str, Any] = {
-            "type": "event",
-            "name": name,
-            "ts_ns": time.perf_counter_ns(),
-            "tid": threading.get_ident(),
-        }
-        if attrs:
-            rec["attrs"] = rec_attrs = {}
-            for k, v in attrs.items():
-                rec_attrs[k] = v
-        _records.append(rec)
+        _records.append(("event", name, time.perf_counter_ns(), None, None,
+                         None, threading.get_ident(), tuple(attrs.items())))
+
+
+def _as_dict(r: tuple) -> Dict[str, Any]:
+    kind, name, ts, dur, depth, seq, tid, items = r
+    if kind == "span":
+        rec = {"type": kind, "name": name, "ts_ns": ts, "dur_ns": dur,
+               "depth": depth, "seq": seq, "first": seq == 0, "tid": tid}
+    else:
+        rec = {"type": kind, "name": name, "ts_ns": ts, "tid": tid}
+    if items:
+        rec["attrs"] = dict(items)
+    return rec
 
 
 def records() -> List[Dict[str, Any]]:
     """Snapshot of the buffered records (copy; safe to mutate)."""
     with _lock:
-        return [dict(r) for r in _records]
+        raw = list(_records)
+    return [_as_dict(r) for r in raw]
 
 
 def _json_default(obj: Any) -> Any:

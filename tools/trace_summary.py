@@ -6,14 +6,12 @@
 Reads either export format (Chrome-trace ``*.trace.json`` from
 ``bench.py`` / ``obs.write_chrome_trace``, or newline-JSON from
 ``obs.write_jsonl``) and renders the per-op aggregation: calls,
-total/first-call/steady-state time, nnz and bytes totals, achieved
-GB/s — and, given the measured stream roofline, the fraction of it
-each op reaches.
+total/first-call/steady-state time, nnz and bytes totals, and GB/s
+over host span time.
 
 Usage::
 
     python tools/trace_summary.py BENCH_20260804T120000.trace.json
-    python tools/trace_summary.py run.trace.json --stream-gbs 819
     python tools/trace_summary.py run.trace.json --events --counters
     python tools/trace_summary.py run.trace.json --comm
     python tools/trace_summary.py run.trace.json --plans
@@ -23,8 +21,6 @@ Usage::
     python tools/trace_summary.py run.trace.json --autotune
     python tools/trace_summary.py run.trace.json --flows --slo
 
-``--stream-gbs`` defaults to the ``stream_gbs`` recorded in the trace
-file's bench metadata when present (bench.py embeds its result blob).
 Exit status: 2 when the file contains no span records (the same
 "silent no-op wiring" condition bench.py guards against).
 """
@@ -133,10 +129,6 @@ def main(argv=None) -> int:
         description="Per-op table from a legate_sparse_tpu trace file."
     )
     ap.add_argument("trace_file", help="Chrome-trace or newline-JSON file")
-    ap.add_argument("--stream-gbs", type=float, default=None,
-                    help="measured stream (triad) bandwidth for the "
-                         "vs_stream roofline column; defaults to the "
-                         "value embedded by bench.py when present")
     ap.add_argument("--events", action="store_true",
                     help="also list instant events (probe failures, "
                          "layout decisions, window declines)")
@@ -205,16 +197,12 @@ def main(argv=None) -> int:
     records = report.load_records(args.trace_file)
     spans = [r for r in records if r.get("type") == "span"]
 
-    stream_gbs = args.stream_gbs
     meta = {}
     try:
         with open(args.trace_file) as f:
             doc = json.load(f)
         if isinstance(doc, dict):
             meta = doc.get("otherData", {}) or {}
-            if stream_gbs is None:
-                stream_gbs = (meta.get("bench_result") or {}).get(
-                    "stream_gbs")
     except (ValueError, OSError):
         pass  # newline-JSON / unreadable: no embedded metadata
 
@@ -224,8 +212,7 @@ def main(argv=None) -> int:
               f"(LEGATE_SPARSE_TPU_OBS=1)?", file=sys.stderr)
         return 2
 
-    print(report.render_table(report.aggregate(records),
-                              stream_gbs=stream_gbs))
+    print(report.render_table(report.aggregate(records)))
 
     if args.events:
         events = [r for r in records if r.get("type") == "event"]
