@@ -233,6 +233,9 @@ class csr_array(CompressedBase, DenseSparseBase):
         self._fingerprint = None
         self._sliced_ell = None
         self._traffic = {}
+        # Sharded graph push operators (graph/algorithms.py ``_build``),
+        # keyed by every input of the build besides this array.
+        self._graph_ops = None
         self.shape: Tuple[int, int] = tuple(int(s) for s in shape)
         assert self._indptr.shape[0] == self.shape[0] + 1, (
             f"indptr length {self._indptr.shape[0]} != rows+1 "
@@ -846,18 +849,10 @@ class csr_array(CompressedBase, DenseSparseBase):
         self._indptr = _convert.indptr_from_row_ids(
             new_rows, self.shape[0]
         )
-        self._row_ids = None
-        self._ell = None
-        self._ell_width = None
-        self._dia = None
-        self._dia_offsets = None
-        self._dia_pack = None
-        self._dia_fused = None
-        self._bsr = None
-        self._engine_pack = None
-        self._fingerprint = None
-        self._sliced_ell = None
-        self._traffic = {}
+        canonical, sorted_ = self._canonical, self._sorted
+        self._invalidate_caches(structure_changed=True)
+        # Dropping entries keeps each row's order and uniqueness.
+        self._canonical, self._sorted = canonical, sorted_
 
     def sort_indices(self):
         """Sort column indices within each row in place (stable; no
@@ -871,19 +866,10 @@ class csr_array(CompressedBase, DenseSparseBase):
         )
         self._data = data
         self._indices = indices
-        self._canonical = None
+        # block_score (the fingerprint) reads stored-entry order, so
+        # every structure cache goes; canonicalness is unknown again.
+        self._invalidate_caches(structure_changed=True)
         self._sorted = True
-        self._row_ids = None
-        self._ell = None
-        self._dia = None
-        self._dia_offsets = None
-        self._dia_pack = None
-        self._dia_fused = None
-        self._bsr = None
-        self._engine_pack = None
-        self._fingerprint = None  # block_score reads stored-entry order
-        self._sliced_ell = None
-        self._traffic = {}
 
     def power(self, n, dtype=None):
         """Element-wise power (scipy semantics: duplicates are summed
@@ -1689,6 +1675,7 @@ class csr_array(CompressedBase, DenseSparseBase):
         self._engine_pack = None
         self._sliced_ell = None  # packs values, not just structure
         self._traffic = {}
+        self._graph_ops = None  # the operators hold the weights too
         if structure_changed:
             self._row_ids = None
             self._ell_width = None

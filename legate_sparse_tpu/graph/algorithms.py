@@ -18,6 +18,16 @@ scalar per cycle (the solver modules' one-fetch-per-cycle cadence):
   column-normalized transpose, convergence checked every
   ``conv_test_iters`` iterations.
 
+The push operator of :func:`bfs`, :func:`sssp` and
+:func:`connected_components` is built once per graph and reused until
+the graph is mutated: a package ``csr_array`` keeps the sharded
+operator in its ``_graph_ops`` dict, keyed by every input of the build
+(direction, weighting, mesh, resolved layout and the settings the
+build reads), and every in-place mutator of the array clears it.  Any
+other input (scipy, dense, another format) is converted into a fresh
+array, so it is built on every call.  :func:`pagerank` builds its own
+column-normalized operator on every call.
+
 Multi-source BFS/SSSP batch their frontiers as one (rows, S) operand
 through ``dist_spmm(..., semiring=)`` — the distributed arm of the
 PR-8 stacked ``multi_matvec`` packing — so S sources cost one
@@ -28,9 +38,11 @@ Counters: ``graph.<alg>.runs`` / ``graph.<alg>.iters`` plus the
 ``graph.dist_spmv.<semiring>`` family rows from the dispatch layer;
 all under the ``graph.*`` prefix (docs/OBSERVABILITY.md).  Spans: a
 ``graph.<alg>`` span covers the whole call, ``graph.build`` the
-operator build inside it, ``graph.sweep`` each BFS sweep; the edge
-fetch, each BFS sweep's scalar and the returned levels count under
-``transfer.host_sync.*``.
+operator build inside it (attribute ``cached``: True when the graph's
+stored operator served the call, counted in ``graph.build.hits``; a
+build counts in ``graph.build.misses``), ``graph.sweep`` each BFS
+sweep; the edge fetch, each BFS sweep's scalar and the returned
+levels count under ``transfer.host_sync.*``.
 """
 
 from __future__ import annotations
@@ -101,15 +113,49 @@ def _shard_operator(op, mesh, layout):
     return _dc.shard_csr(op, mesh=mesh, layout=layout)
 
 
+def _build_key(directed: bool, unweighted: bool, zero_weights: bool,
+               mesh, layout) -> tuple:
+    """Every input of a build besides the graph itself: the arguments,
+    the resolved layout, and the settings the edge weights and
+    ``shard_csr`` read (value dtype, ELL budget, precise images, and
+    the band budgets of its DIA blocks)."""
+    from ..parallel.mesh import resolve_layout
+    from ..runtime import runtime
+    from ..settings import settings
+
+    return (directed, unweighted, zero_weights, mesh,
+            resolve_layout(layout), runtime.default_float,
+            settings.ell_max_expand, settings.precise_images,
+            settings.dia_max_expand, settings.dia_max_diags)
+
+
 def _build(csgraph, directed: bool, unweighted: bool, mesh, layout,
            zero_weights: bool = False):
-    """``(op, dA, n)``: the push operator and its sharded form, built
-    under one ``graph.build`` span (edge fetch, host symmetrize and
-    lexsort, CSR, shard upload)."""
-    with _obs.span("graph.build"):
+    """``(dA, n, dtype)``: the sharded push operator, its vertex count
+    and its value dtype, under one ``graph.build`` span.  A package
+    ``csr_array`` serves a stored operator under the same key; anything
+    else, or a miss, builds it (edge fetch, host symmetrize and
+    lexsort, CSR, shard upload) and stores it on a ``csr_array``."""
+    from ..csr import csr_array
+
+    cacheable = (isinstance(csgraph, csr_array)
+                 and csr_array._can_build_cache(
+                     csgraph._data, csgraph._indices, csgraph._indptr))
+    key = _build_key(directed, unweighted, zero_weights, mesh, layout)
+    built = (csgraph._graph_ops or {}).get(key) if cacheable else None
+    with _obs.span("graph.build", cached=built is not None):
+        if built is not None:
+            _obs.inc("graph.build.hits")
+            return built
+        _obs.inc("graph.build.misses")
         op, n = _push_operator(csgraph, directed, unweighted,
                                zero_weights=zero_weights)
-        return op, _shard_operator(op, mesh, layout), n
+        built = (_shard_operator(op, mesh, layout), n, op.dtype)
+        if cacheable:
+            if csgraph._graph_ops is None:
+                csgraph._graph_ops = {}
+            csgraph._graph_ops[key] = built
+        return built
 
 
 def _shard_vec(v, dA):
@@ -151,7 +197,7 @@ def bfs(csgraph, source=0, *, directed: bool = True, mesh=None,
     scalar = np.ndim(source) == 0
     with _latency.timer("lat.graph.bfs"), \
             _obs.span("graph.bfs", sources=int(sources.size)) as sp:
-        _, dA, n = _build(csgraph, directed, True, mesh, layout)
+        dA, n, _ = _build(csgraph, directed, True, mesh, layout)
         if np.any((sources < 0) | (sources >= n)):
             raise ValueError(f"bfs: source out of range for n={n}")
         cap = _max_iters(n, max_iters)
@@ -234,10 +280,9 @@ def sssp(csgraph, source=0, *, directed: bool = True,
     scalar = np.ndim(source) == 0
     with _latency.timer("lat.graph.sssp"), \
             _obs.span("graph.sssp", sources=int(sources.size)) as sp:
-        op, dA, n = _build(csgraph, directed, unweighted, mesh, layout)
+        dA, n, fdt = _build(csgraph, directed, unweighted, mesh, layout)
         if np.any((sources < 0) | (sources >= n)):
             raise ValueError(f"sssp: source out of range for n={n}")
-        fdt = op.dtype
         # Bellman-Ford terminates in n-1 relaxations on cycle-free
         # distances; improvement at the n-th proves a negative cycle,
         # so the cap is the detector, not a budget.
@@ -298,7 +343,7 @@ def connected_components(csgraph, *, mesh=None, layout=None,
     from ..parallel import dist_csr as _dc
 
     with _latency.timer("lat.graph.cc"), _obs.span("graph.cc") as sp:
-        _, dA, n = _build(csgraph, False, True, mesh, layout,
+        dA, n, _ = _build(csgraph, False, True, mesh, layout,
                           zero_weights=True)
         cap = _max_iters(n, max_iters)
         _obs.inc("graph.cc.runs")

@@ -333,3 +333,221 @@ def test_sssp_negative_cycle_raises():
     A = sparse.csr_array(sp.csr_array(D))
     with pytest.raises(Exception, match="[Nn]egative"):
         graph.sssp(A, 0)
+
+
+# ------------------------------------------------ push-operator cache --
+def _deltas(fn):
+    """``(fn(), counter deltas)``."""
+    before = counters.snapshot()
+    out = fn()
+    after = counters.snapshot()
+    return out, {k: v - before.get(k, 0) for k, v in after.items()
+                 if v != before.get(k, 0)}
+
+
+def _levels_ref(Sc, s, directed=True):
+    """BFS levels from scipy's unweighted shortest paths (-1 unreached)."""
+    d = scsg.dijkstra(Sc, directed=directed, indices=s, unweighted=True)
+    return np.where(np.isinf(d), -1, d).astype(np.int32)
+
+
+def _canonical_graph(n=64, density=0.06, seed=0):
+    Sc = _graph_csr(n, density, seed)
+    Sc.sum_duplicates()
+    Sc.sort_indices()
+    return Sc
+
+
+def test_second_bfs_on_one_graph_is_a_hit():
+    Sc = _canonical_graph(seed=81)
+    A = sparse.csr_array(Sc)
+    first, miss = _deltas(lambda: graph.bfs(A, 0))
+    second, hit = _deltas(lambda: graph.bfs(A, 0))
+    assert miss["graph.build.misses"] == 1
+    assert miss["transfer.host_sync.graph_edges"] == 3
+    assert miss["transfer.host_sync.shard_csr"] == 3
+    assert hit["graph.build.hits"] == 1
+    assert "graph.build.misses" not in hit
+    assert "transfer.host_sync.graph_edges" not in hit
+    assert "transfer.host_sync.shard_csr" not in hit
+    np.testing.assert_array_equal(first, _levels_ref(Sc, 0))
+    np.testing.assert_array_equal(second, first)
+
+
+def test_build_keys_keep_algorithms_apart():
+    """Direction, weighting and zero-weighting each key their own
+    operator; an unweighted SSSP has the directed BFS's exact build
+    inputs and so shares its entry."""
+    Sc = _canonical_graph(seed=83)
+    A = sparse.csr_array(Sc)
+    runs = {
+        "bfs-directed": (lambda: graph.bfs(A, 3),
+                         lambda: _levels_ref(Sc, 3)),
+        "bfs-undirected": (lambda: graph.bfs(A, 3, directed=False),
+                           lambda: _levels_ref(Sc, 3, directed=False)),
+        "sssp-weighted": (lambda: graph.sssp(A, 3),
+                          lambda: scsg.dijkstra(Sc, indices=3)),
+        "sssp-unweighted": (lambda: graph.sssp(A, 3, unweighted=True),
+                            lambda: scsg.dijkstra(Sc, indices=3,
+                                                  unweighted=True)),
+        "cc": (lambda: graph.connected_components(A)[1],
+               lambda: scsg.connected_components(Sc, directed=False)[1]),
+    }
+    for rnd in ("build", "reuse"):
+        for name, (run, ref) in runs.items():
+            got, d = _deltas(run)
+            want_hit = rnd == "reuse" or name == "sssp-unweighted"
+            assert d.get("graph.build.hits", 0) == int(want_hit), (rnd, name)
+            assert d.get("graph.build.misses", 0) == int(not want_hit), (
+                rnd, name)
+            if name == "cc":
+                pairs = set(zip(got.tolist(), ref().tolist()))
+                assert len(pairs) == len(set(got.tolist())) == \
+                    len(set(ref().tolist()))
+            else:
+                np.testing.assert_allclose(got, ref(), rtol=1e-6,
+                                           err_msg=f"{rnd} {name}")
+    assert len(A._graph_ops) == 4
+
+
+@needs_mesh
+def test_layout_setting_change_rebuilds(monkeypatch):
+    from legate_sparse_tpu.settings import settings
+
+    Sc = _canonical_graph(seed=85)
+    A = sparse.csr_array(Sc)
+    ref = _levels_ref(Sc, 1)
+    for layout, want in (("1d-row", "graph.build.misses"),
+                         ("2d-block", "graph.build.misses"),
+                         ("1d-row", "graph.build.hits")):
+        monkeypatch.setattr(settings, "dist_layout", layout)
+        lv, d = _deltas(lambda: graph.bfs(A, 1))
+        assert d.get(want) == 1 and sum(
+            d.get(k, 0) for k in ("graph.build.hits",
+                                  "graph.build.misses")) == 1, layout
+        np.testing.assert_array_equal(lv, ref)
+    assert sorted(dA.layout for dA, _, _ in A._graph_ops.values()) == \
+        ["1d-row", "2d-block"]
+
+
+def _mut_data(A, Sc):
+    w = np.random.default_rng(5).uniform(0.1, 9.0, Sc.nnz)
+    A.data = w.astype(np.asarray(A.data).dtype)
+    Sc.data = w
+    return Sc
+
+
+def _mut_indices(A, Sc):
+    idx = (Sc.indices + 1) % Sc.shape[1]
+    A.indices = idx
+    return sp.csr_array((Sc.data, idx, Sc.indptr), shape=Sc.shape)
+
+
+def _mut_setdiag(A, Sc):
+    n = Sc.shape[0]
+    A.setdiag(np.ones(n - 1), k=1)
+    L = sp.lil_array(Sc)
+    L.setdiag(np.ones(n - 1), k=1)
+    return L.tocsr()
+
+
+def _mut_eliminate_zeros(A, Sc):
+    A.eliminate_zeros()
+    Sc = Sc.copy()
+    Sc.eliminate_zeros()
+    return Sc
+
+
+def _mut_sort_indices(A, Sc):
+    A.sort_indices()
+    return Sc
+
+
+def _mut_sum_duplicates(A, Sc):
+    A.sum_duplicates()
+    return Sc
+
+
+def _mut_resize(A, Sc):
+    A.resize(48, 48)
+    return Sc[:48, :48].tocsr()
+
+
+def _half_zero_graph():
+    Sc = _canonical_graph(seed=87)
+    Sc.data[::2] = 0.0                   # stored zeros are edges
+    return sparse.csr_array(Sc), Sc
+
+
+def _reversed_rows_graph():
+    """Raw (data, indices, indptr) with every row's columns reversed."""
+    Sc = _canonical_graph(seed=89)
+    data, idx = Sc.data.copy(), Sc.indices.copy()
+    for r in range(Sc.shape[0]):
+        lo, hi = Sc.indptr[r], Sc.indptr[r + 1]
+        data[lo:hi], idx[lo:hi] = data[lo:hi][::-1], idx[lo:hi][::-1]
+    return sparse.csr_array((data, idx, Sc.indptr), shape=Sc.shape), Sc
+
+
+def _duplicated_graph():
+    """COO with every edge stored twice: the push operator keeps the
+    smaller copy, and ``sum_duplicates`` adds them."""
+    Sc = _canonical_graph(seed=91)
+    C = Sc.tocoo()
+    rows = np.concatenate([C.row, C.row])
+    cols = np.concatenate([C.col, C.col])
+    vals = np.concatenate([C.data, 2.0 * C.data])
+    A = sparse.csr_array((vals, (rows, cols)), shape=Sc.shape)
+    return A, sp.csr_array((vals, (rows, cols)), shape=Sc.shape)
+
+
+#: (search on a package array, its scipy twin) from vertex 0.
+_SEARCHES = {
+    "bfs": (lambda A: graph.bfs(A, 0), lambda S: _levels_ref(S, 0)),
+    "sssp": (lambda A: graph.sssp(A, 0),
+             lambda S: scsg.dijkstra(S, indices=0)),
+}
+
+
+def _plain_graph(seed):
+    def make():
+        Sc = _canonical_graph(seed=seed)
+        return sparse.csr_array(Sc), Sc
+    return make
+
+
+@pytest.mark.parametrize("mutate,make,alg,changes", [
+    (_mut_data, _plain_graph(93), "sssp", True),
+    (_mut_indices, _plain_graph(95), "bfs", True),
+    (_mut_setdiag, _plain_graph(97), "bfs", True),
+    (_mut_eliminate_zeros, _half_zero_graph, "bfs", True),
+    (_mut_sort_indices, _reversed_rows_graph, "bfs", False),
+    (_mut_sum_duplicates, _duplicated_graph, "sssp", True),
+    (_mut_resize, _plain_graph(99), "bfs", True),
+], ids=["data", "indices", "setdiag", "eliminate_zeros", "sort_indices",
+        "sum_duplicates", "resize"])
+def test_mutation_drops_the_stored_operator(mutate, make, alg, changes):
+    """Every in-place mutator clears ``_graph_ops``; the next search
+    rebuilds and matches scipy on the mutated graph."""
+    search, ref = _SEARCHES[alg]
+    A, Sc = make()
+    before = search(A)
+    assert A._graph_ops
+    Sc = mutate(A, Sc)
+    assert A._graph_ops is None
+    after, d = _deltas(lambda: search(A))
+    assert d.get("graph.build.misses") == 1 and "graph.build.hits" not in d
+    np.testing.assert_allclose(after, ref(Sc), rtol=1e-6)
+    assert (before.shape != after.shape
+            or not np.allclose(before, after)) == changes
+
+
+def test_scipy_and_dense_inputs_never_hit():
+    Sc = _canonical_graph(seed=101)
+    ref = _levels_ref(Sc, 2)
+    for G in (Sc, Sc.toarray()):
+        for _ in range(2):
+            lv, d = _deltas(lambda: graph.bfs(G, 2))
+            assert d.get("graph.build.misses") == 1
+            assert "graph.build.hits" not in d
+            np.testing.assert_array_equal(lv, ref)

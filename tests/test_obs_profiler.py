@@ -19,12 +19,16 @@ import legate_sparse_tpu as sparse
 from legate_sparse_tpu import graph, linalg, obs
 from legate_sparse_tpu.obs import counters, trace
 
-#: Blocking fetches of one single-root search beyond its sweeps' (one
-#: per sweep, the last finding nothing new): the returned levels (1),
-#: the build's edge fetch (3) and the host cut of ``shard_csr`` (3),
-#: plus the terminating sweep's scalar.  ``host_syncs.bfs`` minus
-#: ``bfs_sweeps`` in the benchmark reads this.
-BFS_SYNCS_BEYOND_SWEEPS = 8
+#: Blocking fetches of one single-root search on a graph whose push
+#: operator is already built, beyond its sweeps' (one per sweep, the
+#: last finding nothing new): the returned levels (1) plus the
+#: terminating sweep's scalar.  ``host_syncs.bfs`` minus ``bfs_sweeps``
+#: in the benchmark reads this.
+BFS_SYNCS_BEYOND_SWEEPS = 2
+
+#: What the first search of a graph adds: the build's edge fetch (3)
+#: and the host cut of ``shard_csr`` (3).
+BFS_BUILD_SYNCS = 6
 
 
 @pytest.fixture(autouse=True)
@@ -171,18 +175,43 @@ def test_bfs_span_tree_and_sync_counts(tmp_path):
     (top,) = _named(spans, "graph.bfs")
     kids = [r["name"] for r in _children(spans, top)]
     assert kids.count("graph.build") == 1
+    (build,) = _named(spans, "graph.build")
+    assert build["attrs"]["cached"] is True
     assert kids.count("graph.sweep") == iters + 1
     assert len(_named(spans, "graph.sweep")) == iters + 1
+    assert _host_syncs(off) == _host_syncs(on) == {
+        "transfer.host_sync.bfs_sweep": iters + 1,
+        "transfer.host_sync.bfs_levels": 1,
+    }
+    assert off["graph.build.hits"] == on["graph.build.hits"] == 1
+    assert "graph.build.misses" not in off and "graph.build.misses" not in on
+    assert (sum(_host_syncs(on).values()) - iters
+            == BFS_SYNCS_BEYOND_SWEEPS)
+    assert {"graph.bfs", "graph.build", "graph.sweep"} <= \
+        _host_event_names(tmp_path)
+
+
+def test_bfs_first_search_builds_and_counts_its_fetches(tmp_path):
+    """A fresh graph's first search is a miss: its build fetches the
+    edges (3 arrays) and cuts the shards on the host (3 more)."""
+    graph.bfs(_graph(), 0, directed=False)   # compile outside
+    _, _, off = _measure(lambda: graph.bfs(_graph(), 0, directed=False))
+    _, spans, on = _measure(lambda: graph.bfs(_graph(), 0, directed=False),
+                            tmp_path)
+    iters = on["graph.bfs.iters"]
+    assert iters == off["graph.bfs.iters"] > 1
+    (build,) = _named(spans, "graph.build")
+    assert build["attrs"]["cached"] is False
     assert _host_syncs(off) == _host_syncs(on) == {
         "transfer.host_sync.bfs_sweep": iters + 1,
         "transfer.host_sync.bfs_levels": 1,
         "transfer.host_sync.graph_edges": 3,
         "transfer.host_sync.shard_csr": 3,
     }
+    assert off["graph.build.misses"] == on["graph.build.misses"] == 1
+    assert "graph.build.hits" not in off and "graph.build.hits" not in on
     assert (sum(_host_syncs(on).values()) - iters
-            == BFS_SYNCS_BEYOND_SWEEPS)
-    assert {"graph.bfs", "graph.build", "graph.sweep"} <= \
-        _host_event_names(tmp_path)
+            == BFS_SYNCS_BEYOND_SWEEPS + BFS_BUILD_SYNCS)
 
 
 def test_graph_build_sits_under_each_algorithm(tmp_path):
