@@ -2529,31 +2529,32 @@ def dist_cg(
     headline GMG-preconditioned configuration, ``examples/gmg.py:104-143``).
     Returns the solution truncated to the unpadded length, plus the
     iteration count.
-    """
-    from ..linalg import (
-        _cg_loop, _cg_loop_resil, _get_atol_rtol, _resil_solver_active,
-    )
 
-    _obs.inc("op.dist_cg")
-    rows, b_sh, x0_sh, maxiter, cb = _shard_system(
-        A, b, x0, maxiter, callback
-    )
-    if x0_sh is None:
-        x0_sh = jnp.zeros_like(b_sh)
-    bnrm2 = float(jnp.linalg.norm(b_sh))
-    atol, _ = _get_atol_rtol(bnrm2, tol, atol, rtol)
-    M_mv = M if M is not None else (lambda r: r)
+    Without a callback the solve records the span tree of
+    ``linalg.cg``: ``dist_cg`` ⊃ ``dist_cg.launch`` (sharding, the
+    ‖b‖ fetch, the operator wrap, the loop's trace and enqueue) ⊃
+    ``dist_cg.norm`` (the fetch, one ``transfer.host_sync.dist_cg_norm``).
+    """
+    from ..linalg import _cg_loop, _cg_loop_resil, _resil_solver_active
     from ..obs import comm as _comm
     from ..obs import memory as _mem
 
-    item = jnp.dtype(b_sh.dtype).itemsize
-    if callback is None:
-        with _tctx.profiler_scope("dist_cg"), \
-                _lat.timer("lat.dist_cg.solve." + _lat.shape_bucket(rows)), \
-                _obs.span("dist_cg", n=rows, shards=A.num_shards,
-                          maxiter=int(maxiter),
-                          preconditioned=M is not None) as sp, \
-                _mem.watermark("dist_cg", n=rows, shards=A.num_shards):
+    _obs.inc("op.dist_cg")
+    if callback is not None:
+        return _dist_cg_observed(A, b, x0, tol, maxiter, M, callback,
+                                 atol, rtol, conv_test_iters)
+    rows = A.shape[0]
+    with _tctx.profiler_scope("dist_cg"), \
+            _lat.timer("lat.dist_cg.solve." + _lat.shape_bucket(rows)), \
+            _obs.span("dist_cg", n=rows, shards=A.num_shards,
+                      preconditioned=M is not None) as sp, \
+            _mem.watermark("dist_cg", n=rows, shards=A.num_shards):
+        with _obs.span("dist_cg.launch"):
+            _, b_sh, x0_sh, maxiter, _, atol, M_mv = _dist_cg_prologue(
+                A, b, x0, tol, maxiter, M, None, atol, rtol)
+            if sp is not None:
+                sp.set(maxiter=int(maxiter))
+
             # Resilience: the whole loop dispatch is the ``dist.cg``
             # site — an injected (or real) collective failure retries
             # the solve from x0, which re-converges to the identical
@@ -2582,26 +2583,55 @@ def dist_cg(
             else:
                 x, iters = _solve(A, b_sh, x0_sh, maxiter)
                 A_fin = A
-            if sp is not None:
-                # One host sync for honest timing + the true iteration
-                # count (tracing mode only; see linalg.cg).  The same
-                # count drives the comm ledger: the loop body is traced
-                # once, so the per-iteration volumes are multiplied out
-                # here rather than at the (trace-time) dispatch.
-                it = int(iters)
-                vols, calls = cg_comm_volumes(A_fin, item, it)
-                sp.set(iters=it,
-                       comm_bytes=_comm.record("dist_cg", vols,
-                                               calls,
-                                               layout=A_fin.layout),
-                       comm_calls=sum(
-                           calls[k] for k, b in vols.items()
-                           if b > 0))
-        return x[:rows], iters
+        if sp is not None:
+            # One host sync for honest timing + the true iteration
+            # count (tracing mode only, uncounted; see linalg.cg).  The
+            # same count drives the comm ledger: the loop body is
+            # traced once, so the per-iteration volumes are multiplied
+            # out here rather than at the (trace-time) dispatch.
+            it = int(iters)
+            vols, calls = cg_comm_volumes(
+                A_fin, jnp.dtype(b_sh.dtype).itemsize, it)
+            sp.set(iters=it,
+                   comm_bytes=_comm.record("dist_cg", vols, calls,
+                                           layout=A_fin.layout),
+                   comm_calls=sum(calls[k] for k, b in vols.items()
+                                  if b > 0))
+    return x[:rows], iters
 
-    # Callback path: Python-driven loop so user code observes every
-    # iterate (mirrors ``linalg.cg``'s callback contract; the truncated
-    # host view of x is passed, matching the reference's semantics).
+
+def _dist_cg_prologue(A: DistCSR, b, x0, tol, maxiter, M, callback,
+                      atol, rtol):
+    """What both ``dist_cg`` paths need before their loop: the
+    sharded system (``_shard_system``), the starting iterate, the
+    absolute tolerance from ‖b‖ (one host sync, the ``dist_cg.norm``
+    span) and the preconditioner's matvec."""
+    from ..linalg import _get_atol_rtol
+
+    rows, b_sh, x0_sh, maxiter, cb = _shard_system(
+        A, b, x0, maxiter, callback
+    )
+    if x0_sh is None:
+        x0_sh = jnp.zeros_like(b_sh)
+    with _obs.span("dist_cg.norm"):
+        _obs.inc("transfer.host_sync.dist_cg_norm")
+        bnrm2 = float(jnp.linalg.norm(b_sh))
+    atol, _ = _get_atol_rtol(bnrm2, tol, atol, rtol)
+    M_mv = M if M is not None else (lambda r: r)
+    return rows, b_sh, x0_sh, maxiter, cb, atol, M_mv
+
+
+def _dist_cg_observed(A: DistCSR, b, x0, tol, maxiter, M, callback,
+                      atol, rtol, conv_test_iters):
+    """``dist_cg``'s callback path: a Python-driven loop so user code
+    observes every iterate (mirrors ``linalg.cg``'s callback contract;
+    the truncated host view of x is passed, matching the reference's
+    semantics)."""
+    from ..obs import comm as _comm
+
+    rows, b_sh, x0_sh, maxiter, cb, atol, M_mv = _dist_cg_prologue(
+        A, b, x0, tol, maxiter, M, callback, atol, rtol)
+    item = jnp.dtype(b_sh.dtype).itemsize
     A_mv = A.matvec_fn()
     x = x0_sh
     r = b_sh - A_mv(x)
@@ -2635,13 +2665,13 @@ def dist_cg(
             n_norm += 1
             if float(jnp.linalg.norm(r)) < atol:
                 break
-    # Callback path: every eager A_mv dispatch above self-recorded its
-    # realization under comm.dist_spmv.*, so recording SpMV volumes
-    # again here would double-count the same bytes.  Only the scalar
-    # reductions this driver loop adds are ledgered under dist_cg:
-    # rho + pq every iteration, plus the residual norms the check
-    # branch actually executed (counted in the loop, not approximated
-    # — the ledger's contract is exactness).
+    # Every eager A_mv dispatch above self-recorded its realization
+    # under comm.dist_spmv.*, so recording SpMV volumes again here
+    # would double-count the same bytes.  Only the scalar reductions
+    # this Python loop adds are ledgered under dist_cg: rho + pq every
+    # iteration, plus the residual norms the check branch actually
+    # executed (counted in the loop, not approximated — the ledger's
+    # contract is exactness).
     n_psum = 2 * iters + n_norm
     _comm.record(
         "dist_cg",

@@ -2,9 +2,10 @@
 # SPDX-License-Identifier: Apache-2.0
 """Spans that follow ``jax.profiler``: with tracing off and no profiler
 the span API stays a shared no-op; while a profiler trace runs, the
-solver, graph and dispatch layers record their span trees into the obs
-buffer and annotate the profiler's host plane with the same names; and
-the host-sync counters read the same with tracing off and on."""
+solver (one-chip and distributed), graph and dispatch layers record
+their span trees into the obs buffer and annotate the profiler's host
+plane with the same names; and the host-sync counters read the same
+with tracing off and on."""
 
 import glob
 import os
@@ -16,7 +17,7 @@ import scipy.sparse as sp
 import jax
 
 import legate_sparse_tpu as sparse
-from legate_sparse_tpu import graph, linalg, obs
+from legate_sparse_tpu import graph, linalg, obs, parallel
 from legate_sparse_tpu.obs import counters, trace
 
 #: Blocking fetches of one single-root search on a graph whose push
@@ -162,6 +163,39 @@ def test_cg_span_tree_and_norm_sync(tmp_path):
     assert _host_syncs(off) == _host_syncs(on) == {
         "transfer.host_sync.cg_norm": 1}
     assert {"cg", "cg.launch", "cg.norm"} <= _host_event_names(tmp_path)
+
+
+def _dist_banded(n=64):
+    return parallel.dist_diags(
+        [4.0, -1.0, -1.0], [0, 1, -1], shape=(n, n),
+        mesh=parallel.make_row_mesh(4), dtype=np.float32)
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs four devices")
+def test_dist_cg_span_tree_and_norm_sync(tmp_path):
+    """``dist_cg`` records ``linalg.cg``'s tree: ``dist_cg`` ⊃
+    ``dist_cg.launch`` ⊃ ``dist_cg.norm``, and one counted fetch a
+    solve, traced or not."""
+    dA = _dist_banded()
+    b = np.ones(64, np.float32)
+    solve = lambda: [parallel.dist_cg(dA, b, rtol=1e-6)  # noqa: E731
+                     for _ in range(2)]
+    solve()                                  # compile outside
+    _, off_spans, off = _measure(solve)
+    out, spans, on = _measure(solve, tmp_path)
+    assert off_spans == []
+    tops = _named(spans, "dist_cg")
+    assert [t["attrs"]["iters"] for t in tops] == [int(it) for _, it in out]
+    for top in tops:
+        assert top["depth"] == 0 and top["attrs"]["maxiter"] == 640
+        (launch,) = [r for r in _children(spans, top)
+                     if r["name"] == "dist_cg.launch"]
+        assert [r["name"] for r in _children(spans, launch)
+                if r["name"] == "dist_cg.norm"] == ["dist_cg.norm"]
+    assert _host_syncs(off) == _host_syncs(on) == {
+        "transfer.host_sync.dist_cg_norm": 2}
+    assert {"dist_cg", "dist_cg.launch", "dist_cg.norm"} <= \
+        _host_event_names(tmp_path)
 
 
 def test_bfs_span_tree_and_sync_counts(tmp_path):
