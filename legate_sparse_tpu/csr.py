@@ -424,8 +424,7 @@ class csr_array(CompressedBase, DenseSparseBase):
 
         Declared IEEE trade for banded matrices: compressed storage
         drops the DIA hole mask (the band data is zero-filled, so
-        hole products are exact zeros for every *finite* operand, and
-        the mask stream is a full quarter of a bf16 band's bytes).  A
+        hole products are exact zeros for every *finite* operand).  A
         non-finite operand entry aligned with a band hole therefore
         propagates NaN where canonical f32 storage masks it — values
         are already rounded; compression is opt-in lossy.
@@ -624,10 +623,9 @@ class csr_array(CompressedBase, DenseSparseBase):
         # Exact band (every in-bounds slot explicit): no mask needed.
         exact = _dia_ops.band_cover(offsets, self.shape, cols) == nnz
         # Compressed-value storage (``compress()``) declares the hole
-        # trade: ``dia_from_csr`` zero-fills, so hole products are
-        # exact zeros for finite x and the mask stream — 1 byte/slot,
-        # a full quarter of a bf16 band's traffic — is dropped.  The
-        # cost is that a non-finite x entry at a hole propagates
+        # trade: ``dia_from_csr`` zero-fills and no mask is kept, so
+        # holes read as explicit zeros — exact products for finite x.
+        # The cost is that a non-finite x entry at a hole propagates
         # (0*inf) where canonical storage masks it; values are already
         # rounded, and the compress() docstring documents both.
         if str(self.dtype) in ("bfloat16", "float16"):
@@ -1626,10 +1624,13 @@ class csr_array(CompressedBase, DenseSparseBase):
             )
         if dia is not None:
             dia_data, _offsets, mask = dia
+            pallas = (path == "dia-pallas" if path is not None
+                      else self._dia_pack not in (None, False))
             mask_bytes = 0
-            if mask is not None:
-                # The Pallas kernel streams an int8 mask; the XLA
-                # fallback streams the bool (also 1 byte/slot).
+            if mask is not None and not pallas:
+                # The XLA lowerings stream the bool hole mask (1
+                # byte/slot); the Pallas pack carries the holes in the
+                # sign of zero of its values.
                 mask_bytes = mask.size
             return int(dia_data.size * dia_data.dtype.itemsize
                        + mask_bytes + x_bytes + out_bytes)

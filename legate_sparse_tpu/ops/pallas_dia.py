@@ -29,7 +29,8 @@ Design (role parity with the reference's hand-tuned SpMV leaf,
 - **Row-aligned band layout**: ``rdata[d, i] = A[i, i + off_d]``
   (vs scipy DIA's column-aligned ``data[d, j] = A[j - off_d, j]``), so
   the kernel's data tile multiplies an x window shifted by ``off_d``
-  with no data-side shift.  Out-of-range and hole slots hold 0.
+  with no data-side shift.  Out-of-range, padding and hole slots hold
+  +0.0; an explicit entry never does (see the IEEE invariant below).
 - The x vector is viewed as three aligned neighbor tiles
   (prev/center/next, clamped at the edges) so a shifted window never
   needs a misaligned HBM load; Mosaic requires dynamic vector loads to
@@ -38,10 +39,18 @@ Design (role parity with the reference's hand-tuned SpMV leaf,
   becomes: sublane-roll by ``q`` (and ``q+1``), lane-roll by ``r``,
   then a lane-index select between the two — three register ops, no
   relayout.
-- IEEE invariant: shifted x values are zeroed *before* the multiply at
-  out-of-range slots and band holes (explicit-entry mask), so a
-  non-finite x entry a row never references cannot inject NaN —
-  matching CSR semantics exactly (same contract as ``ops/spmv.py``).
+- IEEE invariant, carried in the sign of zero: the pack stores every
+  hole, out-of-range and padding slot as +0.0 (bits ``0x00000000``)
+  and every explicit entry whose value is +0.0 as -0.0, so a slot is
+  an entry iff its bits are not zero.  The kernel adds
+  ``d * where(bits != 0, x, 0)``: a non-finite x entry a row never
+  references cannot inject NaN (0 * inf), while an explicit zero still
+  propagates it — CSR semantics exactly (same contract as
+  ``ops/spmv.py``).  The encoding is exact: -0.0 * x differs from
+  +0.0 * x only in the sign of a zero, and the accumulator starts at
+  +0.0, where +0 + (-0) = +0, so every sum is bit-identical to the
+  masked form.  The band streams 4 bytes a slot (2 for bf16) and no
+  mask.
 
 Supported: f32/bf16 values (f64 is rejected — Mosaic has no 64-bit
 vectors; the XLA path in ``ops/dia_ops.py`` serves f64),
@@ -65,6 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import obs as _obs
+from . import dia_ops as _dia_ops
 
 L = 128                 # TPU lane width
 TILE_MIN = 1 << 14      # default rows per grid step (multiple of 1024)
@@ -87,23 +97,31 @@ def _compiler_params():
     return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _spmv_vmem(tile: int, nd: int, itemsize: int, masked: bool) -> int:
+def _spmv_vmem(tile: int, nd: int, itemsize: int) -> int:
     """Modeled VMEM of one SpMV grid step: the three x views, the
-    output and the band (plus its int8 mask) as (tile/L, L) blocks —
-    lane-exact, no padding — double-buffered, plus the f32 window and
-    its rolled copies."""
-    blocks = 4 * tile * itemsize + nd * tile * (itemsize + masked)
+    output and the band as (tile/L, L) blocks — lane-exact, no padding
+    — double-buffered, plus the f32 window and its rolled copies."""
+    blocks = 4 * tile * itemsize + nd * tile * itemsize
     return 2 * blocks + 12 * tile * 4
 
 
-def _spmm_vmem(tile: int, k: int, nd: int, itemsize: int,
-               masked: bool) -> int:
+def _spmm_vmem(tile: int, k: int, nd: int, itemsize: int) -> int:
     """Modeled VMEM of one SpMM grid step: the SpMV blocks with the x
     views and the output widened k-fold (transposed, lane-exact),
     double-buffered, plus the f32 window, its rolled copies and the
     accumulator."""
-    blocks = 4 * k * tile * itemsize + nd * tile * (itemsize + masked)
+    blocks = 4 * k * tile * itemsize + nd * tile * itemsize
     return 2 * blocks + 12 * k * tile * 4
+
+
+def stream_bytes(rdata, x_itemsize: int, k: int = 1) -> int:
+    """Bytes one band-kernel call's blocks stream, as each grid step
+    names them: the band values ``rdata``, the three x tiles and the
+    output, for ``k`` right-hand sides."""
+    nd, row_blocks, _ = rdata.shape
+    itemsize = rdata.dtype.itemsize
+    return row_blocks * L * (nd * itemsize
+                             + k * (3 * x_itemsize + itemsize))
 
 
 def _tile_override() -> Optional[int]:
@@ -148,7 +166,7 @@ def choose_tile(max_abs_off: int) -> Optional[int]:
     return _auto_tile(max_abs_off)
 
 
-def supported(offsets: Tuple[int, ...], dtype, masked: bool) -> Optional[int]:
+def supported(offsets: Tuple[int, ...], dtype) -> Optional[int]:
     """Return the tile size to use, or None when the kernel can't run."""
     if np.dtype(dtype) not in (np.dtype(np.float32),
                                np.dtype(jnp.bfloat16)):
@@ -159,7 +177,7 @@ def supported(offsets: Tuple[int, ...], dtype, masked: bool) -> Optional[int]:
     itemsize = np.dtype(dtype).itemsize
 
     def vmem_of(t: int) -> int:
-        return _spmv_vmem(t, nd, itemsize, masked)
+        return _spmv_vmem(t, nd, itemsize)
 
     tile = choose_tile(max(abs(o) for o in offsets))
     if tile is None:
@@ -183,17 +201,26 @@ def supported(offsets: Tuple[int, ...], dtype, masked: bool) -> Optional[int]:
     return tile
 
 
-@partial(jax.jit, static_argnames=("offsets", "shape", "tile", "with_mask"))
-def row_align(dia_data, offsets: Tuple[int, ...], shape: Tuple[int, int],
-              tile: int, mask=None, with_mask: bool = False):
-    """Repack scipy-layout DIA storage into the kernel's row-aligned,
-    tile-padded 2-D block layout.
+def encode_band(values, entry):
+    """The pack's slot encoding: an explicit entry (``entry`` True)
+    keeps its value, except +0.0, which becomes -0.0; every other slot
+    is +0.0.  A slot is then an entry iff its bits are not zero."""
+    signed = jnp.where(values == 0, jnp.asarray(-0.0, values.dtype),
+                       values)
+    return jnp.where(entry, signed, jnp.zeros((), values.dtype))
 
-    Returns ``(rdata, rmask)``: rdata is (nd, rows_pad // L, L) with
-    ``rdata[d, i] = dia_data[d, i + off_d]`` for in-range slots else 0;
-    rmask (int8, same blocking) is all-1 at explicit entries when
-    ``with_mask`` else None.  Runs once per matrix at structure-cache
-    build (the analog of Legion caching image partitions, ref §3.2).
+
+@partial(jax.jit, static_argnames=("offsets", "shape", "tile"))
+def row_align(dia_data, offsets: Tuple[int, ...], shape: Tuple[int, int],
+              tile: int, mask=None):
+    """Repack scipy-layout DIA storage into the kernel's row-aligned,
+    tile-padded 2-D block layout, (nd, rows_pad // L, L), with
+    ``rdata[d, i] = dia_data[d, i + off_d]`` at explicit entries
+    (``encode_band``: +0.0 stored as -0.0) and +0.0 at out-of-range,
+    padding and hole slots.  ``mask`` marks the explicit entries of a
+    holey band; without it every in-range slot is one.  Runs once per
+    matrix at structure-cache build (the analog of Legion caching image
+    partitions, ref §3.2).
     """
     rows, cols = shape
     rows_pad = -(-rows // tile) * tile
@@ -207,22 +234,16 @@ def row_align(dia_data, offsets: Tuple[int, ...], shape: Tuple[int, int],
         return jax.lax.dynamic_slice(padded, (tile + off,), (rows_pad,))
 
     parts = []
-    mparts = []
     i = jnp.arange(rows_pad, dtype=jnp.int32)
     for d, off in enumerate(offsets):
-        valid = (
+        entry = (
             (i + off >= 0) & (i + off < min(cols, width)) & (i < rows)
         )
+        if mask is not None:
+            entry = entry & shift_one(mask[d], off)
         shifted = shift_one(dia_data[d], off)
-        parts.append(jnp.where(valid, shifted, 0).reshape(-1, L))
-        if with_mask:
-            ms = shift_one(mask[d].astype(jnp.int8), off)
-            mparts.append(
-                jnp.where(valid, ms, 0).astype(jnp.int8).reshape(-1, L)
-            )
-    rdata = jnp.stack(parts)
-    rmask = jnp.stack(mparts) if with_mask else None
-    return rdata, rmask
+        parts.append(encode_band(shifted, entry).reshape(-1, L))
+    return jnp.stack(parts)
 
 
 def _flat_shift(w, s: int, lane, interpret: bool, axis: int = 0):
@@ -255,50 +276,43 @@ def _flat_shift(w, s: int, lane, interpret: bool, axis: int = 0):
     return jnp.where(lane < L - r, a, b)
 
 
-def _make_kernel(offsets: Tuple[int, ...], rows: int, cols: int,
-                 tile: int, masked: bool, interpret: bool):
+def _band_term(d, xs):
+    """One diagonal's products: ``d * xs`` where the slot holds an
+    entry (bits not zero, ``encode_band``), exactly 0 elsewhere (+0.0
+    times a zeroed x), so an x the row never references cannot inject
+    NaN.  The select zeroes x, not the product, so every slot takes
+    the one multiply-add, whether or not the compiler fuses it.  bf16
+    is widened to f32 first, which keeps the sign of a zero."""
+    d = d.astype(jnp.float32)
+    live = jax.lax.bitcast_convert_type(d, jnp.int32) != 0
+    return d * jnp.where(live, xs, jnp.zeros((), jnp.float32))
+
+
+def _make_kernel(offsets: Tuple[int, ...], tile: int, interpret: bool):
     Rt = tile // L
 
-    def kernel(*refs):
-        if masked:
-            xm_ref, xc_ref, xp_ref, d_ref, m_ref, y_ref = refs
-        else:
-            xm_ref, xc_ref, xp_ref, d_ref, y_ref = refs
-            m_ref = None
-        import jax.experimental.pallas as pl
-
-        base = pl.program_id(0) * tile
-        dtype = d_ref.dtype
-        acc_dtype = jnp.float32 if dtype != jnp.float64 else dtype
+    def kernel(xm_ref, xc_ref, xp_ref, d_ref, y_ref):
         # Widened before the rolls: Mosaic rotates 32-bit data only.
         w = jnp.concatenate([xm_ref[:], xc_ref[:], xp_ref[:]],
-                            axis=0).astype(acc_dtype)
+                            axis=0).astype(jnp.float32)
         lane3 = jax.lax.broadcasted_iota(jnp.int32, (3 * Rt, L), 1)
-        row_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 0)
-        lane_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 1)
-        gi = base + row_t * L + lane_t            # global output row
-        acc = jnp.zeros((Rt, L), acc_dtype)
+        acc = jnp.zeros((Rt, L), jnp.float32)
         for di, off in enumerate(offsets):
             xs = _flat_shift(w, off, lane3, interpret)[Rt: 2 * Rt]
-            valid = (gi + off >= 0) & (gi + off < cols) & (gi < rows)
-            if masked:
-                # v5e has no int8 vector compare: widen first.
-                valid = valid & (m_ref[di].astype(jnp.int32) > 0)
-            xsafe = jnp.where(valid, xs, jnp.zeros((), acc_dtype))
-            acc = acc + d_ref[di].astype(acc_dtype) * xsafe
-        y_ref[:] = acc.astype(dtype)
+            acc = acc + _band_term(d_ref[di], xs)
+        y_ref[:] = acc.astype(y_ref.dtype)
 
     return kernel
 
 
 @partial(jax.jit,
          static_argnames=("offsets", "shape", "tile", "interpret"))
-def pallas_dia_spmv(rdata, rmask, x, offsets: Tuple[int, ...],
+def pallas_dia_spmv(rdata, x, offsets: Tuple[int, ...],
                     shape: Tuple[int, int], tile: int,
                     interpret: bool = False):
     """y = A @ x over the row-aligned band layout (see ``row_align``).
 
-    ``rdata``/``rmask`` blocked (nd, rows_pad//L, L); x of length cols.
+    ``rdata`` blocked (nd, rows_pad//L, L); x of length cols.
     """
     import jax.experimental.pallas as pl
 
@@ -312,35 +326,26 @@ def pallas_dia_spmv(rdata, rmask, x, offsets: Tuple[int, ...],
     ntx = x_pad // tile
     xv = jnp.pad(x, (0, x_pad - cols)).reshape(-1, L)
 
-    masked = rmask is not None
-    kernel = _make_kernel(offsets, rows, cols, tile, masked, interpret)
-
-    in_specs = [
-        pl.BlockSpec((Rt, L), lambda i: (jnp.maximum(i - 1, 0), _I0)),
-        pl.BlockSpec((Rt, L), lambda i: (jnp.minimum(i, ntx - 1), _I0)),
-        pl.BlockSpec((Rt, L), lambda i: (jnp.minimum(i + 1, ntx - 1), _I0)),
-        pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)),
-    ]
-    args = [xv, xv, xv, rdata]
-    if masked:
-        in_specs.append(
-            pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)))
-        args.append(rmask)
-
     y2 = pl.pallas_call(
-        kernel,
+        _make_kernel(offsets, tile, interpret),
         out_shape=jax.ShapeDtypeStruct((rows_pad // L, L), rdata.dtype),
         grid=(nt,),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((Rt, L), lambda i: (jnp.maximum(i - 1, 0), _I0)),
+            pl.BlockSpec((Rt, L), lambda i: (jnp.minimum(i, ntx - 1), _I0)),
+            pl.BlockSpec((Rt, L),
+                         lambda i: (jnp.minimum(i + 1, ntx - 1), _I0)),
+            pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)),
+        ],
         out_specs=pl.BlockSpec((Rt, L), lambda i: (i, _I0)),
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(*args)
+    )(xv, xv, xv, rdata)
     return y2.reshape(-1)[:rows]
 
 
-def _make_spmm_kernel(offsets: Tuple[int, ...], rows: int, cols: int,
-                      tile: int, masked: bool, interpret: bool):
+def _make_spmm_kernel(offsets: Tuple[int, ...], tile: int,
+                      interpret: bool):
     """SpMM (dense multi-RHS) variant: the SpMV kernel batched over the
     k columns of X.  X rides transposed as lane-dense (k, tile/L, L)
     blocks, so every shift is the SpMV's flat shift (batched over k)
@@ -349,33 +354,16 @@ def _make_spmm_kernel(offsets: Tuple[int, ...], rows: int, cols: int,
     the TPU pads to a full lane each — 128x the band's bytes."""
     Rt = tile // L
 
-    def kernel(*refs):
-        if masked:
-            xm_ref, xc_ref, xp_ref, d_ref, m_ref, y_ref = refs
-        else:
-            xm_ref, xc_ref, xp_ref, d_ref, y_ref = refs
-            m_ref = None
-        import jax.experimental.pallas as pl
-
-        base = pl.program_id(0) * tile
-        dtype = d_ref.dtype
-        acc_dtype = jnp.float32 if dtype != jnp.float64 else dtype
+    def kernel(xm_ref, xc_ref, xp_ref, d_ref, y_ref):
         # Widened before the rolls: Mosaic rotates 32-bit data only.
         w = jnp.concatenate([xm_ref[:], xc_ref[:], xp_ref[:]],
-                            axis=1).astype(acc_dtype)
+                            axis=1).astype(jnp.float32)
         lane3 = jax.lax.broadcasted_iota(jnp.int32, w.shape, 2)
-        row_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 0)
-        lane_t = jax.lax.broadcasted_iota(jnp.int32, (Rt, L), 1)
-        gi = base + row_t * L + lane_t            # global output row
-        acc = jnp.zeros((w.shape[0], Rt, L), acc_dtype)
+        acc = jnp.zeros((w.shape[0], Rt, L), jnp.float32)
         for di, off in enumerate(offsets):
             xs = _flat_shift3(w, off, lane3, interpret)[:, Rt: 2 * Rt, :]
-            valid = (gi + off >= 0) & (gi + off < cols) & (gi < rows)
-            if masked:
-                valid = valid & (m_ref[di].astype(jnp.int32) > 0)
-            xsafe = jnp.where(valid[None], xs, jnp.zeros((), acc_dtype))
-            acc = acc + d_ref[di].astype(acc_dtype)[None] * xsafe
-        y_ref[:] = acc.astype(dtype)
+            acc = acc + _band_term(d_ref[di][None], xs)
+        y_ref[:] = acc.astype(y_ref.dtype)
 
     return kernel
 
@@ -387,7 +375,7 @@ SPMM_MAX_K = 1024
 
 @partial(jax.jit,
          static_argnames=("offsets", "shape", "tile", "interpret"))
-def pallas_dia_spmm(rdata, rmask, X, offsets: Tuple[int, ...],
+def pallas_dia_spmm(rdata, X, offsets: Tuple[int, ...],
                     shape: Tuple[int, int], tile: int,
                     interpret: bool = False):
     """Y = A @ X for dense X (cols, k) over the row-aligned band pack.
@@ -407,34 +395,24 @@ def pallas_dia_spmm(rdata, rmask, X, offsets: Tuple[int, ...],
     ntx = x_pad // tile
     XT = jnp.pad(X, ((0, x_pad - cols), (0, 0))).T.reshape(k, -1, L)
 
-    masked = rmask is not None
-    kernel = _make_spmm_kernel(offsets, rows, cols, tile, masked,
-                               interpret)
-    in_specs = [
-        pl.BlockSpec((k, Rt, L),
-                     lambda i: (_I0, jnp.maximum(i - 1, 0), _I0)),
-        pl.BlockSpec((k, Rt, L),
-                     lambda i: (_I0, jnp.minimum(i, ntx - 1), _I0)),
-        pl.BlockSpec((k, Rt, L),
-                     lambda i: (_I0, jnp.minimum(i + 1, ntx - 1), _I0)),
-    ]
-    args = [XT, XT, XT, rdata]
-    in_specs.append(pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)))
-    if masked:
-        in_specs.append(
-            pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)))
-        args.append(rmask)
-
     YT = pl.pallas_call(
-        kernel,
+        _make_spmm_kernel(offsets, tile, interpret),
         out_shape=jax.ShapeDtypeStruct((k, rows_pad // L, L),
                                        rdata.dtype),
         grid=(nt,),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((k, Rt, L),
+                         lambda i: (_I0, jnp.maximum(i - 1, 0), _I0)),
+            pl.BlockSpec((k, Rt, L),
+                         lambda i: (_I0, jnp.minimum(i, ntx - 1), _I0)),
+            pl.BlockSpec((k, Rt, L),
+                         lambda i: (_I0, jnp.minimum(i + 1, ntx - 1), _I0)),
+            pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)),
+        ],
         out_specs=pl.BlockSpec((k, Rt, L), lambda i: (_I0, i, _I0)),
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(*args)
+    )(XT, XT, XT, rdata)
     return YT.reshape(k, rows_pad)[:, :rows].T
 
 
@@ -446,10 +424,9 @@ def _spmm_tile(packed, k: int) -> Optional[int]:
     max_off = max(abs(o) for o in packed.offsets)
     itemsize = np.dtype(packed.rdata.dtype).itemsize
     nd = len(packed.offsets)
-    masked = packed.rmask is not None
     tile = packed.tile
     while tile >= max(8 * L, max_off):
-        if _spmm_vmem(tile, k, nd, itemsize, masked) <= _VMEM_BUDGET:
+        if _spmm_vmem(tile, k, nd, itemsize) <= _VMEM_BUDGET:
             return tile
         tile //= 2
     return None
@@ -476,10 +453,12 @@ def dia_spmm_maybe_pallas(packed, X):
     if tile is None:
         return None
     with _obs.span("pallas.spmm", tile=tile, k=int(k),
-                   num_diags=len(packed.offsets)):
+                   num_diags=len(packed.offsets),
+                   bytes=stream_bytes(packed.rdata, X.dtype.itemsize,
+                                      int(k))):
         return pallas_dia_spmm(
-            packed.rdata, packed.rmask, X, packed.offsets,
-            packed.shape, tile, interpret=interpret,
+            packed.rdata, X, packed.offsets, packed.shape, tile,
+            interpret=interpret,
         )
 
 
@@ -676,21 +655,21 @@ def dia_spmv_maybe_pallas(packed, x):
     if not interpret and not _on_tpu():
         return None
     with _obs.span("pallas.spmv", tile=packed.tile,
-                   num_diags=len(packed.offsets)):
+                   num_diags=len(packed.offsets),
+                   bytes=stream_bytes(packed.rdata, x.dtype.itemsize)):
         return pallas_dia_spmv(
-            packed.rdata, packed.rmask, x, packed.offsets,
-            packed.shape, packed.tile, interpret=interpret,
+            packed.rdata, x, packed.offsets, packed.shape, packed.tile,
+            interpret=interpret,
         )
 
 
 class PackedBand:
     """Cached row-aligned band pack (built once per matrix structure)."""
 
-    __slots__ = ("rdata", "rmask", "offsets", "shape", "tile")
+    __slots__ = ("rdata", "offsets", "shape", "tile")
 
-    def __init__(self, rdata, rmask, offsets, shape, tile):
+    def __init__(self, rdata, offsets, shape, tile):
         self.rdata = rdata
-        self.rmask = rmask
         self.offsets = offsets
         self.shape = shape
         self.tile = tile
@@ -699,13 +678,17 @@ class PackedBand:
 def pack_band(dia_data, offsets: Tuple[int, ...], shape: Tuple[int, int],
               mask=None) -> Optional[PackedBand]:
     """Build the kernel's layout from the scipy-layout DIA cache
-    (``csr_array._get_dia()`` output), or None when ``supported``
-    declines the band."""
-    tile = supported(offsets, dia_data.dtype, mask is not None)
+    (``csr_array._get_dia()`` output: ``mask`` marks a holey band's
+    explicit entries), or None when ``supported`` declines the band.
+    Counts the in-range slots it folds to +0.0 under
+    ``pallas.pack.hole_slots``."""
+    tile = supported(offsets, dia_data.dtype)
     if tile is None:
         return None
-    rdata, rmask = row_align(
-        dia_data, offsets, shape, tile,
-        mask=mask, with_mask=mask is not None,
-    )
-    return PackedBand(rdata, rmask, offsets, shape, tile)
+    holes = 0
+    if mask is not None:
+        covered = _dia_ops.band_cover(offsets, shape, dia_data.shape[1])
+        holes = covered - int(jnp.count_nonzero(mask))
+    _obs.inc("pallas.pack.hole_slots", holes)
+    rdata = row_align(dia_data, offsets, shape, tile, mask=mask)
+    return PackedBand(rdata, offsets, shape, tile)

@@ -119,11 +119,11 @@ class DistCSR:
     # Pre-blocked Mosaic layout for the per-shard Pallas band kernel,
     # built once at shard time (the cached-partition analog — the shard
     # body does zero packing per call): pdia_data (R, nd, rps_pad) is
-    # the tile-padded band, pdia_mask (int8, same shape) merges global
-    # bounds, padding rows and band holes.  ``pdia_tile`` is the grid
-    # tile (0 = no prepack -> XLA shifted-add branch).
+    # the tile-padded band, +0.0 at every slot outside the global
+    # bounds, on a padding row or in a band hole, and never +0.0 at an
+    # explicit entry (``pallas_dia.encode_band``).  ``pdia_tile`` is
+    # the grid tile (0 = no prepack -> XLA shifted-add branch).
     pdia_data: Optional[jax.Array] = None
-    pdia_mask: Optional[jax.Array] = None
     pdia_tile: int = 0
     # Per-shard block-sparse pack for irregular (all_gather) matrices
     # (``attach_bsr_prepack``): (R, nb_max, 128, 128) transposed
@@ -367,18 +367,24 @@ def attach_dia_prepack(dist: DistCSR) -> DistCSR:
     off (``pallas_dist_mode() == "0"`` — the default off-TPU — so pure
     XLA runs never pay the doubled band memory).
 
-    The int8 mask merges global row/column bounds, padding rows and
-    band holes, so the ring-wrapped halo never injects non-finite
-    values (same IEEE invariant as the XLA branch).
+    The pack carries validity in its values (``encode_band``): a slot
+    outside the global row/column bounds, on a padding row or in a band
+    hole is +0.0, an explicit +0.0 entry is stored as -0.0, and the
+    kernel multiplies only slots whose bits are not zero.  So the
+    ring-wrapped halo never injects non-finite values (same IEEE
+    invariant as the XLA branch), and since -0.0 and +0.0 products
+    differ only in the sign of a zero that the +0.0-started sums
+    absorb, the products are bit-identical to the masked form.  The
+    in-range hole slots are counted under ``pallas.pack.hole_slots``.
     """
-    from ..ops.pallas_dia import pallas_dist_mode, supported
+    from ..ops.pallas_dia import encode_band, pallas_dist_mode, supported
 
     if (dist.pdia_tile or dist.dia_data is None or dist.halo < 0
             or dist.dia_offsets is None or pallas_dist_mode() == "0"):
         return dist
     offsets = dist.dia_offsets
     offs2 = tuple(int(o) + dist.halo for o in offsets)
-    tile = supported(offs2, dist.dtype, True)
+    tile = supported(offs2, dist.dtype)
     if tile is None:
         return dist
     R, nd, rps = dist.dia_data.shape
@@ -386,17 +392,18 @@ def attach_dia_prepack(dist: DistCSR) -> DistCSR:
     rps_pad = -(-rps // tile) * tile
     r_g = jnp.arange(R * rps, dtype=jnp.int32).reshape(R, 1, rps)
     offs_a = jnp.asarray(offsets, dtype=jnp.int32).reshape(1, nd, 1)
-    valid = ((r_g + offs_a >= 0) & (r_g + offs_a < n_rows)
+    entry = ((r_g + offs_a >= 0) & (r_g + offs_a < n_rows)
              & (r_g < n_rows))
+    holes = 0
     if dist.dia_mask is not None:
-        valid = valid & (jnp.asarray(dist.dia_mask) != 0)
+        stored = jnp.asarray(dist.dia_mask) != 0
+        holes = int(jnp.count_nonzero(entry & ~stored))
+        entry = entry & stored
+    _obs.inc("pallas.pack.hole_slots", holes)
     pad = ((0, 0), (0, 0), (0, rps_pad - rps))
     spec = NamedSharding(dist.mesh, P(ROW_AXIS, None, None))
     dist.pdia_data = jax.device_put(
-        jnp.pad(jnp.asarray(dist.dia_data), pad), spec
-    )
-    dist.pdia_mask = jax.device_put(
-        jnp.pad(valid.astype(jnp.int8), pad), spec
+        jnp.pad(encode_band(jnp.asarray(dist.dia_data), entry), pad), spec
     )
     dist.pdia_tile = tile
     return dist
@@ -1040,14 +1047,17 @@ def _dia_spmv_pallas_fn(mesh: Mesh, offsets: Tuple[int, ...], halo: int,
                         rps: int, tile: int, interpret: bool):
     """Cached shard_map callable for the banded dist SpMV through the
     per-shard Mosaic kernel over the **pre-blocked** layout
-    (``DistCSR.pdia_data``/``pdia_mask``, built once at ``shard_csr``
-    time — the cached-partition analog): the shard body is one halo
-    ``ppermute`` plus one ``pallas_dia_spmv`` call, zero packing.
+    (``DistCSR.pdia_data``, built once at ``shard_csr`` time — the
+    cached-partition analog): the shard body is one halo ``ppermute``
+    plus one ``pallas_dia_spmv`` call, zero packing.
 
     The halo-extended window makes the local problem a rectangular band
-    with offsets shifted by +halo; global bounds, ring-wrap and band
-    holes are already merged into the int8 mask, so IEEE non-finite-x
-    semantics match the XLA branch exactly.  The shard body runs inside
+    with offsets shifted by +halo.  Global bounds, ring-wrap slots,
+    padding rows and band holes are +0.0 in the pack and every explicit
+    entry has nonzero bits (an explicit +0.0 is stored as -0.0), so the
+    kernel multiplies entries only: IEEE non-finite-x semantics match
+    the XLA branch exactly, and -0.0 changes no sum, whose accumulator
+    starts at +0.0 (``attach_dia_prepack``).  The shard body runs inside
     shard_map's trace, so a Mosaic compile failure surfaces at the
     outer compile — callers gate on ``supported()`` having produced the
     prepack and on result-dtype equality.
@@ -1059,17 +1069,15 @@ def _dia_spmv_pallas_fn(mesh: Mesh, offsets: Tuple[int, ...], halo: int,
     offs2 = tuple(int(o) + halo for o in offsets)
     nd = len(offsets)
 
-    def dia_kernel(pdata, pmask, x_local):
+    def dia_kernel(pdata, x_local):
         x_ext = _extend_x(x_local, halo)
         return pallas_dia_spmv(
             pdata[0].reshape(nd, -1, _LANES),
-            pmask[0].reshape(nd, -1, _LANES),
             x_ext, offs2, (rps, x_ext.shape[0]), tile,
             interpret=interpret,
         )
 
-    in_specs = (P(ROW_AXIS, None, None), P(ROW_AXIS, None, None),
-                P(ROW_AXIS))
+    in_specs = (P(ROW_AXIS, None, None), P(ROW_AXIS))
     return jax.jit(shard_map(
         dia_kernel, mesh=mesh, in_specs=in_specs,
         out_specs=P(ROW_AXIS), check_vma=False,
@@ -1653,7 +1661,7 @@ def _dist_spmv_impl(A: DistCSR, x: jax.Array) -> jax.Array:
                 )
                 if sp is not None:
                     sp.set(path="dia-pallas")
-                return fn(A.pdia_data, A.pdia_mask, x)
+                return fn(A.pdia_data, x)
             has_mask = A.dia_mask is not None
             fn = _dia_spmv_fn(
                 A.mesh, A.dia_offsets, halo, A.rows_per_shard,
@@ -1870,17 +1878,15 @@ def _dia_spmm_dist_fn(mesh: Mesh, offsets: Tuple[int, ...], halo: int,
     nd = len(offsets)
     xcol = COL_AXIS if col_sharded else None
 
-    def kernel(pdata, pmask, X_local):
+    def kernel(pdata, X_local):
         X_ext = _extend_x(X_local, halo)            # axis 0
         return pallas_dia_spmm(
             pdata[0].reshape(nd, -1, _LANES),
-            pmask[0].reshape(nd, -1, _LANES),
             X_ext, offs2, (rps, X_ext.shape[0]), tile,
             interpret=interpret,
         )
 
-    in_specs = (P(ROW_AXIS, None, None), P(ROW_AXIS, None, None),
-                P(ROW_AXIS, xcol))
+    in_specs = (P(ROW_AXIS, None, None), P(ROW_AXIS, xcol))
     return jax.jit(shard_map(
         kernel, mesh=mesh, in_specs=in_specs,
         out_specs=P(ROW_AXIS, xcol), check_vma=False,
@@ -1944,13 +1950,13 @@ def dist_spmm(A: DistCSR, X: jax.Array, semiring=None) -> jax.Array:
         mode = pallas_dist_mode()
         nd = A.pdia_data.shape[1]
         item = np.dtype(A.dtype).itemsize
-        vmem = _spmm_vmem(A.pdia_tile, max(k_loc, 1), nd, item, True)
+        vmem = _spmm_vmem(A.pdia_tile, max(k_loc, 1), nd, item)
         if mode != "0" and 0 < k_loc and vmem <= _VMEM_BUDGET:
             fn = _dia_spmm_dist_fn(
                 A.mesh, A.dia_offsets, A.halo, A.rows_per_shard,
                 A.pdia_tile, col_sharded, mode == "interpret",
             )
-            return fn(A.pdia_data, A.pdia_mask, X)
+            return fn(A.pdia_data, X)
     fn = _block_spmm_fn(A.mesh, A.halo, precise, A.ell,
                         A.rows_per_shard, col_sharded)
     if A.ell:

@@ -64,64 +64,71 @@ def chip_config(topo):
     compilation_cache.reset_cache()
 
 
-def _band_tile(masked: bool, dtype=jnp.float32) -> int:
-    tile = pallas_dia.supported(OFFSETS, dtype, masked)
+def _band_tile(dtype=jnp.float32) -> int:
+    tile = pallas_dia.supported(OFFSETS, dtype)
     assert tile is not None
     return tile
 
 
-def _band(n, masked, dtype, S):
-    tile = _band_tile(masked, dtype)
+def _band(n, dtype, S):
+    tile = _band_tile(dtype)
     rows_pad = -(-n // tile) * tile
-    rd = S((len(OFFSETS), rows_pad // 128, 128), dtype)
-    rm = S(rd.shape, jnp.int8) if masked else None
-    return tile, rd, rm
+    return tile, S((len(OFFSETS), rows_pad // 128, 128), dtype)
 
 
-def _spmv(masked, dtype=jnp.float32):
+def _spmv(dtype=jnp.float32):
     def build(S):
         n = BAND_ROWS
-        tile, rd, rm = _band(n, masked, dtype, S)
-        if masked:
-            return (lambda r, m, x: pallas_dia.pallas_dia_spmv(
-                r, m, x, OFFSETS, (n, n), tile)), (rd, rm, S((n,), dtype))
+        tile, rd = _band(n, dtype, S)
         return (lambda r, x: pallas_dia.pallas_dia_spmv(
-            r, None, x, OFFSETS, (n, n), tile)), (rd, S((n,), dtype))
+            r, x, OFFSETS, (n, n), tile)), (rd, S((n,), dtype))
     return build
+
+
+def _holey_spmv(S):
+    """A holey band's pack build (scipy-layout values and their
+    explicit-entry mask, encoded in the sign of zero) and the kernel
+    over it, as one program."""
+    n = BAND_ROWS
+    tile = _band_tile()
+
+    def spmv(d, m, x):
+        rd = pallas_dia.row_align(d, OFFSETS, (n, n), tile, mask=m)
+        return pallas_dia.pallas_dia_spmv(rd, x, OFFSETS, (n, n), tile)
+    return spmv, (S((len(OFFSETS), n), jnp.float32),
+                  S((len(OFFSETS), n), jnp.bool_), S((n,), jnp.float32))
 
 
 def _fori_loop(S):
     """The kernel chained in a jitted fori_loop: the composition that
     crashed the worker in the earlier on-chip bench runs."""
     n = BAND_ROWS
-    tile, rd, _ = _band(n, False, jnp.float32, S)
+    tile, rd = _band(n, jnp.float32, S)
 
     def loop(r, x):
         return jax.lax.fori_loop(
             0, 8, lambda i, v: pallas_dia.pallas_dia_spmv(
-                r, None, v, OFFSETS, (n, n), tile), x)
+                r, v, OFFSETS, (n, n), tile), x)
     return loop, (rd, S((n,), jnp.float32))
 
 
 class _Pack:
     """The fields of ``PackedBand`` that ``_spmm_tile`` reads."""
 
-    def __init__(self, tile, masked):
+    def __init__(self, tile):
         self.offsets = OFFSETS
         self.tile = tile
         self.rdata = np.zeros(1, np.float32)
-        self.rmask = 1 if masked else None
 
 
 def _spmm(k):
     def build(S):
         n = SPGEMM_ROWS
-        tile, rd, rm = _band(n, True, jnp.float32, S)
-        mt = pallas_dia._spmm_tile(_Pack(tile, True), k)
+        tile, rd = _band(n, jnp.float32, S)
+        mt = pallas_dia._spmm_tile(_Pack(tile), k)
         assert mt is not None
-        return (lambda r, m, X: pallas_dia.pallas_dia_spmm(
-            r, m, X, OFFSETS, (n, n), mt)), (rd, rm,
-                                             S((n, k), jnp.float32))
+        return (lambda r, X: pallas_dia.pallas_dia_spmm(
+            r, X, OFFSETS, (n, n), mt)), (rd, S((n, k), jnp.float32))
     return build
 
 
@@ -169,12 +176,11 @@ def _dist_1d(topo):
     rps, halo = rows // R, GRID
     offsets = (-GRID, -1, 0, 1, GRID)
     tile = pallas_dia.supported(tuple(o + halo for o in offsets),
-                                jnp.float32, True)
+                                jnp.float32)
     rps_pad = -(-rps // tile) * tile
     spec = NamedSharding(mesh, P("rows", None, None))
     band = (len(offsets), rps_pad)
     args = (jax.ShapeDtypeStruct((R, *band), jnp.float32, sharding=spec),
-            jax.ShapeDtypeStruct((R, *band), jnp.int8, sharding=spec),
             jax.ShapeDtypeStruct((rows,), jnp.float32,
                                  sharding=NamedSharding(mesh, P("rows"))))
     return _dia_spmv_pallas_fn(mesh, offsets, halo, rps, tile, False), args
@@ -204,10 +210,10 @@ def _dist_2d(topo):
 
 
 ONE_CHIP = {
-    "dia-spmv-f32": _spmv(False),
-    "dia-spmv-f32-masked": _spmv(True),
+    "dia-spmv-f32": _spmv(),
+    "dia-spmv-f32-holey": _holey_spmv,
     "dia-spmv-fori-loop": _fori_loop,
-    "dia-spmv-bf16": _spmv(False, jnp.bfloat16),
+    "dia-spmv-bf16": _spmv(jnp.bfloat16),
     "dia-spmm-k16": _spmm(16),
     "dia-spmm-k128": _spmm(128),
     "dia-spgemm": _spgemm,

@@ -76,7 +76,7 @@ assert int(starts[-1]) == (A.num_shards - 1) * A.rows_per_shard
 
 # --- every DEVICE array must be int32-or-narrower / float -----------
 for name in ("data", "cols", "counts", "row_ids", "dia_data",
-             "dia_mask", "pdia_data", "pdia_mask"):
+             "dia_mask", "pdia_data"):
     arr = getattr(A, name)
     if arr is None:
         continue

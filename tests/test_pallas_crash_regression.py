@@ -4,7 +4,8 @@
 
 Three permanent gates, one subprocess each:
 
-1. **TPU lowering**: the band kernels (SpMV masked+unmasked, SpMM,
+1. **TPU lowering**: the band kernels (SpMV over exact and holey
+   bands, SpMM,
    banded SpGEMM) and the exact looped composition that crashed r3
    (kernel chained in a jitted ``fori_loop`` at the bench trip counts
    2/6/24, production tile 2^14, 2^24 rows) must lower + serialize for
@@ -63,26 +64,29 @@ from legate_sparse_tpu.ops import pallas_dia
 
 W = 11
 offsets = tuple(range(-(W // 2), W // 2 + 1))
-tile = pallas_dia.supported(offsets, np.float32, masked=False)
+tile = pallas_dia.supported(offsets, np.float32)
 assert tile == 1 << 14, tile          # the production bench tile
 n = 1 << 24                           # the production bench rows
 rows_pad = -(-n // tile) * tile
 rdata = jax.ShapeDtypeStruct((W, rows_pad // 128, 128), jnp.float32)
-rmask = jax.ShapeDtypeStruct((W, rows_pad // 128, 128), jnp.int8)
+# A holey band: scipy-layout values and their explicit-entry mask.
+hdata = jax.ShapeDtypeStruct((W, n), jnp.float32)
+hmask = jax.ShapeDtypeStruct((W, n), jnp.bool_)
 x = jax.ShapeDtypeStruct((n,), jnp.float32)
 
 
 def spmv(rd, v):
-    return pallas_dia.pallas_dia_spmv(rd, None, v, offsets, (n, n), tile)
+    return pallas_dia.pallas_dia_spmv(rd, v, offsets, (n, n), tile)
 
 
-def spmv_masked(rd, rm, v):
-    return pallas_dia.pallas_dia_spmv(rd, rm, v, offsets, (n, n), tile)
+def spmv_holey(d, m, v):
+    rd = pallas_dia.row_align(d, offsets, (n, n), tile, mask=m)
+    return pallas_dia.pallas_dia_spmv(rd, v, offsets, (n, n), tile)
 
 
 assert jex.export(jax.jit(spmv), platforms=["tpu"])(rdata, x).serialize()
-assert jex.export(jax.jit(spmv_masked), platforms=["tpu"])(
-    rdata, rmask, x).serialize()
+assert jex.export(jax.jit(spmv_holey), platforms=["tpu"])(
+    hdata, hmask, x).serialize()
 
 # The r3 crash composition: kernel chained inside one jitted fori_loop,
 # at the bench/canary trip counts (k_lo=2, k_hi=6, k_cap=24).
@@ -101,8 +105,7 @@ X = jax.ShapeDtypeStruct((n, kk), jnp.float32)
 
 
 def spmm(rd, V):
-    return pallas_dia.pallas_dia_spmm(rd, None, V, offsets, (n, n),
-                                      mm_tile)
+    return pallas_dia.pallas_dia_spmm(rd, V, offsets, (n, n), mm_tile)
 
 
 def mm_loop(rd, V):
@@ -146,7 +149,7 @@ from legate_sparse_tpu.ops import pallas_dia
 W = 11
 half = W // 2
 offsets = tuple(range(-half, half + 1))
-tile = pallas_dia.supported(offsets, np.float32, masked=False)
+tile = pallas_dia.supported(offsets, np.float32)
 assert tile == 1024, tile
 n = 1 << 14
 assert n // tile == 16                # multi-step grid, like production
@@ -155,15 +158,14 @@ rng = np.random.default_rng(7)
 # Scipy column-aligned DIA layout, magnitude-stable rows.
 dia_data = (rng.uniform(0.5, 1.0, (W, n)) / W).astype(np.float32)
 A = sp.dia_array((dia_data, offsets), shape=(n, n)).tocsr()
-rdata, _ = pallas_dia.row_align(jnp.asarray(dia_data), offsets, (n, n),
-                                tile)
+rdata = pallas_dia.row_align(jnp.asarray(dia_data), offsets, (n, n), tile)
 x_np = rng.uniform(-1.0, 1.0, n).astype(np.float32)
 x = jnp.asarray(x_np)
 
 
 def step(v):
-    return pallas_dia.pallas_dia_spmv(rdata, None, v, offsets, (n, n),
-                                      tile, interpret=True)
+    return pallas_dia.pallas_dia_spmv(rdata, v, offsets, (n, n), tile,
+                                      interpret=True)
 
 # Eager launch (passed on-chip in r3) ...
 y = np.asarray(step(x))
@@ -180,18 +182,17 @@ for k in (2, 6, 24):
         ref = A @ ref
     np.testing.assert_allclose(yk, ref, rtol=5e-3, atol=1e-5)
 
-# Masked variant (band with holes) through the same composition.
+# Holey band (holes +0.0 in the pack) through the same composition.
 mask = (rng.uniform(size=(W, n)) > 0.2)
 dia_masked = np.where(mask, dia_data, 0.0).astype(np.float32)
 Am = sp.dia_array((dia_masked, offsets), shape=(n, n)).tocsr()
-rd_m, rm_m = pallas_dia.row_align(
-    jnp.asarray(dia_masked), offsets, (n, n), tile,
-    mask=jnp.asarray(mask), with_mask=True)
+rd_m = pallas_dia.row_align(jnp.asarray(dia_masked), offsets, (n, n),
+                            tile, mask=jnp.asarray(mask))
 
 
 def mstep(v):
-    return pallas_dia.pallas_dia_spmv(rd_m, rm_m, v, offsets, (n, n),
-                                      tile, interpret=True)
+    return pallas_dia.pallas_dia_spmv(rd_m, v, offsets, (n, n), tile,
+                                      interpret=True)
 
 ym = np.asarray(jax.jit(
     lambda v: jax.lax.fori_loop(0, 6, lambda i, u: mstep(u), v))(x))
@@ -206,8 +207,8 @@ X0 = rng.uniform(-1.0, 1.0, (n, kk)).astype(np.float32)
 
 
 def mm_step(V):
-    return pallas_dia.pallas_dia_spmm(rdata, None, V, offsets, (n, n),
-                                      tile, interpret=True)
+    return pallas_dia.pallas_dia_spmm(rdata, V, offsets, (n, n), tile,
+                                      interpret=True)
 
 Ym = np.asarray(jax.jit(
     lambda V: jax.lax.fori_loop(0, 8, lambda i, U: mm_step(U), V)

@@ -27,7 +27,7 @@ def _spmv_via_pallas(A, x):
     assert packed is not None, "kernel must support this band"
     return np.asarray(
         pallas_dia.pallas_dia_spmv(
-            packed.rdata, packed.rmask, jnp.asarray(x), packed.offsets,
+            packed.rdata, jnp.asarray(x), packed.offsets,
             packed.shape, packed.tile, interpret=True,
         )
     )
@@ -63,7 +63,7 @@ def test_large_offsets_multirow_shift(rng):
 
 
 def test_holey_band_mask(rng):
-    # diags().tocsr() drops interior zeros -> holes -> masked variant.
+    # diags().tocsr() drops interior zeros -> holes, +0.0 in the pack.
     n = 600
     main = rng.standard_normal(n).astype(np.float32)
     off1 = rng.standard_normal(n - 1).astype(np.float32)
@@ -130,7 +130,7 @@ def test_bfloat16_supported(rng):
     assert packed is not None
     y = np.asarray(
         pallas_dia.pallas_dia_spmv(
-            packed.rdata, packed.rmask, x, packed.offsets, packed.shape,
+            packed.rdata, x, packed.offsets, packed.shape,
             packed.tile, interpret=True,
         ).astype(jnp.float32)
     )
@@ -139,11 +139,11 @@ def test_bfloat16_supported(rng):
 
 
 def test_f64_unsupported():
-    assert pallas_dia.supported((0, 1), np.float64, False) is None
+    assert pallas_dia.supported((0, 1), np.float64) is None
 
 
 def test_band_reach_cap():
-    assert pallas_dia.supported((-(1 << 20), 0), np.float32, False) is None
+    assert pallas_dia.supported((-(1 << 20), 0), np.float32) is None
     assert pallas_dia.choose_tile(1 << 16) == 1 << 16
 
 
@@ -173,7 +173,7 @@ def _spmm_via_pallas(A, X):
     assert tile is not None
     return np.asarray(
         pallas_dia.pallas_dia_spmm(
-            packed.rdata, packed.rmask, jnp.asarray(X), packed.offsets,
+            packed.rdata, jnp.asarray(X), packed.offsets,
             packed.shape, tile, interpret=True,
         )
     )
@@ -336,7 +336,7 @@ def test_tile_override_matches_default(tile, rng, monkeypatch):
     packed = pallas_dia.pack_band(dia_data, offs, A.shape, mask=mask)
     assert packed is not None and packed.tile == tile
     got = np.asarray(pallas_dia.pallas_dia_spmv(
-        packed.rdata, packed.rmask, jnp.asarray(x), packed.offsets,
+        packed.rdata, jnp.asarray(x), packed.offsets,
         packed.shape, packed.tile, interpret=True))
     np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-6)
 
@@ -354,5 +354,193 @@ def test_tile_override_over_vmem_budget_degrades_to_auto(monkeypatch):
     monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_TILE",
                        str(pallas_dia.TILE_MAX))
     offsets = tuple(range(-100, 101))       # 201 diagonals
-    tile = pallas_dia.supported(offsets, np.float32, masked=True)
+    tile = pallas_dia.supported(offsets, np.float32)
     assert tile == pallas_dia.TILE_MIN
+
+
+# ---------------- holes carried in the sign of zero ----------------
+
+IEEE_N = 2600                   # three 1024-row tiles, the last partial
+IEEE_OFFSETS = (-3, -1, 0, 1, 4)
+
+
+def _ieee_band(rng):
+    """A holey band whose explicit entries include +0.0, -0.0 and NaN,
+    and an x with inf/NaN at hole columns and at the columns an
+    out-of-range reach reads through the clamped neighbour tiles (run
+    at a forced tile of 1024).  Returns (A, A_sp, x, slots): ``slots``
+    names the rows the checks below read."""
+    n = IEEE_N
+    rows, cols, vals = [], [], []
+    for off in IEEE_OFFSETS:
+        i = np.arange(max(0, -off), min(n, n - off))
+        keep = rng.random(i.size) > 0.2
+        rows.append(i[keep])
+        cols.append(i[keep] + off)
+        # Halves and quarters: every product and sum is exact, so bits
+        # compare the semantics, not one order of rounding.
+        vals.append((rng.integers(-4, 5, int(keep.sum())) * 0.5
+                     ).astype(np.float32))
+    r, c, v = (np.concatenate(a) for a in (rows, cols, vals))
+    entries = dict(zip(zip(r.tolist(), c.tolist()), v.tolist()))
+    # Row 100: an explicit +0.0 on the diagonal; row 200: an explicit
+    # -0.0; row 400: an explicit NaN; row 300: a hole at (300, 301),
+    # its other slots finite entries.
+    entries[(100, 100)] = 0.0
+    entries[(200, 200)] = -0.0
+    entries[(400, 400)] = np.nan
+    for off in IEEE_OFFSETS:
+        entries[(300, 300 + off)] = 0.5
+    del entries[(300, 301)]
+    keys = sorted(entries)
+    r = np.array([k[0] for k in keys])
+    c = np.array([k[1] for k in keys])
+    v = np.array([entries[k] for k in keys], np.float32)
+    A_sp = scsp.csr_matrix((v, (r, c)), shape=(n, n))
+    A = sparse.csr_array(A_sp)
+    x = (rng.integers(-4, 5, n) * 0.25).astype(np.float32)
+    x[[297, 299, 300, 304]] = 1.0
+    x[[100, 200, 301]] = np.inf           # explicit +-0.0 rows, a hole
+    holes = [(i, i + o) for o in IEEE_OFFSETS for i in range(0, n, 37)
+             if 0 <= i + o < n and (i, i + o) not in entries]
+    for i, (_, col) in enumerate(holes[:12]):
+        x[col] = np.inf if i % 2 else np.nan
+    # What rows 0-2 (negative reach) and the partial tile's last rows
+    # (positive reach) would read through the clamped neighbours.
+    x[1021:1024] = np.inf
+    x[2048:2051] = np.nan
+    return A, A_sp, x
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+def _csr_reference(A, x):
+    from legate_sparse_tpu.ops import spmv as spmv_ops
+
+    return np.asarray(spmv_ops.csr_spmv_rowids(
+        A._data, A._indices, A._get_row_ids(), jnp.asarray(x),
+        A.shape[0]))
+
+
+def test_holey_band_ieee_bit_for_bit(rng, monkeypatch):
+    from legate_sparse_tpu.ops import dia_ops
+
+    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_TILE", "1024")
+    A, A_sp, x = _ieee_band(rng)
+    dia_data, offsets, mask = A._get_dia()
+    assert mask is not None, "expect a holey band"
+    packed = pallas_dia.pack_band(dia_data, offsets, A.shape, mask=mask)
+    assert packed.tile == 1024
+    y = np.asarray(pallas_dia.pallas_dia_spmv(
+        packed.rdata, jnp.asarray(x), packed.offsets, packed.shape,
+        packed.tile, interpret=True))
+    ref = np.asarray(dia_ops.dia_spmv_masked(
+        dia_data, mask, jnp.asarray(x), offsets, A.shape))
+    np.testing.assert_array_equal(_bits(y), _bits(ref))
+    np.testing.assert_array_equal(_bits(y), _bits(_csr_reference(A, x)))
+    # An explicit +0.0 or -0.0 against inf is NaN, as in CSR; so is an
+    # explicit NaN; a hole against inf contributes exactly 0.
+    assert np.isnan(y[100]) and np.isnan(y[200]) and np.isnan(y[400])
+    assert np.isfinite(y[300]) and y[300] == np.float32(0.5 * 4)
+    assert np.isfinite(y[:3]).any()
+
+
+@pytest.mark.parametrize("k", [1, 3])
+def test_holey_band_ieee_spmm_bit_for_bit(k, rng, monkeypatch):
+    from legate_sparse_tpu.ops import dia_ops
+
+    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_TILE", "1024")
+    A, A_sp, x = _ieee_band(rng)
+    X = np.stack([np.roll(x, 5 * j) for j in range(k)], axis=1)
+    dia_data, offsets, mask = A._get_dia()
+    packed = pallas_dia.pack_band(dia_data, offsets, A.shape, mask=mask)
+    tile = pallas_dia._spmm_tile(packed, k)
+    Y = np.asarray(pallas_dia.pallas_dia_spmm(
+        packed.rdata, jnp.asarray(X), packed.offsets, packed.shape, tile,
+        interpret=True))
+    ref = np.asarray(dia_ops.dia_spmm_masked(
+        dia_data, mask, jnp.asarray(X), offsets, A.shape))
+    np.testing.assert_array_equal(_bits(Y), _bits(ref))
+    for j in range(k):
+        np.testing.assert_array_equal(
+            _bits(Y[:, j]), _bits(_csr_reference(A, X[:, j])))
+    assert np.isnan(Y[100, 0]) and np.isfinite(Y[300, 0])
+
+
+def test_pack_encodes_entries_in_sign_of_zero(rng):
+    A, A_sp, _ = _ieee_band(rng)
+    dia_data, offsets, mask = A._get_dia()
+    packed = pallas_dia.pack_band(dia_data, offsets, A.shape, mask=mask)
+    arrays = [getattr(packed, s) for s in packed.__slots__
+              if hasattr(getattr(packed, s), "dtype")]
+    assert [a.dtype for a in arrays] == [np.dtype(np.float32)]
+    bits = _bits(packed.rdata).reshape(len(offsets), -1)
+    coo = A_sp.tocoo()
+    live = np.zeros_like(bits, dtype=bool)
+    d_of = {o: d for d, o in enumerate(offsets)}
+    live[[d_of[o] for o in (coo.col - coo.row)], coo.row] = True
+    np.testing.assert_array_equal(bits != 0, live)
+    # Values survive, with +0.0 stored as -0.0.
+    d0 = d_of[0]
+    assert bits[d0, 100] == np.float32(-0.0).view(np.uint32)
+    assert bits[d0, 200] == np.float32(-0.0).view(np.uint32)
+    assert np.isnan(packed.rdata.reshape(len(offsets), -1)[d0, 400])
+
+
+def test_pack_counts_hole_slots():
+    from legate_sparse_tpu import obs
+
+    N = 16
+    A = sparse.diags([-1.0, -1.0, 4.0, -1.0, -1.0], [-N, -1, 0, 1, N],
+                     shape=(N * N, N * N), format="csr", dtype=np.float32)
+    main = np.full(N * N, 4.0, np.float32)
+    off1 = np.full(N * N - 1, -1.0, np.float32)
+    off1[np.arange(1, N) * N - 1] = 0.0      # grid-row boundaries
+    offn = np.full(N * N - N, -1.0, np.float32)
+    P = sparse.csr_array(scsp.diags(
+        [offn, off1, main, off1, offn], [-N, -1, 0, 1, N],
+        format="csr", dtype=np.float32))     # drops the boundary zeros
+    for M, holes in ((A, 0), (P, 2 * (N - 1))):
+        dia_data, offsets, mask = M._get_dia()
+        before = obs.counters.get("pallas.pack.hole_slots")
+        assert pallas_dia.pack_band(dia_data, offsets, M.shape,
+                                    mask=mask) is not None
+        assert obs.counters.get("pallas.pack.hole_slots") == before + holes
+
+
+def test_pallas_spans_carry_stream_bytes(rng, monkeypatch):
+    from legate_sparse_tpu import obs
+
+    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_DIA", "interpret")
+    n = 1500
+    A, A_sp = _banded(n, [-2, 0, 1], rng)
+    x = jnp.asarray(rng.standard_normal(n).astype(np.float32))
+    X = jnp.asarray(rng.standard_normal((n, 3)).astype(np.float32))
+    was = obs.enabled()
+    obs.enable()
+    try:
+        obs.reset()
+        A @ x
+        A @ X
+        recs = {r["name"]: r for r in obs.records()
+                if r["name"] in ("pallas.spmv", "pallas.spmm")}
+    finally:
+        if not was:
+            obs.disable()
+    rows_pad = A._get_dia_pack().rdata.shape[1] * pallas_dia.L
+    assert recs["pallas.spmv"]["attrs"]["bytes"] == rows_pad * 4 * (3 + 4)
+    assert recs["pallas.spmm"]["attrs"]["bytes"] == rows_pad * 4 * (3 + 12)
+
+
+def test_cell_bands_keep_their_tile():
+    # The 5-point bands of the 2048^2 grid (one chip) and of the 4096^2
+    # grid's per-shard window (offsets + halo on four chips) keep the
+    # 2^14-row tile now that the band streams no mask.
+    assert pallas_dia.supported((-2048, -1, 0, 1, 2048),
+                                np.float32) == 1 << 14
+    halo = 4096
+    assert pallas_dia.supported(
+        tuple(o + halo for o in (-4096, -1, 0, 1, 4096)),
+        np.float32) == 1 << 14

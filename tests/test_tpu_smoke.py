@@ -76,7 +76,7 @@ def test_pallas_dia_kernel_on_chip(accel):
     assert packed is not None
     x = np.linspace(-1.0, 1.0, A.shape[0]).astype(np.float32)
     y = np.asarray(pallas_dia.pallas_dia_spmv(
-        packed.rdata, packed.rmask, x, packed.offsets, packed.shape,
+        packed.rdata, x, packed.offsets, packed.shape,
         packed.tile, interpret=False,
     ))
     y_ref = A.toscipy() @ x
@@ -94,7 +94,7 @@ def test_pallas_dia_spmm_on_chip(accel):
     tile = pallas_dia._spmm_tile(packed, 4)
     assert tile is not None
     Y = np.asarray(pallas_dia.pallas_dia_spmm(
-        packed.rdata, packed.rmask, X, packed.offsets, packed.shape,
+        packed.rdata, X, packed.offsets, packed.shape,
         tile, interpret=False,
     ))
     np.testing.assert_allclose(Y, A.toscipy() @ X, rtol=1e-5, atol=1e-5)
