@@ -511,6 +511,40 @@ def _resil_solver_active() -> bool:
         or _rckpt.active())
 
 
+def _cg_chunk(A_mv: Callable, M_mv: Callable, conv_test_iters: int):
+    """``chunk(state, limit)``: the CG while_loop from ``state`` until
+    it converges, reaches ``maxiter`` or its count reaches ``limit`` —
+    the host-stepped unit of ``_cg_loop_resil`` (``conv_test_iters``
+    iterations a chunk) and ``_cg_loop_observed`` (one).  Returns the
+    state and the stacked scalars (iters, done, ||r||^2)."""
+    cond, body = _cg_builders(A_mv, M_mv, conv_test_iters)
+
+    def chunk(state, limit):
+        def cond_chunk(st):
+            return jnp.logical_and(cond(st), st[4] < limit)
+
+        out = jax.lax.while_loop(cond_chunk, body, state)
+        rdt = out[6].dtype
+        rn2 = jnp.real(jnp.vdot(out[1], out[1]))
+        stats = jnp.stack([out[4].astype(rdt), out[5].astype(rdt),
+                           rn2.astype(rdt)])
+        return out, stats
+
+    return chunk
+
+
+def _cg_chunk_state0(A_mv: Callable, b, x0, atol: float, maxiter: int):
+    """``_cg_state0`` placed as a chunk's outputs are — on a mesh, the
+    scalars replicated over it — so a host-stepped loop traces its
+    chunk once, not again at the second step."""
+    state = _cg_state0(A_mv, b, x0, atol, maxiter)
+    sh = b.sharding
+    if not isinstance(sh, jax.sharding.NamedSharding):
+        return state
+    rep = jax.sharding.NamedSharding(sh.mesh, jax.sharding.PartitionSpec())
+    return state[:3] + tuple(jax.device_put(s, rep) for s in state[3:])
+
+
 def _cg_loop_resil(A_mv: Callable, M_mv: Callable, b, x0, atol: float,
                    maxiter: int, conv_test_iters: int,
                    site: str = "solver.cg.conv"):
@@ -533,21 +567,8 @@ def _cg_loop_resil(A_mv: Callable, M_mv: Callable, b, x0, atol: float,
     convergence checks — including the ``iters == maxiter - 1`` final
     check — fire at exactly the one-shot loop's iterations and the two
     drivers converge at the same count."""
-    cond, body = _cg_builders(A_mv, M_mv, conv_test_iters)
-    rdt = jnp.real(b).dtype
-
-    def chunk(state, limit):
-        def cond_chunk(st):
-            return jnp.logical_and(cond(st), st[4] < limit)
-
-        out = jax.lax.while_loop(cond_chunk, body, state)
-        rn2 = jnp.real(jnp.vdot(out[1], out[1]))
-        stats = jnp.stack([out[4].astype(rdt), out[5].astype(rdt),
-                           rn2.astype(rdt)])
-        return out, stats
-
-    chunk_fn = maybe_jit(chunk)
-    state = _cg_state0(A_mv, b, x0, atol, maxiter)
+    chunk_fn = maybe_jit(_cg_chunk(A_mv, M_mv, conv_test_iters))
+    state = _cg_chunk_state0(A_mv, b, x0, atol, maxiter)
     step = max(int(conv_test_iters), 1)
     monitor = _rhealth.Monitor(site)
     ckpt = _rckpt.current()
@@ -585,24 +606,72 @@ def _cg_loop_resil(A_mv: Callable, M_mv: Callable, b, x0, atol: float,
     return state[0], state[4]
 
 
-def _cg_prologue(A, b, x0, tol, atol, rtol, M):
-    """What every CG path needs before its loop: the absolute
-    tolerance (from ‖b‖, one host sync), the wrapped operators, the
-    promoted right-hand side and the starting iterate."""
-    with _obs.span("cg.norm"):
-        _obs.inc("transfer.host_sync.cg_norm")
-        bnrm2 = float(jnp.linalg.norm(b))
-    atol, _ = _get_atol_rtol(bnrm2, tol, atol, rtol)
-    A_op = make_linear_operator(A)
-    b = _promote_rhs(b, A_op)
-    M_op = (
-        IdentityOperator(A_op.shape, dtype=A_op.dtype)
-        if M is None
-        else make_linear_operator(M)
-    )
-    x = (jnp.zeros(b.shape[0], dtype=b.dtype) if x0 is None
-         else jnp.asarray(x0, dtype=b.dtype).reshape(-1))
-    return atol, A_op, b, M_op, x
+def _cg_loop_observed(A_mv: Callable, M_mv: Callable, b, x0, atol: float,
+                      maxiter: int, conv_test_iters: int, callback):
+    """CG that calls ``callback(x)`` after every iteration: the chunk
+    of ``_cg_chunk`` stepped one iteration a dispatch, so the iterates
+    are ``_cg_loop``'s.  The host reads the ``done`` flag only where
+    the body tests convergence (every ``conv_test_iters``-th iteration
+    and at ``maxiter - 1``): one fetch there, counted like
+    ``_cg_loop_resil``'s chunk fetches (``transfer.host_sync.cg_conv``)."""
+    chunk = _cg_chunk(A_mv, M_mv, conv_test_iters)
+    # Stats unused: their ||r||^2 reduction (a psum on a mesh) drops out.
+    step_fn = maybe_jit(lambda state, limit: chunk(state, limit)[0])
+    state = _cg_chunk_state0(A_mv, b, x0, atol, maxiter)
+    it = 0
+    while it < maxiter:
+        with _obs.span("cg.iter", i=it):
+            state = step_fn(state, jnp.asarray(it + 1, dtype=index_dtype()))
+        it += 1
+        callback(state[0])
+        if it % conv_test_iters == 0 or it == maxiter - 1:
+            _obs.inc("transfer.host_sync.cg_conv")
+            if bool(state[5]):
+                break
+    return state[0], it
+
+
+def _cg_solve(name: str, n: int, solve: Callable, host_attrs: Callable,
+              b, x0, tol, atol, rtol, maxiter: int, callback, **attrs):
+    """The one CG solve routine of ``cg`` and ``parallel.dist_cg``
+    (``name`` ``"cg"`` or ``"dist_cg"``): the ``<name>`` ⊃
+    ``<name>.launch`` ⊃ ``<name>.norm`` spans, the ‖b‖ fetch (one
+    ``transfer.host_sync.<name>_norm``), atol and x0, then
+    ``solve(loop, b, x0, atol)`` with ``loop(A_mv, M_mv, b, x0, atol,
+    maxiter, conv_test_iters)`` the callback stepper, ``_cg_loop_resil``
+    or ``_cg_loop``.  Where the iteration count reaches the host
+    (tracing, or a callback solve) ``host_attrs(iters)`` returns the
+    span's attributes."""
+    with _lat.timer(f"lat.{name}.solve." + _lat.shape_bucket(n)), \
+            _obs.span(name, n=n, maxiter=int(maxiter), **attrs) as sp:
+        with _obs.span(name + ".launch"):
+            with _obs.span(name + ".norm"):
+                _obs.inc(f"transfer.host_sync.{name}_norm")
+                bnrm2 = float(jnp.linalg.norm(b))
+            atol, _ = _get_atol_rtol(bnrm2, tol, atol, rtol)
+            if x0 is None:
+                x0 = jnp.zeros_like(b)
+
+            def loop(*args):
+                # Chosen at the call: a checkpoint scope that ``solve``
+                # opens (dist_cg) routes to ``_cg_loop_resil``.
+                if callback is not None:
+                    return _cg_loop_observed(*args, callback)
+                return (_cg_loop_resil if _resil_solver_active()
+                        else _cg_loop)(*args)
+
+            x, iters = solve(loop, b, x0, atol)
+        if sp is not None or callback is not None:
+            # Tracing mode trades one host sync for honest span timing
+            # (the fetch is the only trusted completion signal on
+            # detached-dispatch backends) and the true iteration count.
+            # It is not counted under transfer.host_sync.*: the counted
+            # syncs are the same with tracing on and off.
+            it = int(iters)
+            extra = host_attrs(it)
+            if sp is not None:
+                sp.set(iters=it, **extra)
+    return x, iters
 
 
 def cg(
@@ -622,8 +691,8 @@ def cg(
     reference ``linalg.py:465-535``).  Returns ``(x, iters)``.
 
     Without a callback the solve is a single jitted while_loop (no host
-    sync per iteration).  With a callback, a Python-level loop mirrors
-    the reference's structure so user code observes every iterate.
+    sync per iteration).  With a callback the same loop is stepped one
+    iteration a dispatch so user code observes every iterate.
 
     ``refine="auto"`` (or a positive cycle count) switches to
     mixed-precision iterative refinement: inner CG sweeps run over the
@@ -640,78 +709,46 @@ def cg(
     n = b.shape[0]
     if maxiter is None:
         maxiter = n * 10
+    if refine is not None and (M is not None or callback is not None):
+        raise ValueError(
+            "cg: refine= composes with neither M= nor callback= — "
+            "inner sweeps run over the compressed operator without "
+            "the outer preconditioner/observer")
+    _obs.inc("op.cg")
+    A_op = make_linear_operator(A)
+    b = _promote_rhs(b, A_op)
+    M_op = (
+        IdentityOperator(A_op.shape, dtype=A_op.dtype)
+        if M is None
+        else make_linear_operator(M)
+    )
+    if x0 is not None:
+        x0 = jnp.asarray(x0, dtype=b.dtype).reshape(-1)
 
-    if refine is None and callback is None:
-        _obs.inc("op.cg")
-        with _lat.timer("lat.cg.solve." + _lat.shape_bucket(n)), \
-                _obs.span("cg", n=n, maxiter=int(maxiter)) as sp:
-            with _obs.span("cg.launch"):
-                atol, A_op, b, M_op, x = _cg_prologue(A, b, x0, tol,
-                                                      atol, rtol, M)
-                loop = (_cg_loop_resil if _resil_solver_active()
-                        else _cg_loop)
-                xs, iters = loop(
-                    A_op.matvec, M_op.matvec, b, x, atol, int(maxiter),
-                    int(conv_test_iters),
-                )
-            if sp is not None:
-                # Tracing mode trades one host sync for honest span
-                # timing (the fetch is the only trusted completion
-                # signal on detached-dispatch backends) and records
-                # the true iteration count + per-iter traffic model.
-                # It is not counted under transfer.host_sync.*: the
-                # counted syncs are the same with tracing on and off.
-                it = int(iters)
-                sp.set(iters=it)
-                src = getattr(A_op, "A", None)
-                if isinstance(src, csr_array):
-                    sp.set(nnz=src.nnz * it,
-                           bytes=src.spmv_traffic_bytes(b) * it,
-                           flops=2 * src.nnz * it)
-        return xs, iters
-
-    atol, A_op, b, M_op, x = _cg_prologue(A, b, x0, tol, atol, rtol, M)
-    if refine is not None:
-        if M is not None or callback is not None:
-            raise ValueError(
-                "cg: refine= composes with neither M= nor callback= — "
-                "inner sweeps run over the compressed operator without "
-                "the outer preconditioner/observer")
-        _obs.inc("op.cg")
-
+    if refine is None:
+        def solve(loop, b, x, atol):
+            return loop(A_op.matvec, M_op.matvec, b, x, atol,
+                        int(maxiter), int(conv_test_iters))
+    else:
         def _inner(A_in, r, inner_atol, budget):
             return cg(A_in, r, atol=inner_atol, rtol=0.0,
                       maxiter=budget, conv_test_iters=conv_test_iters)
 
-        return _refined_solve(
-            "cg", _inner, A_op, _refine_inner_operator(A), b, x,
-            atol, int(maxiter), _refine_cycles(refine))
+        def solve(loop, b, x, atol):
+            return _refined_solve(
+                "cg", _inner, A_op, _refine_inner_operator(A), b, x,
+                atol, int(maxiter), _refine_cycles(refine))
 
-    _obs.inc("op.cg")
-    # Callback path: Python loop, one deferred pipeline per iteration.
-    r = b - A_op.matvec(x)
-    p = jnp.zeros_like(b)
-    rho = jnp.ones((), dtype=b.dtype)
-    iters = 0
-    while iters < maxiter:
-        with _obs.span("cg.iter", i=iters):
-            z = M_op.matvec(r)
-            rho_old = rho
-            rho = jnp.vdot(r, z)
-            beta = jnp.where(iters == 0, jnp.zeros_like(rho),
-                             rho / rho_old)
-            p = z + beta * p
-            q = A_op.matvec(p)
-            alpha = rho / jnp.vdot(p, q)
-            x = x + alpha * p
-            r = r - alpha * q
-        iters += 1
-        callback(x)
-        if (iters % conv_test_iters == 0 or iters == maxiter - 1) and float(
-            jnp.linalg.norm(r)
-        ) < atol:
-            break
-    return x, iters
+    def traffic(it):
+        # The per-iteration traffic model of a package operator.
+        src = getattr(A_op, "A", None)
+        if refine is not None or not isinstance(src, csr_array):
+            return {}
+        return dict(nnz=src.nnz * it, bytes=src.spmv_traffic_bytes(b) * it,
+                    flops=2 * src.nnz * it)
+
+    return _cg_solve("cg", n, solve, traffic, b, x0, tol, atol, rtol,
+                     maxiter, callback)
 
 
 # --------------------------------------------------------------------------

@@ -2524,8 +2524,8 @@ def dist_cg(
     rtol: float = 1e-5,
     conv_test_iters: int = 25,
 ):
-    """Distributed (optionally preconditioned) CG: one jitted while_loop
-    over sharded state.
+    """Distributed (optionally preconditioned) CG: ``linalg.cg``'s
+    solve routine (``_cg_solve``) and loop over sharded state.
 
     Global reductions (rho, pq, convergence norm) are jnp.vdot on sharded
     vectors — GSPMD lowers them to local dots + ``psum`` over ICI,
@@ -2534,155 +2534,65 @@ def dist_cg(
     padded sharded vectors (e.g. ``DistGMG.cycle`` — the reference's
     headline GMG-preconditioned configuration, ``examples/gmg.py:104-143``).
     Returns the solution truncated to the unpadded length, plus the
-    iteration count.
+    iteration count; a callback sees iterates truncated the same way.
 
-    Without a callback the solve records the span tree of
-    ``linalg.cg``: ``dist_cg`` ⊃ ``dist_cg.launch`` (sharding, the
-    ‖b‖ fetch, the operator wrap, the loop's trace and enqueue) ⊃
-    ``dist_cg.norm`` (the fetch, one ``transfer.host_sync.dist_cg_norm``).
+    The solve records the span tree of ``linalg.cg``: ``dist_cg`` ⊃
+    ``dist_cg.launch`` (the ‖b‖ fetch, the loop's trace and enqueue)
+    ⊃ ``dist_cg.norm`` (the fetch, one
+    ``transfer.host_sync.dist_cg_norm``).  Where the iteration count
+    reaches the host (tracing mode, or a callback solve) the comm
+    ledger records ``cg_comm_volumes`` for it under ``dist_cg``.
     """
-    from ..linalg import _cg_loop, _cg_loop_resil, _resil_solver_active
+    from ..linalg import _cg_solve
     from ..obs import comm as _comm
     from ..obs import memory as _mem
 
     _obs.inc("op.dist_cg")
-    if callback is not None:
-        return _dist_cg_observed(A, b, x0, tol, maxiter, M, callback,
-                                 atol, rtol, conv_test_iters)
-    rows = A.shape[0]
-    with _tctx.profiler_scope("dist_cg"), \
-            _lat.timer("lat.dist_cg.solve." + _lat.shape_bucket(rows)), \
-            _obs.span("dist_cg", n=rows, shards=A.num_shards,
-                      preconditioned=M is not None) as sp, \
-            _mem.watermark("dist_cg", n=rows, shards=A.num_shards):
-        with _obs.span("dist_cg.launch"):
-            _, b_sh, x0_sh, maxiter, _, atol, M_mv = _dist_cg_prologue(
-                A, b, x0, tol, maxiter, M, None, atol, rtol)
-            if sp is not None:
-                sp.set(maxiter=int(maxiter))
-
-            # Resilience: the whole loop dispatch is the ``dist.cg``
-            # site — an injected (or real) collective failure retries
-            # the solve from x0, which re-converges to the identical
-            # answer instead of corrupting the Krylov state.  An
-            # active deadline scope / health opt-in / checkpoint
-            # scope swaps in the chunked driver (one fetch per
-            # conv_test_iters cycle — the existing cadence), and a
-            # ``DeviceLost`` routes through the recovery ladder
-            # (shrink -> reshard -> restore -> resume).  NOTE: after
-            # a shrink, ``M`` is applied to survivor-mesh vectors —
-            # a mesh-agnostic jittable callable recovers; a
-            # mesh-pinned preconditioner will not.
-            def _solve(A_cur, b_cur, x0_cur, miter):
-                loop = (_cg_loop_resil if _resil_solver_active()
-                        else _cg_loop)
-                return loop(
-                    A_cur.matvec_fn(), M_mv, b_cur, x0_cur, atol,
-                    int(miter), int(conv_test_iters),
-                )
-
-            if _rsettings.resil:
-                with _maybe_ckpt_scope("dist.cg"):
-                    x, iters, A_fin = _solve_with_recovery(
-                        "dist.cg", A, b, b_sh, x0_sh, int(maxiter),
-                        _solve)
-            else:
-                x, iters = _solve(A, b_sh, x0_sh, maxiter)
-                A_fin = A
-        if sp is not None:
-            # One host sync for honest timing + the true iteration
-            # count (tracing mode only, uncounted; see linalg.cg).  The
-            # same count drives the comm ledger: the loop body is
-            # traced once, so the per-iteration volumes are multiplied
-            # out here rather than at the (trace-time) dispatch.
-            it = int(iters)
-            vols, calls = cg_comm_volumes(
-                A_fin, jnp.dtype(b_sh.dtype).itemsize, it)
-            sp.set(iters=it,
-                   comm_bytes=_comm.record("dist_cg", vols, calls,
-                                           layout=A_fin.layout),
-                   comm_calls=sum(calls[k] for k, b in vols.items()
-                                  if b > 0))
-    return x[:rows], iters
-
-
-def _dist_cg_prologue(A: DistCSR, b, x0, tol, maxiter, M, callback,
-                      atol, rtol):
-    """What both ``dist_cg`` paths need before their loop: the
-    sharded system (``_shard_system``), the starting iterate, the
-    absolute tolerance from ‖b‖ (one host sync, the ``dist_cg.norm``
-    span) and the preconditioner's matvec."""
-    from ..linalg import _get_atol_rtol
-
     rows, b_sh, x0_sh, maxiter, cb = _shard_system(
         A, b, x0, maxiter, callback
     )
-    if x0_sh is None:
-        x0_sh = jnp.zeros_like(b_sh)
-    with _obs.span("dist_cg.norm"):
-        _obs.inc("transfer.host_sync.dist_cg_norm")
-        bnrm2 = float(jnp.linalg.norm(b_sh))
-    atol, _ = _get_atol_rtol(bnrm2, tol, atol, rtol)
     M_mv = M if M is not None else (lambda r: r)
-    return rows, b_sh, x0_sh, maxiter, cb, atol, M_mv
+    A_fin = A
 
+    def solve(loop, b_sh, x0_sh, atol):
+        # Resilience: the whole loop dispatch is the ``dist.cg`` site —
+        # an injected (or real) collective failure retries the solve
+        # from x0, which re-converges to the identical answer instead
+        # of corrupting the Krylov state.  An active deadline scope /
+        # health opt-in / checkpoint scope swaps in the chunked loop
+        # (one fetch per conv_test_iters cycle — the existing cadence),
+        # and a ``DeviceLost`` routes through the recovery ladder
+        # (shrink -> reshard -> restore -> resume).  NOTE: after a
+        # shrink, ``M`` is applied to survivor-mesh vectors — a
+        # mesh-agnostic jittable callable recovers; a mesh-pinned
+        # preconditioner will not.
+        nonlocal A_fin
 
-def _dist_cg_observed(A: DistCSR, b, x0, tol, maxiter, M, callback,
-                      atol, rtol, conv_test_iters):
-    """``dist_cg``'s callback path: a Python-driven loop so user code
-    observes every iterate (mirrors ``linalg.cg``'s callback contract;
-    the truncated host view of x is passed, matching the reference's
-    semantics)."""
-    from ..obs import comm as _comm
+        def _solve(A_cur, b_cur, x0_cur, miter):
+            return loop(A_cur.matvec_fn(), M_mv, b_cur, x0_cur, atol,
+                        int(miter), int(conv_test_iters))
 
-    rows, b_sh, x0_sh, maxiter, cb, atol, M_mv = _dist_cg_prologue(
-        A, b, x0, tol, maxiter, M, callback, atol, rtol)
-    item = jnp.dtype(b_sh.dtype).itemsize
-    A_mv = A.matvec_fn()
-    x = x0_sh
-    r = b_sh - A_mv(x)
-    p = jnp.zeros_like(b_sh)
-    rho = jnp.ones((), dtype=b_sh.dtype)
-    iters = 0
-    n_norm = 0
-    while iters < maxiter:
-        z = M_mv(r)
-        rho_old = rho
-        rho = jnp.vdot(r, z)
-        # Same zero-division guards as _cg_loop: an exactly-converged
-        # residual must reach the convergence check, not produce NaNs.
-        beta = jnp.where(
-            jnp.logical_or(iters == 0, rho_old == 0),
-            jnp.zeros_like(rho),
-            rho / jnp.where(rho_old == 0, jnp.ones_like(rho_old), rho_old),
-        )
-        p = z + beta * p
-        q = A_mv(p)
-        pq = jnp.vdot(p, q)
-        alpha = jnp.where(
-            pq == 0, jnp.zeros_like(rho),
-            rho / jnp.where(pq == 0, jnp.ones_like(pq), pq),
-        )
-        x = x + alpha * p
-        r = r - alpha * q
-        iters += 1
-        cb(x)
-        if iters % conv_test_iters == 0 or iters == maxiter - 1:
-            n_norm += 1
-            if float(jnp.linalg.norm(r)) < atol:
-                break
-    # Every eager A_mv dispatch above self-recorded its realization
-    # under comm.dist_spmv.*, so recording SpMV volumes again here
-    # would double-count the same bytes.  Only the scalar reductions
-    # this Python loop adds are ledgered under dist_cg: rho + pq every
-    # iteration, plus the residual norms the check branch actually
-    # executed (counted in the loop, not approximated — the ledger's
-    # contract is exactness).
-    n_psum = 2 * iters + n_norm
-    _comm.record(
-        "dist_cg",
-        {"psum": n_psum * _comm.psum_bytes(1, item, A.num_shards)},
-        calls={"psum": n_psum},
-        layout=A.layout,
-    )
+        if not _rsettings.resil:
+            return _solve(A, b_sh, x0_sh, maxiter)
+        with _maybe_ckpt_scope("dist.cg"):
+            x, iters, A_fin = _solve_with_recovery(
+                "dist.cg", A, b, b_sh, x0_sh, int(maxiter), _solve)
+        return x, iters
+
+    def ledger(it):
+        # The loop body is traced once, so the per-iteration volumes
+        # are multiplied out here rather than at the (trace-time)
+        # dispatch; the final mesh prices them.
+        vols, calls = cg_comm_volumes(
+            A_fin, jnp.dtype(b_sh.dtype).itemsize, it)
+        return dict(comm_bytes=_comm.record("dist_cg", vols, calls,
+                                            layout=A_fin.layout),
+                    comm_calls=sum(calls[k] for k, v in vols.items()
+                                   if v > 0))
+
+    with _tctx.profiler_scope("dist_cg"), \
+            _mem.watermark("dist_cg", n=rows, shards=A.num_shards):
+        x, iters = _cg_solve(
+            "dist_cg", rows, solve, ledger, b_sh, x0_sh, tol, atol, rtol,
+            maxiter, cb, shards=A.num_shards, preconditioned=M is not None)
     return x[:rows], iters

@@ -178,24 +178,32 @@ def test_dist_cg_comm_matches_iteration_model():
 
 @needs_mesh
 def test_dist_cg_callback_path_does_not_double_count_spmv():
-    """The eager callback loop's A_mv calls self-record under
-    comm.dist_spmv.*; dist_cg must ledger only the scalar reductions
-    the driver adds — re-recording the SpMV volumes would double the
-    reported interconnect bytes vs the fused path."""
+    """A callback solve runs the loop path's iteration, so it leaves the
+    loop path's ledger: the same comm.dist_spmv.* (one SpMV dispatch
+    for the initial residual, one traced into the loop) and the same
+    comm.dist_cg.*, priced from ``cg_comm_volumes`` alone."""
+    trace.enable()
     mesh = make_row_mesh()
     n = 32 * R
     dA = shard_csr(_banded(n), mesh=mesh)
-    counters.reset("comm.")
+    b = np.ones(n, np.float32)
+
+    def ledger(**kw):
+        counters.reset("comm.")
+        _, iters = dist_cg(dA, b, rtol=0.0, maxiter=3, **kw)
+        return int(iters), {k: v for k, v in counters.snapshot().items()
+                            if k.startswith(("comm.dist_cg.",
+                                             "comm.dist_spmv."))}
+
     seen = []
-    _ = dist_cg(dA, np.ones(n, np.float32), rtol=0.0, maxiter=3,
-                callback=seen.append)
-    assert len(seen) == 3
-    # 4 eager dispatches: the initial residual + one per iteration.
-    assert counters.get("comm.dist_spmv.ppermute") == 4
-    # No SpMV bytes under dist_cg — psums only.
-    assert counters.get("comm.dist_cg.ppermute") == 0
-    assert counters.get("comm.dist_cg.ppermute_bytes") == 0
-    assert counters.get("comm.dist_cg.psum") == 2 * 3 + 3 // 25 + 1
+    it_loop, loop = ledger()
+    it_cb, observed = ledger(callback=seen.append)
+    assert it_cb == it_loop == len(seen) == 3
+    assert observed == loop
+    vols, _calls = cg_comm_volumes(dA, 4, it_cb)
+    assert sum(v for k, v in observed.items()
+               if k.startswith("comm.dist_cg.") and k.endswith("_bytes")) \
+        == sum(vols.values())
 
 
 @needs_mesh
