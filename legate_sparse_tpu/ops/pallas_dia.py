@@ -34,7 +34,10 @@ Design (role parity with the reference's hand-tuned SpMV leaf,
 - The x vector is viewed as three aligned neighbor tiles
   (prev/center/next, clamped at the edges) so a shifted window never
   needs a misaligned HBM load; Mosaic requires dynamic vector loads to
-  be 1024-element aligned, which is exactly what this avoids.
+  be 1024-element aligned, which is exactly what this avoids.  A shard
+  of the distributed SpMV passes its two halos as edge tiles: the prev
+  view at the grid's first step and the next view at its last come
+  from them, so the kernel reads the shard's x where it lies.
 - A flat shift by ``s = q*L + r`` (floor divmod, lane width L=128)
   becomes: sublane-roll by ``q`` (and ``q+1``), lane-roll by ``r``,
   then a lane-index select between the two — three register ops, no
@@ -49,8 +52,11 @@ Design (role parity with the reference's hand-tuned SpMV leaf,
   ``ops/spmv.py``).  The encoding is exact: -0.0 * x differs from
   +0.0 * x only in the sign of a zero, and the accumulator starts at
   +0.0, where +0 + (-0) = +0, so every sum is bit-identical to the
-  masked form.  The band streams 4 bytes a slot (2 for bf16) and no
-  mask.
+  masked form, but for one sign of zero: the compiled kernel (Mosaic
+  on the v5e, XLA in interpret mode) folds the +0.0 start away, so a
+  row whose every live product is -0.0 (explicit zeros times positive
+  x) reads -0.0 where CSR reads +0.0.  The band streams 4 bytes a slot
+  (2 for bf16) and no mask.
 
 Supported: f32/bf16 values (f64 is rejected — Mosaic has no 64-bit
 vectors; the XLA path in ``ops/dia_ops.py`` serves f64),
@@ -288,19 +294,37 @@ def _band_term(d, xs):
     return d * jnp.where(live, xs, jnp.zeros((), jnp.float32))
 
 
-def _make_kernel(offsets: Tuple[int, ...], tile: int, interpret: bool):
+def _make_kernel(offsets: Tuple[int, ...], tile: int, interpret: bool,
+                 last: Optional[int] = None):
+    """The SpMV kernel.  With ``last`` (the grid's last step) it takes
+    two edge tiles after the three x views: the prev view at step 0 is
+    the low edge, the next view at step ``last`` the high edge."""
     Rt = tile // L
 
-    def kernel(xm_ref, xc_ref, xp_ref, d_ref, y_ref):
+    def band(xm, xc, xp, d_ref, y_ref):
         # Widened before the rolls: Mosaic rotates 32-bit data only.
-        w = jnp.concatenate([xm_ref[:], xc_ref[:], xp_ref[:]],
-                            axis=0).astype(jnp.float32)
+        w = jnp.concatenate([xm, xc, xp], axis=0).astype(jnp.float32)
         lane3 = jax.lax.broadcasted_iota(jnp.int32, (3 * Rt, L), 1)
         acc = jnp.zeros((Rt, L), jnp.float32)
         for di, off in enumerate(offsets):
             xs = _flat_shift(w, off, lane3, interpret)[Rt: 2 * Rt]
             acc = acc + _band_term(d_ref[di], xs)
         y_ref[:] = acc.astype(y_ref.dtype)
+
+    if last is None:
+        def kernel(xm_ref, xc_ref, xp_ref, d_ref, y_ref):
+            band(xm_ref[:], xc_ref[:], xp_ref[:], d_ref, y_ref)
+
+        return kernel
+
+    import jax.experimental.pallas as pl
+
+    def kernel(xm_ref, xc_ref, xp_ref, lo_ref, hi_ref, d_ref, y_ref):
+        f32 = lambda ref: ref[:].astype(jnp.float32)
+        step = jnp.full((Rt, L), pl.program_id(0), jnp.int32)
+        xm = jnp.where(step == 0, f32(lo_ref), f32(xm_ref))
+        xp = jnp.where(step == last, f32(hi_ref), f32(xp_ref))
+        band(xm, f32(xc_ref), xp, d_ref, y_ref)
 
     return kernel
 
@@ -309,10 +333,16 @@ def _make_kernel(offsets: Tuple[int, ...], tile: int, interpret: bool):
          static_argnames=("offsets", "shape", "tile", "interpret"))
 def pallas_dia_spmv(rdata, x, offsets: Tuple[int, ...],
                     shape: Tuple[int, int], tile: int,
-                    interpret: bool = False):
+                    interpret: bool = False, edges=None):
     """y = A @ x over the row-aligned band layout (see ``row_align``).
 
     ``rdata`` blocked (nd, rows_pad//L, L); x of length cols.
+
+    ``edges``, a pair of (tile/L, L) tiles, stands for the x below
+    row 0 and past the last row: the prev view of the grid's first
+    step and the next view of its last.  A shard passes its halos so,
+    and the kernel reads x where it lies; x then has to be a whole
+    number of tiles, one per grid step.
     """
     import jax.experimental.pallas as pl
 
@@ -321,26 +351,43 @@ def pallas_dia_spmv(rdata, x, offsets: Tuple[int, ...],
     nd = len(offsets)
     rows_pad = rdata.shape[1] * L
     nt = rows_pad // tile
-    # x padded so every clamped neighbor-tile view is in range.
-    x_pad = -(-max(cols, rows_pad) // tile) * tile
-    ntx = x_pad // tile
-    xv = jnp.pad(x, (0, x_pad - cols)).reshape(-1, L)
+    if edges is None:
+        # x padded so every clamped neighbor-tile view is in range.
+        x_pad = -(-max(cols, rows_pad) // tile) * tile
+        ntx = x_pad // tile
+        xv = jnp.pad(x, (0, x_pad - cols)).reshape(-1, L)
+    else:
+        if cols != rows_pad:
+            raise ValueError(f"edge tiles need x of {rows_pad} rows, "
+                             f"one tile a grid step; got {cols}")
+        ntx = nt
+        xv = x.reshape(-1, L)
+    views = [
+        pl.BlockSpec((Rt, L), lambda i: (jnp.maximum(i - 1, 0), _I0)),
+        pl.BlockSpec((Rt, L), lambda i: (jnp.minimum(i, ntx - 1), _I0)),
+        pl.BlockSpec((Rt, L),
+                     lambda i: (jnp.minimum(i + 1, ntx - 1), _I0)),
+    ]
+    band = pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0))
+    if edges is None:
+        kernel = _make_kernel(offsets, tile, interpret)
+        in_specs, args = views + [band], (xv, xv, xv, rdata)
+    else:
+        # A constant block index: each edge tile is fetched once a call.
+        edge = pl.BlockSpec((Rt, L), lambda i: (_I0, _I0))
+        kernel = _make_kernel(offsets, tile, interpret, last=nt - 1)
+        in_specs = views + [edge, edge, band]
+        args = (xv, xv, xv, *edges, rdata)
 
     y2 = pl.pallas_call(
-        _make_kernel(offsets, tile, interpret),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((rows_pad // L, L), rdata.dtype),
         grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((Rt, L), lambda i: (jnp.maximum(i - 1, 0), _I0)),
-            pl.BlockSpec((Rt, L), lambda i: (jnp.minimum(i, ntx - 1), _I0)),
-            pl.BlockSpec((Rt, L),
-                         lambda i: (jnp.minimum(i + 1, ntx - 1), _I0)),
-            pl.BlockSpec((nd, Rt, L), lambda i: (_I0, i, _I0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((Rt, L), lambda i: (i, _I0)),
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(xv, xv, xv, rdata)
+    )(*args)
     return y2.reshape(-1)[:rows]
 
 

@@ -376,6 +376,10 @@ def attach_dia_prepack(dist: DistCSR) -> DistCSR:
     differ only in the sign of a zero that the +0.0-started sums
     absorb, the products are bit-identical to the masked form.  The
     in-range hole slots are counted under ``pallas.pack.hole_slots``.
+
+    The tile covers the copied window's reach, the offsets shifted by
+    +halo, so the shard body can take either route (``_dia_window``);
+    the pack itself does not depend on the route.
     """
     from ..ops.pallas_dia import encode_band, pallas_dist_mode, supported
 
@@ -969,17 +973,17 @@ def dist_plan_fingerprint(A: DistCSR) -> str:
             f":t{A.pdia_tile}:g{grid}")
 
 
-def _extend_x(x_local, halo: int, axis: int = 0):
+def _halo_exchange(x_local, halo: int, axis: int = 0):
     """Halo exchange: ppermute boundary slices to/from ring neighbors
-    along ``axis`` of the local block.
+    along ``axis`` of the local block.  Returns ``(from_left,
+    from_right)``: the left neighbor's last ``halo`` slices and the
+    right neighbor's first ``halo`` (ring-wrapped at the mesh's ends).
 
     Structurally the ring/context-parallel neighbor pattern: each shard
     never materializes the global x — this is what makes 1e8-row weak
     scaling possible where ``all_gather`` would not (SURVEY §7 hard
     part #4).
     """
-    if halo <= 0:
-        return x_local
     axis_size = jax.lax.axis_size(ROW_AXIS)
     right_perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
     left_perm = [(i, (i - 1) % axis_size) for i in range(axis_size)]
@@ -988,6 +992,16 @@ def _extend_x(x_local, halo: int, axis: int = 0):
     head = jax.lax.slice_in_dim(x_local, 0, halo, axis=axis)
     from_left = jax.lax.ppermute(tail, ROW_AXIS, right_perm)
     from_right = jax.lax.ppermute(head, ROW_AXIS, left_perm)
+    return from_left, from_right
+
+
+def _extend_x(x_local, halo: int, axis: int = 0):
+    """The halo-extended window ``[from_left, x_local, from_right]``
+    along ``axis`` (``_halo_exchange``): a fresh buffer ``2 * halo``
+    longer than the local block."""
+    if halo <= 0:
+        return x_local
+    from_left, from_right = _halo_exchange(x_local, halo, axis)
     return jnp.concatenate([from_left, x_local, from_right], axis=axis)
 
 
@@ -1042,39 +1056,73 @@ def _dia_spmv_fn(mesh: Mesh, offsets: Tuple[int, ...], halo: int,
     ))
 
 
+def _dia_window(rps: int, halo: int, tile: int) -> str:
+    """How the banded Pallas dist SpMV's shard body hands x to the
+    kernel: ``"in-place"`` when the shard's rows are a whole number of
+    tiles and the halo fits in one tile, else ``"copied"`` into the
+    halo-extended window (``_dia_spmv_pallas_fn``)."""
+    return "in-place" if rps % tile == 0 and halo <= tile else "copied"
+
+
 @lru_cache(maxsize=256)
 def _dia_spmv_pallas_fn(mesh: Mesh, offsets: Tuple[int, ...], halo: int,
                         rps: int, tile: int, interpret: bool):
     """Cached shard_map callable for the banded dist SpMV through the
     per-shard Mosaic kernel over the **pre-blocked** layout
     (``DistCSR.pdia_data``, built once at ``shard_csr`` time — the
-    cached-partition analog): the shard body is one halo ``ppermute``
-    plus one ``pallas_dia_spmv`` call, zero packing.
+    cached-partition analog): the shard body is one halo exchange plus
+    one ``pallas_dia_spmv`` call, zero packing.
 
-    The halo-extended window makes the local problem a rectangular band
-    with offsets shifted by +halo.  Global bounds, ring-wrap slots,
-    padding rows and band holes are +0.0 in the pack and every explicit
-    entry has nonzero bits (an explicit +0.0 is stored as -0.0), so the
-    kernel multiplies entries only: IEEE non-finite-x semantics match
-    the XLA branch exactly, and -0.0 changes no sum, whose accumulator
-    starts at +0.0 (``attach_dia_prepack``).  The shard body runs inside
-    shard_map's trace, so a Mosaic compile failure surfaces at the
-    outer compile — callers gate on ``supported()`` having produced the
-    prepack and on result-dtype equality.
+    The kernel takes x in one of two ways (``_dia_window``).  In place:
+    the kernel reads ``x_local`` where it lies, with the band's own
+    offsets, and the two halos ride as edge tiles — ``from_left`` at the
+    end of a zero tile below the shard, ``from_right`` at the start of
+    one above it (``pallas_dia_spmv``'s ``edges``).  Copied: x and its
+    halos are concatenated into a window ``2 * halo`` longer, whose
+    local problem is a rectangular band with offsets shifted by +halo.
+    Either way each slot multiplies the same x element in the same
+    diagonal order, so the two give the same bits.  Each build counts
+    its route under ``dist_csr.dia_window.in_place`` / ``.copied``.
+
+    Global bounds, ring-wrap slots, padding rows and band holes are
+    +0.0 in the pack and every explicit entry has nonzero bits (an
+    explicit +0.0 is stored as -0.0), so the kernel multiplies entries
+    only: a non-finite value in a wrapped halo or in the edge tiles'
+    zero fill injects nothing, and IEEE non-finite-x semantics match
+    the XLA branch exactly (``attach_dia_prepack``).  The one
+    difference is the sign of a zero: a row whose every live product
+    is -0.0 reads -0.0 here and +0.0 in the XLA branch (the compiled
+    kernel folds its +0.0 accumulator start).  The shard body runs
+    inside shard_map's trace, so a Mosaic compile failure surfaces at
+    the outer compile — callers gate on ``supported()`` having produced
+    the prepack and on result-dtype equality.
     """
     _obs.inc("jit_miss.dist_csr.dia_spmv_pallas_fn")
     from ..ops.pallas_dia import L as _LANES
     from ..ops.pallas_dia import pallas_dia_spmv
 
-    offs2 = tuple(int(o) + halo for o in offsets)
     nd = len(offsets)
+    in_place = _dia_window(rps, halo, tile) == "in-place"
+    _obs.inc("dist_csr.dia_window.in_place" if in_place
+             else "dist_csr.dia_window.copied")
+
+    def edge_tiles(x_local):
+        from_left, from_right = _halo_exchange(x_local, halo)
+        lo = jnp.pad(from_left, (tile - halo, 0))
+        hi = jnp.pad(from_right, (0, tile - halo))
+        return lo.reshape(-1, _LANES), hi.reshape(-1, _LANES)
 
     def dia_kernel(pdata, x_local):
+        band = pdata[0].reshape(nd, -1, _LANES)
+        if in_place:
+            return pallas_dia_spmv(
+                band, x_local, offsets, (rps, rps), tile,
+                interpret=interpret, edges=edge_tiles(x_local),
+            )
         x_ext = _extend_x(x_local, halo)
         return pallas_dia_spmv(
-            pdata[0].reshape(nd, -1, _LANES),
-            x_ext, offs2, (rps, x_ext.shape[0]), tile,
-            interpret=interpret,
+            band, x_ext, tuple(int(o) + halo for o in offsets),
+            (rps, x_ext.shape[0]), tile, interpret=interpret,
         )
 
     in_specs = (P(ROW_AXIS, None, None), P(ROW_AXIS))
@@ -1660,7 +1708,8 @@ def _dist_spmv_impl(A: DistCSR, x: jax.Array) -> jax.Array:
                     A.pdia_tile, mode == "interpret",
                 )
                 if sp is not None:
-                    sp.set(path="dia-pallas")
+                    sp.set(path="dia-pallas", window=_dia_window(
+                        A.rows_per_shard, halo, A.pdia_tile))
                 return fn(A.pdia_data, x)
             has_mask = A.dia_mask is not None
             fn = _dia_spmv_fn(

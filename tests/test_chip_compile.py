@@ -166,24 +166,37 @@ def _rowids(S):
         S((nnz,), jnp.int32), S((n,), jnp.float32))
 
 
-def _dist_1d(topo):
+def _dist_1d(grid):
     """The banded 1d-row dist SpMV plan body (halo ppermute + the
-    per-shard Mosaic kernel) for the 5-point grid on 4 chips."""
-    from legate_sparse_tpu.parallel.dist_csr import _dia_spmv_pallas_fn
+    per-shard Mosaic kernel) for the 5-point ``grid``² grid on 4 chips:
+    2048² gives whole tiles a shard and the in-place halo route, 2050²
+    does not and copies x into the halo window."""
 
-    mesh = Mesh(np.asarray(topo.devices[:4]), ("rows",))
-    R, rows = 4, GRID * GRID
-    rps, halo = rows // R, GRID
-    offsets = (-GRID, -1, 0, 1, GRID)
-    tile = pallas_dia.supported(tuple(o + halo for o in offsets),
-                                jnp.float32)
-    rps_pad = -(-rps // tile) * tile
-    spec = NamedSharding(mesh, P("rows", None, None))
-    band = (len(offsets), rps_pad)
-    args = (jax.ShapeDtypeStruct((R, *band), jnp.float32, sharding=spec),
-            jax.ShapeDtypeStruct((rows,), jnp.float32,
-                                 sharding=NamedSharding(mesh, P("rows"))))
-    return _dia_spmv_pallas_fn(mesh, offsets, halo, rps, tile, False), args
+    def case(topo):
+        from legate_sparse_tpu.parallel.dist_csr import (
+            _dia_spmv_pallas_fn, _dia_window)
+
+        mesh = Mesh(np.asarray(topo.devices[:4]), ("rows",))
+        R = 4
+        rps = -(-grid * grid // R)
+        rows, halo = R * rps, grid
+        offsets = (-grid, -1, 0, 1, grid)
+        tile = pallas_dia.supported(tuple(o + halo for o in offsets),
+                                    jnp.float32)
+        assert _dia_window(rps, halo, tile) == (
+            "in-place" if grid == GRID else "copied")
+        rps_pad = -(-rps // tile) * tile
+        spec = NamedSharding(mesh, P("rows", None, None))
+        band = (len(offsets), rps_pad)
+        args = (jax.ShapeDtypeStruct((R, *band), jnp.float32,
+                                     sharding=spec),
+                jax.ShapeDtypeStruct((rows,), jnp.float32,
+                                     sharding=NamedSharding(mesh,
+                                                            P("rows"))))
+        fn = _dia_spmv_pallas_fn(mesh, offsets, halo, rps, tile, False)
+        return fn, args
+
+    return case
 
 
 def _dist_2d(topo):
@@ -221,7 +234,9 @@ ONE_CHIP = {
     "bsr-spmm-k16": _bsr(16),
     "csr-rowids": _rowids,
 }
-FOUR_CHIPS = {"dist-spmv-1d-row": _dist_1d, "dist-spmv-2d-block": _dist_2d}
+FOUR_CHIPS = {"dist-spmv-1d-row": _dist_1d(GRID),
+              "dist-spmv-2d-block": _dist_2d,
+              "dist-spmv-1d-row-copied": _dist_1d(GRID + 2)}
 
 
 @pytest.mark.parametrize("case", [*ONE_CHIP, *FOUR_CHIPS])

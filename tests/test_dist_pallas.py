@@ -188,3 +188,221 @@ def test_dist_dia_spmv_pallas_ieee_nonfinite(band, mesh, monkeypatch, rng):
     # 0 * inf at the explicit +0.0 and -0.0 entries, as in CSR.
     assert np.isnan(y[40]) and np.isnan(y[41]) and np.isnan(y[42])
     assert np.isfinite(y[n // 2])
+
+
+# ------------------------------------------------- the in-place halo route
+# A forced 1024-row tile puts whole tiles in a CPU-sized shard: 8 shards
+# of 1024 rows run one grid step each, of 2048 rows two; 1536 rows is
+# not a whole number of tiles and keeps the copied window.
+_TILE = 1024
+_REACH = 64
+
+
+def _band(rps, rng, holey=False):
+    """An 8-shard band of reach ``_REACH`` with small half-integer
+    values, so every product and sum is exact; holey: 20% of its slots
+    missing, and an explicit +0.0 and -0.0 on the diagonal of the rows
+    ``_zero_rows``."""
+    import scipy.sparse as sp
+
+    n = 8 * rps
+    rows, cols, vals = [], [], []
+    for off in (-_REACH, -1, 0, 1, _REACH):
+        r = np.arange(max(0, -off), min(n, n - off))
+        if holey:
+            r = r[rng.random(r.size) > 0.2]
+        rows.append(r)
+        cols.append(r + off)
+        vals.append(rng.integers(-4, 5, r.size) * 0.5)
+    rows, cols, vals = map(np.concatenate, (rows, cols, vals))
+    if holey:
+        zr = _zero_rows(n)
+        keep = ~((rows == cols) & np.isin(rows, zr))
+        rows = np.concatenate([rows[keep], zr])
+        cols = np.concatenate([cols[keep], zr])
+        vals = np.concatenate([vals[keep], [0.0, -0.0]])
+    A_sp = sp.csr_matrix((vals.astype(np.float32), (rows, cols)),
+                         shape=(n, n))
+    return sparse.csr_array(A_sp)
+
+
+def _zero_rows(n):
+    return np.array([n // 2 + 3, n // 2 + 5])
+
+
+@pytest.fixture
+def tiled(monkeypatch):
+    """The Pallas dist route in interpret mode at the forced tile."""
+    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_DIST", "interpret")
+    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_TILE", str(_TILE))
+
+
+def _routes(dA, xs, monkeypatch):
+    """y through the Pallas route, then through the XLA branch."""
+    y_pl = np.asarray(dist_spmv(dA, xs))
+    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_DIST", "0")
+    y_xla = np.asarray(dist_spmv(dA, xs))
+    monkeypatch.setenv("LEGATE_SPARSE_TPU_PALLAS_DIST", "interpret")
+    return y_pl, y_xla
+
+
+def _bits(a):
+    """The float32 bits of ``a``, with -0.0 read as +0.0.  A row whose
+    every live product is -0.0 (an explicit zero, stored as -0.0 in the
+    pack, times a positive x) reads -0.0 from the band kernel on either
+    route, because the compiled kernel folds its +0.0 accumulator start
+    (XLA in interpret mode, Mosaic on the chip), and +0.0 from the XLA
+    branch.  Every other bit, NaN's included, is compared."""
+    a = np.asarray(a, np.float32)
+    return np.where(a == 0, np.float32(0), a).view(np.uint32)
+
+
+@pytest.mark.parametrize("band", ["exact", "holey"])
+@pytest.mark.parametrize("rps", [1024, 2048, 1536],
+                         ids=["one-step", "two-steps", "not-whole-tiles"])
+def test_dist_dia_window_routes_match_xla(rps, band, mesh, tiled,
+                                          monkeypatch, rng):
+    """Both routes of the Pallas shard body equal the XLA shifted-add
+    branch bit for bit: in place with one grid step a shard (both edge
+    tiles in one step) and with two; copied where the shard is not a
+    whole number of tiles."""
+    from legate_sparse_tpu.parallel.dist_csr import _dia_window
+
+    A = _band(rps, rng, holey=band == "holey")
+    n = A.shape[0]
+    dA = shard_csr(A, mesh=mesh)
+    assert dA.rows_per_shard == rps and dA.halo == _REACH
+    assert dA.pdia_tile == _TILE
+    assert (dA.dia_mask is not None) == (band == "holey")
+    want = "copied" if rps % _TILE else "in-place"
+    assert _dia_window(rps, dA.halo, dA.pdia_tile) == want
+    x = (rng.integers(-8, 9, n) * 0.25).astype(np.float32)
+    xs = shard_vector(x, mesh, dA.rows_padded)
+    y_pl, y_xla = _routes(dA, xs, monkeypatch)
+    np.testing.assert_array_equal(_bits(y_pl), _bits(y_xla))
+    np.testing.assert_array_equal(y_pl[:n], A.toscipy() @ x)
+
+
+@pytest.mark.parametrize("band", ["exact", "holey"])
+def test_dist_dia_in_place_ieee_nonfinite(band, mesh, tiled, monkeypatch,
+                                          rng):
+    """A non-finite x in a ring-wrapped halo, which reaches the first
+    and last shards' edge tiles, does not reach y on the in-place
+    route; a non-finite x an explicit zero multiplies does."""
+    A = _band(2048, rng, holey=band == "holey")
+    n = A.shape[0]
+    dA = shard_csr(A, mesh=mesh)
+    x = np.ones(n, np.float32)
+    x[0] = np.inf     # the last shard's high edge tile, via the ring
+    x[-1] = np.nan    # the first shard's low edge tile, via the ring
+    xs = shard_vector(x, mesh, dA.rows_padded)
+    y_pl, y_xla = _routes(dA, xs, monkeypatch)
+    np.testing.assert_array_equal(_bits(y_pl), _bits(y_xla))
+    y_ref = A.toscipy() @ x
+    np.testing.assert_array_equal(np.isnan(y_pl[:n]), np.isnan(y_ref))
+    np.testing.assert_array_equal(np.isinf(y_pl[:n]), np.isinf(y_ref))
+    # Rows whose band never reaches column 0 or n-1 stay finite.
+    assert np.isfinite(y_pl[_REACH + 1: n - _REACH - 1]).all()
+    if band == "holey":
+        # The explicit +0.0 / -0.0 on the diagonal times inf is NaN.
+        zr = _zero_rows(n)
+        x[zr] = np.inf
+        xs = shard_vector(x, mesh, dA.rows_padded)
+        y_pl, y_xla = _routes(dA, xs, monkeypatch)
+        np.testing.assert_array_equal(_bits(y_pl), _bits(y_xla))
+        assert np.isnan(y_pl[zr]).all()
+
+
+@pytest.mark.parametrize("rps,route", [(2048, "in_place"),
+                                        (1536, "copied")])
+def test_dist_dia_window_counters(rps, route, mesh, tiled, rng):
+    """Building the shard body counts its route once a program; the
+    ``dist_spmv`` span names it beside ``path="dia-pallas"``."""
+    from legate_sparse_tpu import obs
+    from legate_sparse_tpu.parallel.dist_csr import _dia_spmv_pallas_fn
+
+    A = _band(rps, rng)
+    dA = shard_csr(A, mesh=mesh)
+    xs = shard_vector(np.ones(A.shape[0], np.float32), mesh,
+                      dA.rows_padded)
+    _dia_spmv_pallas_fn.cache_clear()
+    keys = ("dist_csr.dia_window.in_place", "dist_csr.dia_window.copied")
+    before = {k: obs.counters.get(k) for k in keys}
+    obs.enable()
+    try:
+        obs.reset()
+        for _ in range(2):
+            dist_spmv(dA, xs).block_until_ready()
+        spans = [r for r in obs.records()
+                 if r.get("type") == "span" and r["name"] == "dist_spmv"]
+    finally:
+        obs.disable()
+    grew = {k: obs.counters.get(k) - before[k] for k in keys}
+    assert grew == {f"dist_csr.dia_window.{r}": int(r == route)
+                    for r in ("in_place", "copied")}
+    assert len(spans) == 2
+    for sp in spans:
+        assert sp["attrs"]["path"] == "dia-pallas"
+        assert sp["attrs"]["window"] == route.replace("_", "-")
+
+
+def _long_copies(jaxpr, length):
+    """The ``concatenate`` and ``pad`` equations of ``jaxpr`` and its
+    sub-jaxprs, outside any Pallas kernel, whose output holds at least
+    ``length`` elements."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("concatenate", "pad"):
+            if any(np.prod(v.aval.shape) >= length for v in eqn.outvars):
+                found.append(eqn)
+        if name == "pallas_call":
+            continue
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _long_copies(sub, length)
+    return found
+
+
+@pytest.mark.parametrize("rps,route", [(2048, "in-place"),
+                                        (1536, "copied")])
+def test_dist_dia_in_place_builds_no_window(rps, route, mesh, tiled, rng):
+    """On the in-place route the jitted shard body holds no
+    concatenate or pad as long as the shard's rows; the copied route,
+    the control, holds the window's."""
+    from legate_sparse_tpu.parallel.dist_csr import _dia_spmv_pallas_fn
+
+    A = _band(rps, rng)
+    dA = shard_csr(A, mesh=mesh)
+    xs = shard_vector(np.ones(A.shape[0], np.float32), mesh,
+                      dA.rows_padded)
+    fn = _dia_spmv_pallas_fn(dA.mesh, dA.dia_offsets, dA.halo, rps,
+                             dA.pdia_tile, True)
+    copies = _long_copies(jax.make_jaxpr(fn)(dA.pdia_data, xs).jaxpr, rps)
+    if route == "in-place":
+        assert copies == []
+    else:
+        assert {e.primitive.name for e in copies} >= {"concatenate"}
+
+
+def test_dist_dia_in_place_diagonal(mesh, tiled, monkeypatch, rng):
+    """A diagonal matrix has no halo: the in-place route exchanges
+    nothing, its edge tiles are all zero, and it still matches."""
+    import scipy.sparse as sp
+
+    from legate_sparse_tpu.parallel.dist_csr import _dia_window
+
+    n = 8 * 2048
+    d = (rng.integers(-4, 5, n) * 0.5).astype(np.float32)
+    A = sparse.csr_array(sp.diags([d], [0]).tocsr())
+    dA = shard_csr(A, mesh=mesh)
+    assert dA.halo == 0 and dA.dia_offsets == (0,)
+    assert _dia_window(dA.rows_per_shard, dA.halo, dA.pdia_tile) == (
+        "in-place")
+    x = (rng.integers(-8, 9, n) * 0.25).astype(np.float32)
+    xs = shard_vector(x, mesh, dA.rows_padded)
+    y_pl, y_xla = _routes(dA, xs, monkeypatch)
+    np.testing.assert_array_equal(_bits(y_pl), _bits(y_xla))
+    np.testing.assert_array_equal(y_pl[:n], d * x)
