@@ -25,6 +25,7 @@ TPU-first re-design (not a port):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import warnings
 from typing import Optional, Tuple
@@ -571,7 +572,7 @@ class csr_array(CompressedBase, DenseSparseBase):
                                           dtype=self.dtype)
         return self._bsr
 
-    def _get_dia(self):
+    def _get_dia(self, with_pack: bool = False):
         """Cached banded (DIA) structure, or None.
 
         On TPU, HBM gathers run orders of magnitude below roofline while
@@ -589,39 +590,73 @@ class csr_array(CompressedBase, DenseSparseBase):
         matvec); keeping the band structure is a deliberate TPU-first
         improvement, and it covers every headline benchmark config
         (banded SpMV sweep, 5-pt Poisson PDE, GMG fine grids).
+
+        The build runs once per structure inside one ``dia.build`` span
+        (``_dia_build``).  ``with_pack`` (the SpMV dispatch, when the
+        Pallas kernel may serve the call) builds the kernel's pack in
+        the same span, so a first product records one build.
         """
-        if self._dia is not None:
-            return self._dia if self._dia is not False else None
-        if not self._can_build_cache(self._data, self._indices,
-                                     self._indptr):
-            return None
+        if self._dia is None and self._can_build_cache(
+                self._data, self._indices, self._indptr):
+            with self._dia_build():
+                self._build_dia()
+                if with_pack and self._dia:
+                    from .ops.pallas_dia import pallas_dia_active
+
+                    if pallas_dia_active():
+                        self._build_dia_pack()
+        return self._dia or None
+
+    @contextlib.contextmanager
+    def _dia_build(self):
+        """The ``dia.build`` span around a band structure build, counted
+        in ``dia.build.count``.  Its children are ``dia.detect`` (which
+        fetches its answer, so it holds its device time), ``dia.scatter``
+        and ``dia.pack``; those two time the host's part only (tracing
+        must not stop the host from running ahead of their device
+        work, which ends inside the first product)."""
+        _obs.inc("dia.build.count")
+        with _obs.span("dia.build", nnz=self.nnz) as sp:
+            yield
+            if sp is not None:
+                dia, pack = self._dia or None, self._dia_pack or None
+                sp.set(diags=len(dia[1]) if dia else 0,
+                       exact=bool(dia) and dia[2] is None,
+                       pack_bytes=pack.rdata.nbytes if pack else 0)
+
+    def _build_dia(self):
+        """Detect the band and scatter the values into DIA storage:
+        ``_dia`` becomes ``(dia_data, offsets, mask)``, or False where
+        the matrix is not banded."""
         from .settings import settings
 
         rows, cols = self.shape
         nnz = self.nnz
-        if (settings.dia_max_expand <= 0 or not nnz or not rows
-                or not self.has_canonical_format):
-            self._dia = False
-            return None
-        if self._dia_offsets is None:
-            max_nd = int(min(
-                settings.dia_max_diags,
-                settings.dia_max_expand * nnz / max(cols, 1),
-            ))
-            offsets = (
-                _dia_ops.csr_band_offsets(
-                    self._indices, self._get_row_ids(), max_nd
+        with _obs.span("dia.detect"):
+            if (settings.dia_max_expand <= 0 or not nnz or not rows
+                    or not self.has_canonical_format):
+                self._dia = False
+                return
+            if self._dia_offsets is None:
+                max_nd = int(min(
+                    settings.dia_max_diags,
+                    settings.dia_max_expand * nnz / max(cols, 1),
+                ))
+                offsets = (
+                    _dia_ops.csr_band_offsets(
+                        self._indices, self._get_row_ids(), max_nd
+                    )
+                    if max_nd >= 1
+                    else None
                 )
-                if max_nd >= 1
-                else None
-            )
-            self._dia_offsets = offsets if offsets is not None else False
-        if self._dia_offsets is False:
-            self._dia = False
-            return None
-        offsets = self._dia_offsets
-        # Exact band (every in-bounds slot explicit): no mask needed.
-        exact = _dia_ops.band_cover(offsets, self.shape, cols) == nnz
+                self._dia_offsets = (offsets if offsets is not None
+                                     else False)
+            if self._dia_offsets is False:
+                self._dia = False
+                return
+            offsets = self._dia_offsets
+            # Exact band (every in-bounds slot explicit): no mask needed.
+            exact = _dia_ops.band_cover(offsets, self.shape, cols) == nnz
         # Compressed-value storage (``compress()``) declares the hole
         # trade: ``dia_from_csr`` zero-fills and no mask is kept, so
         # holes read as explicit zeros — exact products for finite x.
@@ -630,41 +665,48 @@ class csr_array(CompressedBase, DenseSparseBase):
         # rounded, and the compress() docstring documents both.
         if str(self.dtype) in ("bfloat16", "float16"):
             exact = True
-        if exact:
-            dia_data = _dia_ops.dia_from_csr(
-                self._data, self._indices, self._get_row_ids(),
-                offsets, cols,
-            )
-            self._dia = (dia_data, offsets, None)
-        else:
-            dia_data, mask = _dia_ops.dia_from_csr(
-                self._data, self._indices, self._get_row_ids(),
-                offsets, cols, with_mask=True,
-            )
-            self._dia = (dia_data, offsets, mask)
-        return self._dia
+        with _obs.span("dia.scatter"):
+            if exact:
+                dia_data = _dia_ops.dia_from_csr(
+                    self._data, self._indices, self._get_row_ids(),
+                    offsets, cols,
+                )
+                self._dia = (dia_data, offsets, None)
+            else:
+                dia_data, mask = _dia_ops.dia_from_csr(
+                    self._data, self._indices, self._get_row_ids(),
+                    offsets, cols, with_mask=True,
+                )
+                self._dia = (dia_data, offsets, mask)
 
     def _get_dia_pack(self):
         """Cached row-aligned band pack for the Pallas DIA kernel
         (``ops/pallas_dia.py``), or None when the matrix isn't banded
         or the kernel doesn't support it (f64, band reach > tile cap).
-        Built once per structure, on top of ``_get_dia()``."""
-        if self._dia_pack is not None:
-            return self._dia_pack if self._dia_pack is not False else None
-        dia = self._get_dia()
-        if dia is None or not self._can_build_cache(
-            self._data, self._indices, self._indptr
-        ):
-            if dia is None:
+        Built once per structure, on top of ``_get_dia()``: in the same
+        ``dia.build`` span where the band is not built yet."""
+        if self._dia_pack is None and self._can_build_cache(
+                self._data, self._indices, self._indptr):
+            if self._dia is False:
                 self._dia_pack = False
-            return None
+            else:
+                with self._dia_build():
+                    if self._dia is None:
+                        self._build_dia()
+                    if self._dia:
+                        self._build_dia_pack()
+                    else:
+                        self._dia_pack = False
+        return self._dia_pack or None
+
+    def _build_dia_pack(self):
         from .ops import pallas_dia as _pallas_dia
 
-        dia_data, offsets, mask = dia
-        packed = _pallas_dia.pack_band(dia_data, offsets, self.shape,
-                                       mask=mask)
+        dia_data, offsets, mask = self._dia
+        with _obs.span("dia.pack"):
+            packed = _pallas_dia.pack_band(dia_data, offsets, self.shape,
+                                           mask=mask)
         self._dia_pack = packed if packed is not None else False
-        return packed
 
     def _get_dia_fused(self):
         """Cached padded band layout for the fused XLA SpMV
@@ -1401,7 +1443,8 @@ class csr_array(CompressedBase, DenseSparseBase):
                 # BSR stands down (the Mosaic kernel is compiled
                 # same-dtype); the gather-class f32acc kernels cover
                 # the rest.
-                dia = src._get_dia() if src is not None else None
+                dia = (src._get_dia(with_pack=not lowp)
+                       if src is not None else None)
                 bsr = (src._get_bsr()
                        if src is not None and not lowp and dia is None
                        else None)

@@ -2,23 +2,29 @@
 # SPDX-License-Identifier: Apache-2.0
 """Pallas TPU kernel for banded (DIA) SpMV — the roofline hot path.
 
-Why this kernel exists (measured on the target v5e chip, 2^24 rows,
-11 diagonals, f32, loop-amortized timing):
+Why this kernel exists (measured on one v5e, the ``spmv-band11``
+benchmark cell's operator: 2^24 rows, 11 diagonals, f32, a CSR built
+directly; per call, 32 resident x a batch, best of 5 batches; GB/s are
+the yardstick's 4 * (nnz + 2 * rows) bytes over the call's time):
 
-=====================================  ==========
-formulation                            bandwidth
-=====================================  ==========
-XLA ``.at[lo:hi].add`` shifted adds     51 GB/s
-XLA pad + slice shifted adds            84 GB/s
-XLA ``jnp.roll`` shifted adds           74 GB/s
-MXU shift-matmul                        49 GB/s
-**this kernel**                        **622 GB/s**
-chip HBM roofline (v5e)                 819 GB/s
-=====================================  ==========
+============================================  =========  ============
+lowering of the same band product             ms/call    bandwidth
+============================================  =========  ============
+XLA ``.at[lo:hi].add`` (``dia_spmv``)         17.24      51 GB/s
+XLA pad + slice (``dia_spmv_fused``)          11.64      75 GB/s
+XLA interior/edge split (``dia_spmv_nopad``)  10.69      82 GB/s
+**this kernel**                               **1.386**  **629 GB/s**
+chip HBM roofline (v5e)                       1.065      819 GB/s
+============================================  =========  ============
+
+Through ``A @ x`` the benchmark cell reads 1.39-1.47 ms a call, the
+kernel at 78.6% of that roofline and, counting the bytes its blocks
+really stream (60 a row: 11 value slots, three x tiles, y), at 90.7%
+of HBM peak: bound by bytes, not by its rolls.
 
 Every XLA formulation of the stencil shift pays a full lane-relayout
 per diagonal (a flat shift by ±1 moves every element across the
-(8, 128) tiled layout), so the op runs ~10x under roofline.  The
+(8, 128) tiled layout), so the op runs 10-16x under roofline.  The
 Mosaic-level fix: keep the shift *inside* VMEM as register rotates —
 ``pltpu.roll`` on the lane and sublane axes plus a lane-boundary
 select — so HBM sees only perfectly aligned streaming loads.

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from legate_sparse_tpu.ops import bsr, pallas_dia
+from legate_sparse_tpu.ops import bsr, dia_ops, pallas_dia
 from legate_sparse_tpu.ops import spmv as spmv_ops
 
 BAND_ROWS = 1 << 24          # chip_smoke phase a
@@ -110,6 +110,21 @@ def _fori_loop(S):
             0, 8, lambda i, v: pallas_dia.pallas_dia_spmv(
                 r, v, OFFSETS, (n, n), tile), x)
     return loop, (rd, S((n,), jnp.float32))
+
+
+def _band_build(S):
+    """The exact band's build from a CSR built directly, as one
+    program: the scatter into DIA storage, then the pack
+    (``spmv-band11``: 2^24 rows, 11 diagonals, 184.5M values)."""
+    n = BAND_ROWS
+    nnz = len(OFFSETS) * n - sum(abs(o) for o in OFFSETS)
+    tile = _band_tile()
+
+    def build(data, indices, row_ids):
+        d = dia_ops.dia_from_csr(data, indices, row_ids, OFFSETS, n)
+        return pallas_dia.row_align(d, OFFSETS, (n, n), tile)
+    return build, (S((nnz,), jnp.float32), S((nnz,), jnp.int32),
+                   S((nnz,), jnp.int32))
 
 
 class _Pack:
@@ -233,6 +248,7 @@ ONE_CHIP = {
     "bsr-spmv": _bsr(None),
     "bsr-spmm-k16": _bsr(16),
     "csr-rowids": _rowids,
+    "band-build-csr11": _band_build,
 }
 FOUR_CHIPS = {"dist-spmv-1d-row": _dist_1d(GRID),
               "dist-spmv-2d-block": _dist_2d,
