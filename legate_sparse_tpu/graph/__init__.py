@@ -47,7 +47,8 @@ def matvec(A, x, semiring="plus-times", kernel=None):
     kernels, dispatched by autotune registry label.
 
     ``kernel`` picks the packed structure explicitly: "semiring-csr"
-    (default — masked gather/segment-reduce over the row-ids pack),
+    (default — masked gather/segment-reduce over the row-ids pack; an
+    or-and product reduces rows by a prefix count read at ``indptr``),
     "semiring-ell" or "semiring-sliced-ell" (require the matrix's ELL
     / sliced-ELL cache to exist, exactly like the plus-times
     candidates they generalize).  All three produce identical results
@@ -85,4 +86,4 @@ def matvec(A, x, semiring="plus-times", kernel=None):
     nnz = jnp.asarray(A.data.shape[0], dtype=jnp.int32)
     return _sp.csr_semiring_spmv_rowids_masked(
         A.data, A.indices, A._get_row_ids(), nnz, x, A.shape[0],
-        sr.add, sr.mul)
+        sr.add, sr.mul, row_ptr=A.indptr)

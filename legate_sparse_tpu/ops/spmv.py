@@ -165,8 +165,10 @@ def ell_spmv(ell_data, ell_cols, ell_counts, x):
     per-row nnz (rows,), masking padded slots' *products* so non-finite
     x entries behave exactly as in the segment-sum path (0*inf must not
     inject NaN).  One 2-D gather + a W-width masked row reduction — no
-    scatter, no searchsorted; measured ~HBM-roofline on TPU where flat
-    scatter-based SpMV is orders of magnitude slower.
+    scatter, no searchsorted.  A flat scatter costs about as much as the
+    gather again: on one v5e, over the 31.4M slots of the Graph500
+    scale-20 push operator, the ``segment_max`` or-and SpMV took 515.5
+    ms, its gather alone 240.4 ms.
     """
     _obs.inc("trace.ell_spmv")
     W = ell_data.shape[1]
@@ -408,18 +410,53 @@ def _semiring_product(mul: str, vals, gathered):
     raise ValueError(f"unknown semiring multiply {mul!r}")
 
 
+def is_boolean_or(add: str, mul: str, vals_dtype, x_dtype) -> bool:
+    """Whether the pair's add is a boolean OR: ``max`` over a bool
+    product (``or-and``, or any pair over bool operands).  Given row
+    bounds, the padded-CSR semiring kernels reduce such rows by a
+    prefix count; every other add keeps its ``segment_*`` reduction."""
+    prod = (jnp.bool_ if mul == "and"
+            else jnp.result_type(vals_dtype, x_dtype))
+    return add == "max" and prod == jnp.bool_
+
+
+def _prefix_or(prod, row_ptr):
+    """Row-wise OR of a (slots,) bool product without a scatter: row r
+    holds a live product exactly when the count of live slots before
+    its end exceeds the count before its start.  One int32 prefix count
+    over the slots, then one gather of the ``rows + 1`` counts at the
+    bounds.  Slots past ``row_ptr[-1]`` (the padding suffix) lie past
+    every row's end and are never read."""
+    c = jnp.cumsum(prod.astype(jnp.int32))
+    at = jnp.concatenate([jnp.zeros((1,), jnp.int32), c])[row_ptr]
+    return at[1:] > at[:-1]
+
+
 @partial(jax.jit, static_argnames=("rows", "add", "mul"))
 def csr_semiring_spmv_rowids_masked(data, indices, row_ids, valid_nnz,
-                                    x, rows: int, add: str, mul: str):
+                                    x, rows: int, add: str, mul: str,
+                                    row_ptr=None):
     """Semiring SpMV over a padded nonzero suffix: the
     ``csr_spmv_rowids_masked`` program with the reduction and product
     generalized to the (add, mul) pair.  ``add="sum", mul="times"``
     is bit-identical to the plus-times kernel (same gather, same
-    in-order segment reduction)."""
+    in-order segment reduction).
+
+    ``row_ptr`` ((rows + 1,) offsets of each row's slots, the
+    ``indptr`` of the valid prefix) lets a boolean OR (``add="max"``
+    over a bool product, :func:`is_boolean_or`) reduce rows by a
+    prefix count in place of the ``segment_max`` scatter.  That is
+    exact: OR over a row is "some slot in [start, end) is live", and
+    the count of live slots before ``end`` minus the count before
+    ``start`` is that number of slots.  ``sum``, and ``min``/``max``
+    over numbers, keep the segment reduction: a count answers only
+    OR."""
     _obs.inc("trace.csr_semiring_spmv_rowids_masked")
-    nnz = data.shape[0]
-    slot = jnp.arange(nnz, dtype=jnp.int32)
     prod = _semiring_product(mul, data, x[indices])
+    if row_ptr is not None and is_boolean_or(add, mul, data.dtype,
+                                             x.dtype):
+        return _prefix_or(prod, row_ptr)
+    slot = jnp.arange(data.shape[0], dtype=jnp.int32)
     prod = jnp.where(slot < valid_nnz, prod,
                      semiring_identity(add, prod.dtype))
     return _SEG_REDUCE[add](
@@ -429,16 +466,24 @@ def csr_semiring_spmv_rowids_masked(data, indices, row_ids, valid_nnz,
 
 @partial(jax.jit, static_argnames=("rows", "add", "mul"))
 def csr_semiring_spmm_rowids_masked(data, indices, row_ids, valid_nnz,
-                                    X, rows: int, add: str, mul: str):
+                                    X, rows: int, add: str, mul: str,
+                                    row_ptr=None):
     """Batched semiring SpMV (k stacked operand columns in one
     dispatch — the multi-source frontier kernel, the semiring arm of
     the PR-8 stacked ``multi_matvec`` packing): column by column this
     is exactly :func:`csr_semiring_spmv_rowids_masked`, so a batch of
-    k sources is bit-for-bit the k individual sweeps."""
+    k sources is bit-for-bit the k individual sweeps.  With
+    ``row_ptr``, a boolean OR takes the same prefix count, run down
+    the slots for each column in turn."""
     _obs.inc("trace.csr_semiring_spmm_rowids_masked")
-    nnz = data.shape[0]
-    slot = jnp.arange(nnz, dtype=jnp.int32)
+    if row_ptr is not None and is_boolean_or(add, mul, data.dtype,
+                                             X.dtype):
+        # One column at a time: a count of all k at once would hold 4k
+        # bytes a slot (and a narrow k pads to a lane's 128 on TPU).
+        return jax.lax.map(lambda x: _prefix_or(
+            _semiring_product(mul, data, x[indices]), row_ptr), X.T).T
     prod = _semiring_product(mul, data[:, None], X[indices, :])
+    slot = jnp.arange(data.shape[0], dtype=jnp.int32)
     prod = jnp.where((slot < valid_nnz)[:, None], prod,
                      semiring_identity(add, prod.dtype))
     return _SEG_REDUCE[add](

@@ -80,6 +80,8 @@ class DistCSR:
     Padded-CSR layout: ``data``/``cols`` are (R, nnz_max) with
     ``row_ids`` (R, nnz_max) static local row ids and ``counts`` the
     (R,) per-shard valid nnz (padding suffix masked in-kernel).
+    ``shard_csr`` also stores ``row_ptr`` (R, rows_per_shard + 1), each
+    shard's local row bounds as offsets into its block.
     """
 
     # ELL/padded-CSR blocks.  May be None for a DIA-only matrix
@@ -149,6 +151,13 @@ class DistCSR:
     # P((rows, cols)) in row-major grid chunks.
     layout: str = LAYOUT_1D_ROW
     grid: Optional[Tuple[int, int]] = None
+    # Padded-CSR row bounds (int32 offsets into each block; rows past
+    # the shard's real rows repeat its end): (R, rps + 1), or
+    # (Rr, Rc, rps + 1) on a 2-d grid.  The or-and kernels reduce rows
+    # by a prefix count read at these bounds in place of a scatter.
+    # None where the builder stores none (ELL, DIA-only, SpGEMM
+    # products): those keep the segment reduction.
+    row_ptr: Optional[jax.Array] = None
 
     @property
     def num_shards(self) -> int:
@@ -580,6 +589,16 @@ def _route_layout(A: csr_array, mesh: Optional[Mesh]) -> str:
     return choice
 
 
+def _row_bounds(row_counts: np.ndarray) -> np.ndarray:
+    """(blocks, rps + 1) int32 row bounds of padded-CSR blocks from
+    their (blocks, rps) per-row stored counts: 0, then the running
+    count (``DistCSR.row_ptr``)."""
+    out = np.zeros((row_counts.shape[0], row_counts.shape[1] + 1),
+                   dtype=np.int32)
+    np.cumsum(row_counts, axis=1, out=out[:, 1:])
+    return out
+
+
 def _shard_csr_2d(A: csr_array, mesh: Optional[Mesh],
                   layout: str) -> DistCSR:
     """2-d block partitioning: block (i, j) of the (Rr, Rc) grid holds
@@ -633,10 +652,12 @@ def _shard_csr_2d(A: csr_array, mesh: Optional[Mesh],
 
     # Main blocks: grid-block id i*Rc + j.
     bi, bj = r_of // rps, c_of // cps
+    bid, row_local = bi * Rc + bj, r_of - bi * rps
     d_b, c_b, r_b, cnt = blocks_of(
-        bi * Rc + bj, r_of - bi * rps, c_of - bj * cps, N,
-        max(rps - 1, 0),
+        bid, row_local, c_of - bj * cps, N, max(rps - 1, 0),
     )
+    rp_b = _row_bounds(np.bincount(bid * rps + row_local,
+                                   minlength=N * rps).reshape(N, rps))
     spec3 = NamedSharding(mesh, P(ROW_AXIS, COL_AXIS, None))
     spec2 = NamedSharding(mesh, P(ROW_AXIS, COL_AXIS))
 
@@ -658,7 +679,7 @@ def _shard_csr_2d(A: csr_array, mesh: Optional[Mesh],
             cnt.reshape(Rr, Rc), spec2),
         row_ids=grid3(r_b), shape=(rows, cols), rows_per_shard=rps,
         halo=-1, ell=False, mesh=mesh, cols_per_shard=cps,
-        nnz_hint=nnz, layout=layout, grid=grid,
+        nnz_hint=nnz, layout=layout, grid=grid, row_ptr=grid3(rp_b),
     )
 
 
@@ -886,6 +907,9 @@ def shard_csr(A: csr_array, mesh: Optional[Mesh] = None,
         )
         rid_b[s, :ln] = rid
         rid_b[s, ln:] = max(rps - 1, 0)  # padding -> last row, value 0
+    counts_p = np.zeros(R * rps, dtype=np.int64)
+    counts_p[:rows] = counts
+    rp_b = _row_bounds(counts_p.reshape(R, rps))
     if precise:
         idx_b = np.stack(
             [rebase_precise(s, idx_b[s]) for s in range(R)]
@@ -899,6 +923,7 @@ def shard_csr(A: csr_array, mesh: Optional[Mesh] = None,
     dist = attach_dia_prepack(DistCSR(
         data=put(data_b), cols=put(idx_b),
         counts=put(local_nnz.astype(np.int32)), row_ids=put(rid_b),
+        row_ptr=put(rp_b),
         shape=(rows, cols), rows_per_shard=rps, halo=halo, ell=False,
         mesh=mesh,
         gather_idx=(put(gather_idx) if precise else None),
@@ -1264,14 +1289,17 @@ def _block_spmv_2d_fn(mesh: Mesh, grid: Tuple[int, int], rps: int,
 
 @lru_cache(maxsize=128)
 def _block_semiring_spmv_fn(mesh: Mesh, halo: int, precise: bool,
-                            ell: bool, rps: int, add: str, mul: str):
+                            ell: bool, rps: int, add: str, mul: str,
+                            prefix: bool):
     """Cached shard_map callable for the semiring dist SpMV over ELL /
     padded-CSR blocks: the ``_block_spmv_fn`` program with the local
     kernel generalized to the (add, mul) pair (graph/semiring.py).
     The x realization (precise all_to_all / halo ppermute / tiled
     all_gather) is semiring-independent — on 1-d layouts output rows
     live with the row partition, so no cross-shard output reduction
-    exists and the collectives are byte-identical to plus-times."""
+    exists and the collectives are byte-identical to plus-times.
+    ``prefix``: the padded-CSR kernel takes the shard's ``row_ptr``
+    (after ``counts``) and reduces rows by a prefix count."""
     _obs.inc("jit_miss.dist_csr.block_semiring_spmv_fn")
     from ..ops import spmv as _spmv_ops
 
@@ -1305,25 +1333,19 @@ def _block_semiring_spmv_fn(mesh: Mesh, halo: int, precise: bool,
             in_specs = (P(ROW_AXIS, None, None), P(ROW_AXIS, None, None),
                         P(ROW_AXIS, None), P(ROW_AXIS))
     else:
-        if precise:
-            def kernel(data, cols, row_ids, counts, gidx, x_local):
-                x_src = realize(x_local, gidx[0])
-                return _spmv_ops.csr_semiring_spmv_rowids_masked(
-                    data[0], cols[0], row_ids[0], counts[0], x_src,
-                    rps, add, mul)
+        def kernel(data, cols, row_ids, counts, *rest):
+            row_ptr = rest[0][0] if prefix else None
+            gidx = rest[-2][0] if precise else None
+            x_src = realize(rest[-1], gidx)
+            return _spmv_ops.csr_semiring_spmv_rowids_masked(
+                data[0], cols[0], row_ids[0], counts[0], x_src,
+                rps, add, mul, row_ptr=row_ptr)
 
-            in_specs = (P(ROW_AXIS, None), P(ROW_AXIS, None),
-                        P(ROW_AXIS, None), P(ROW_AXIS),
-                        P(ROW_AXIS, None, None), P(ROW_AXIS))
-        else:
-            def kernel(data, cols, row_ids, counts, x_local):
-                x_src = realize(x_local)
-                return _spmv_ops.csr_semiring_spmv_rowids_masked(
-                    data[0], cols[0], row_ids[0], counts[0], x_src,
-                    rps, add, mul)
-
-            in_specs = (P(ROW_AXIS, None), P(ROW_AXIS, None),
-                        P(ROW_AXIS, None), P(ROW_AXIS), P(ROW_AXIS))
+        in_specs = (P(ROW_AXIS, None), P(ROW_AXIS, None),
+                    P(ROW_AXIS, None), P(ROW_AXIS)) + (
+            (P(ROW_AXIS, None),) if prefix else ()) + (
+            (P(ROW_AXIS, None, None),) if precise else ()) + (
+            P(ROW_AXIS),)
     return jax.jit(shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=P(ROW_AXIS),
         check_vma=False,
@@ -1332,7 +1354,8 @@ def _block_semiring_spmv_fn(mesh: Mesh, halo: int, precise: bool,
 
 @lru_cache(maxsize=128)
 def _block_semiring_spmv_2d_fn(mesh: Mesh, grid: Tuple[int, int],
-                               rps: int, add: str, mul: str):
+                               rps: int, add: str, mul: str,
+                               prefix: bool):
     """Cached shard_map callable for the 2-d-block semiring dist SpMV.
 
     Steps 1-3 are ``_block_spmv_2d_fn`` verbatim (chunk-transpose
@@ -1344,7 +1367,9 @@ def _block_semiring_spmv_2d_fn(mesh: Mesh, grid: Tuple[int, int],
     and each device then slices its own output chunk locally.  Ring
     cost is 2*(Rc-1)*rps elements per row group — twice the
     reduce-scatter half — priced under the semiring's collective kind
-    (``comm.dist_spmv.pmin``/``pmax``/``por``)."""
+    (``comm.dist_spmv.pmin``/``pmax``/``por``).  ``prefix``: the block
+    kernel takes the block's ``row_ptr`` (after ``counts``) and reduces
+    rows by a prefix count."""
     _obs.inc("jit_miss.dist_csr.block_semiring_spmv_2d_fn")
     from ..ops import spmv as _spmv_ops
 
@@ -1354,7 +1379,8 @@ def _block_semiring_spmv_2d_fn(mesh: Mesh, grid: Tuple[int, int],
     reduce_op = {"min": jax.lax.pmin, "max": jax.lax.pmax}[add]
     chunk = rps // Rc
 
-    def kernel(data, cols, row_ids, counts, x_local):
+    def kernel(data, cols, row_ids, counts, *rest):
+        x_local = rest[-1]
         if not skip_perm:
             x_local = jax.lax.ppermute(
                 x_local, (ROW_AXIS, COL_AXIS), perm
@@ -1363,14 +1389,16 @@ def _block_semiring_spmv_2d_fn(mesh: Mesh, grid: Tuple[int, int],
         y_part = _spmv_ops.csr_semiring_spmv_rowids_masked(
             data[0, 0], cols[0, 0], row_ids[0, 0], counts[0, 0],
             x_panel, rps, add, mul,
+            row_ptr=rest[0][0, 0] if prefix else None,
         )
         y_full = reduce_op(y_part, COL_AXIS)
         j = jax.lax.axis_index(COL_AXIS)
         return jax.lax.dynamic_slice_in_dim(y_full, j * chunk, chunk)
 
     in_specs = (P(ROW_AXIS, COL_AXIS, None), P(ROW_AXIS, COL_AXIS, None),
-                P(ROW_AXIS, COL_AXIS, None), P(ROW_AXIS, COL_AXIS),
-                P((ROW_AXIS, COL_AXIS)))
+                P(ROW_AXIS, COL_AXIS, None), P(ROW_AXIS, COL_AXIS)) + (
+        (P(ROW_AXIS, COL_AXIS, None),) if prefix else ()) + (
+        P((ROW_AXIS, COL_AXIS)),)
     return jax.jit(shard_map(
         kernel, mesh=mesh, in_specs=in_specs,
         out_specs=P((ROW_AXIS, COL_AXIS)), check_vma=False,
@@ -1485,14 +1513,30 @@ def semiring_spmv_comm_volumes(A: DistCSR, x_itemsize: int,
     )
 
 
+def _takes_prefix_or(A: DistCSR, sr, x_dtype) -> bool:
+    """Whether a semiring dispatch on ``A`` reduces rows by the prefix
+    count: padded-CSR blocks with stored row bounds, and a boolean OR
+    (``ops.spmv.is_boolean_or``).  Counted once a dispatch in
+    ``graph.dist_spmv.prefix_or``."""
+    from ..ops.spmv import is_boolean_or
+
+    prefix = (not A.ell and A.row_ptr is not None
+              and is_boolean_or(sr.add, sr.mul, A.dtype, x_dtype))
+    if prefix:
+        _obs.inc("graph.dist_spmv.prefix_or")
+    return prefix
+
+
 def _dist_spmv_semiring(A: DistCSR, x: jax.Array, sr) -> jax.Array:
     """Semiring arm of ``dist_spmv`` (``sr`` a resolved non-plus-times
     :class:`~..graph.semiring.Semiring`): same accounting discipline
     as ``_dist_spmv_impl`` — comm volumes priced from static fields
-    before dispatch, span with realization path — plus the ``graph.*``
-    ledger row for the semiring family.  Structure-specialized
-    plus-times paths (DIA/BSR) don't generalize, so dispatch goes
-    straight to the ELL / padded-CSR block programs."""
+    before dispatch, span with realization path and row reduction
+    (``reduce``: "prefix", "segment", or "row" on ELL blocks) — plus
+    the ``graph.*`` ledger row for the semiring family.
+    Structure-specialized plus-times paths (DIA/BSR) don't generalize,
+    so dispatch goes straight to the ELL / padded-CSR block
+    programs."""
     _obs.inc("op.dist_spmv")
     _obs.inc("graph.dist_spmv." + sr.name)
     from ..obs import comm as _comm
@@ -1509,29 +1553,30 @@ def _dist_spmv_semiring(A: DistCSR, x: jax.Array, sr) -> jax.Array:
                       comm_bytes=comm_bytes,
                       comm_calls=sum(1 for b in vols.values() if b > 0)
                       ) as sp:
+        A._require_blocks("dist_spmv")
+        prefix = _takes_prefix_or(A, sr, x.dtype)
+        reduce = "row" if A.ell else ("prefix" if prefix else "segment")
+        bounds = (A.row_ptr,) if prefix else ()
         if A.grid is not None:
             fn = _block_semiring_spmv_2d_fn(
-                A.mesh, A.grid, A.rows_per_shard, sr.add, sr.mul)
+                A.mesh, A.grid, A.rows_per_shard, sr.add, sr.mul,
+                prefix)
             if sp is not None:
                 sp.set(path="2d-block", layout=A.layout,
-                       semiring=sr.name)
-            return fn(A.data, A.cols, A.row_ids, A.counts, x)
-        A._require_blocks("dist_spmv")
+                       semiring=sr.name, reduce=reduce)
+            return fn(A.data, A.cols, A.row_ids, A.counts, *bounds, x)
         precise = A.gather_idx is not None
         fn = _block_semiring_spmv_fn(
             A.mesh, A.halo, precise, A.ell, A.rows_per_shard,
-            sr.add, sr.mul)
+            sr.add, sr.mul, prefix)
         if A.ell:
-            args = (A.data, A.cols, A.counts) + (
-                (A.gather_idx,) if precise else ()
-            ) + (x,)
+            args = (A.data, A.cols, A.counts)
         else:
-            args = (A.data, A.cols, A.row_ids, A.counts) + (
-                (A.gather_idx,) if precise else ()
-            ) + (x,)
+            args = (A.data, A.cols, A.row_ids, A.counts) + bounds
+        args += ((A.gather_idx,) if precise else ()) + (x,)
         if sp is not None:
             sp.set(path="ell" if A.ell else "padded-csr",
-                   precise=precise, semiring=sr.name)
+                   precise=precise, semiring=sr.name, reduce=reduce)
         return fn(*args)
 
 
@@ -1851,13 +1896,14 @@ def _block_spmm_fn(mesh: Mesh, halo: int, precise: bool, ell: bool,
 @lru_cache(maxsize=128)
 def _block_semiring_spmm_fn(mesh: Mesh, halo: int, precise: bool,
                             ell: bool, rps: int, col_sharded: bool,
-                            add: str, mul: str):
+                            add: str, mul: str, prefix: bool):
     """Cached shard_map callable for distributed semiring SpMM — the
     batched multi-source frontier program (k stacked sources ride one
     dispatch, the distributed arm of the PR-8 ``multi_matvec``
     packing).  Structure is ``_block_spmm_fn`` with the local kernel
     generalized; x realization collectives are semiring-independent
-    (1-d layouts only, like ``dist_spmm`` itself)."""
+    (1-d layouts only, like ``dist_spmm`` itself).  ``prefix`` as in
+    ``_block_semiring_spmv_fn``."""
     _obs.inc("jit_miss.dist_csr.block_semiring_spmm_fn")
     from ..ops import spmv as _spmv_ops
 
@@ -1892,17 +1938,19 @@ def _block_semiring_spmm_fn(mesh: Mesh, halo: int, precise: bool,
         )
     else:
         def kernel(data, cols, row_ids, counts, *rest):
-            gidx = rest[0][0] if precise else None
+            row_ptr = rest[0][0] if prefix else None
+            gidx = rest[-2][0] if precise else None
             X_local = rest[-1]
             X_src = realize(X_local, gidx)
             return _spmv_ops.csr_semiring_spmm_rowids_masked(
                 data[0], cols[0], row_ids[0], counts[0], X_src,
-                rps, add, mul)
+                rps, add, mul, row_ptr=row_ptr)
 
         in_specs = (
             P(ROW_AXIS, None), P(ROW_AXIS, None), P(ROW_AXIS, None),
             P(ROW_AXIS),
-        ) + ((P(ROW_AXIS, None, None),) if precise else ()) + (
+        ) + ((P(ROW_AXIS, None),) if prefix else ()) + (
+            (P(ROW_AXIS, None, None),) if precise else ()) + (
             P(ROW_AXIS, xcol),
         )
     return jax.jit(shard_map(
@@ -1978,17 +2026,16 @@ def dist_spmm(A: DistCSR, X: jax.Array, semiring=None) -> jax.Array:
     sr = _resolve_semiring_arg(semiring)
     if sr is not None:
         _obs.inc("graph.dist_spmm." + sr.name)
+        prefix = _takes_prefix_or(A, sr, X.dtype)
         fn = _block_semiring_spmm_fn(
             A.mesh, A.halo, precise, A.ell, A.rows_per_shard,
-            col_sharded, sr.add, sr.mul)
+            col_sharded, sr.add, sr.mul, prefix)
         if A.ell:
-            args = (A.data, A.cols, A.counts) + (
-                (A.gather_idx,) if precise else ()
-            ) + (X,)
+            args = (A.data, A.cols, A.counts)
         else:
             args = (A.data, A.cols, A.row_ids, A.counts) + (
-                (A.gather_idx,) if precise else ()
-            ) + (X,)
+                (A.row_ptr,) if prefix else ())
+        args += ((A.gather_idx,) if precise else ()) + (X,)
         return fn(*args)
     if (A.pdia_tile and A.halo >= 0 and not precise
             and jnp.result_type(A.dtype, X.dtype) == A.dtype):

@@ -30,6 +30,8 @@ BAND_ROWS = 1 << 24          # chip_smoke phase a
 SPGEMM_ROWS = 1 << 22        # chip_smoke phase c
 OFFSETS = tuple(range(-5, 6))
 GRID = 2048                  # chip_smoke --four-chips CG grid
+BFS_ROWS = 1 << 20           # graph500-s20's vertices
+BFS_SLOTS = 1 << 25          # its stored entries, both directions
 
 
 @pytest.fixture(scope="module")
@@ -181,6 +183,23 @@ def _rowids(S):
         S((nnz,), jnp.int32), S((n,), jnp.float32))
 
 
+def _or_and(k):
+    """The BFS frontier push's padded-CSR kernel at ``bfs-graph500``'s
+    width (SpMV, or a batch of k roots): the or-and rows reduced by the
+    prefix count read at the row bounds."""
+    def build(S):
+        n, nnz = BFS_ROWS, BFS_SLOTS
+        kern = (spmv_ops.csr_semiring_spmv_rowids_masked if k is None
+                else spmv_ops.csr_semiring_spmm_rowids_masked)
+        x = S((n,) if k is None else (n, k), jnp.bool_)
+        return (lambda d, i, r, c, x, rp: kern(
+            d, i, r, c, x, n, "max", "and", row_ptr=rp)), (
+            S((nnz,), jnp.float32), S((nnz,), jnp.int32),
+            S((nnz,), jnp.int32), S((), jnp.int32), x,
+            S((n + 1,), jnp.int32))
+    return build
+
+
 def _dist_1d(grid):
     """The banded 1d-row dist SpMV plan body (halo ppermute + the
     per-shard Mosaic kernel) for the 5-point ``grid``² grid on 4 chips:
@@ -249,6 +268,8 @@ ONE_CHIP = {
     "bsr-spmm-k16": _bsr(16),
     "csr-rowids": _rowids,
     "band-build-csr11": _band_build,
+    "or-and-prefix-spmv": _or_and(None),
+    "or-and-prefix-spmm-k3": _or_and(3),
 }
 FOUR_CHIPS = {"dist-spmv-1d-row": _dist_1d(GRID),
               "dist-spmv-2d-block": _dist_2d,
@@ -272,6 +293,8 @@ def test_compiles_for_v5e(case, chip_config):
     text = compiled.as_text()
     if case.startswith(("dia-", "bsr-", "dist-spmv-1d")):
         assert "tpu_custom_call" in text      # the Mosaic kernel is there
+    if case.startswith("or-and-"):
+        assert " scatter(" not in text        # rows reduced by a count
     mem = compiled.memory_analysis()
     if mem is not None:
         used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
